@@ -9,9 +9,9 @@ experiments/repro_results.json the full pipeline (~10 min CPU) runs once and
 is cached for subsequent invocations.
 
 --smoke is the CI registration gate: every non-repro section runs at tiny
-shapes and any section error fails the process (the normal mode reports
-errors as CSV rows and keeps going) — so a benchmark whose imports or
-registrations rot cannot pass CI silently.
+shapes. In every mode a section error is reported as a CSV row, the
+remaining sections still run, and the process exits 1 at the end — so a
+benchmark whose imports or registrations rot cannot pass silently.
 
 --json [DIR] persists each section's numeric rows as one run record in
 DIR/BENCH_<section>.json (bounded trajectory, default DIR "."); --compare
@@ -47,6 +47,8 @@ def main() -> None:
     if args.compare and args.json_dir is None:
         ap.error("--compare requires --json (needs a trajectory to compare "
                  "against)")
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     smoke = args.smoke
     quick = args.quick or smoke
     skip_repro = args.skip_repro or smoke
@@ -85,7 +87,7 @@ def main() -> None:
         t0 = time.perf_counter()
         try:
             rows = list(fn())
-        except Exception as e:  # keep the harness robust: report and continue
+        except Exception as e:  # report, run the rest, fail at the end
             print(f"{name}_ERROR,0,{type(e).__name__}: {str(e)[:120]}")
             failed.append(name)
             rows = []
@@ -105,8 +107,8 @@ def main() -> None:
                           f"{prev:.6g} -> {cur:.6g}")
                     regressions.append((name, metric))
             append_run(args.json_dir, rec)
-    if smoke and failed:
-        print(f"SMOKE_FAILED,{len(failed)},{';'.join(failed)}")
+    if failed:
+        print(f"SECTIONS_FAILED,{len(failed)},{';'.join(failed)}")
         sys.exit(1)
     if regressions:
         print(f"COMPARE_FAILED,{len(regressions)},"

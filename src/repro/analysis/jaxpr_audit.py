@@ -45,7 +45,8 @@ from .findings import Finding, FindingSet
 FORBIDDEN_PRIMITIVES: Dict[str, str] = {
     "pure_callback": "host callback (jax.pure_callback)",
     "io_callback": "host callback (jax.experimental.io_callback)",
-    "debug_callback": "host callback (jax.debug.print / jax.debug.callback)",
+    "debug_callback": "host callback (jax.debug.callback)",
+    "debug_print": "host callback (jax.debug.print)",
     "custom_transpose_call": "host re-entry via custom_transpose",
     "infeed": "device infeed (host dependency mid-program)",
     "outfeed": "device outfeed (host dependency mid-program)",
@@ -99,7 +100,7 @@ def iter_primitives(jaxpr):
 def _sub_jaxprs(param):
     """Extract jaxprs nested inside an eqn param (covers ClosedJaxpr,
     bare Jaxpr, and lists/tuples of either — cond branches)."""
-    import jax.core as jcore
+    import jax.extend.core as jcore
     vals = param if isinstance(param, (list, tuple)) else [param]
     out = []
     for v in vals:
@@ -207,7 +208,7 @@ def _live_state_leaves(subj: AuditSubject) -> int:
     is dead on entry, gets DCE'd, and legitimately cannot alias. Count the
     state invars that survive into the traced jaxpr's equations/outputs.
     """
-    import jax.core as jcore
+    import jax.extend.core as jcore
     jaxpr = jax.make_jaxpr(subj.fn)(*subj.args).jaxpr
     sizes = [len(jax.tree_util.tree_leaves(a)) for a in subj.args]
     start = sum(sizes[:subj.state_argnum])

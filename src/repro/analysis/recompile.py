@@ -43,9 +43,10 @@ import numpy as np
 from .findings import Finding, FindingSet
 
 # the pxla dispatch logger emits "Compiling <name> with global shapes and
-# types [...]" at WARNING whenever jax_log_compiles is on
+# types [...]" at WARNING whenever jax_log_compiles is on; the name may come
+# wrapped as "jit(<name>)"
 _COMPILE_LOGGER = "jax._src.interpreters.pxla"
-_COMPILE_RE = re.compile(r"Compiling ([^\s]+)")
+_COMPILE_RE = re.compile(r"Compiling (?:jit\()?([^\s()]+)")
 _COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 
 # jax-internal housekeeping compiles (eager single-primitive dispatch, PRNG
@@ -84,12 +85,14 @@ class CompileWatcher:
     shapes and types ...`` log line — name plus abstract argument shapes,
     i.e. exactly the jit cache key the dispatch missed on. ``n_compiles``
     counts backend-compile monitoring events (includes compiles that bypass
-    the dispatch logger, e.g. internal helpers).
+    the dispatch logger, e.g. internal helpers); ``seconds`` sums their
+    durations (a persistent-cache hit counts as its read time).
     """
 
     def __init__(self):
         self.signatures: List[str] = []
         self.n_compiles = 0
+        self.seconds = 0.0
         self._handler: Optional[_LogCapture] = None
         self._prev_log_compiles = None
         self._prev_level = None
@@ -97,10 +100,10 @@ class CompileWatcher:
     def _on_event(self, event: str, duration: float, **kw):
         if event == _COMPILE_EVENT:
             self.n_compiles += 1
+            self.seconds += duration
 
     def __enter__(self):
-        from jax._src import monitoring
-        monitoring.register_event_duration_secs_listener(self._on_event)
+        jax.monitoring.register_event_duration_secs_listener(self._on_event)
         self._handler = _LogCapture()
         logger = logging.getLogger(_COMPILE_LOGGER)
         self._prev_level = logger.level
@@ -118,7 +121,6 @@ class CompileWatcher:
         return self
 
     def __exit__(self, *exc):
-        from jax._src import monitoring
         jax.config.update("jax_log_compiles", self._prev_log_compiles)
         logger = logging.getLogger(_COMPILE_LOGGER)
         logger.removeHandler(self._handler)
@@ -126,8 +128,7 @@ class CompileWatcher:
         logger.propagate = self._prev_propagate
         logging.getLogger("jax._src.dispatch").setLevel(
             self._prev_dispatch_level)
-        monitoring._unregister_event_duration_listener_by_callback(
-            self._on_event)
+        jax.monitoring.unregister_event_duration_listener(self._on_event)
         self.signatures = [m for m in self._handler.messages
                            if _COMPILE_RE.match(m)]
         return False

@@ -23,8 +23,10 @@ def probs_from_logits(logits, temperature: float = 1.0, top_p: float = 1.0):
     if top_p < 1.0:
         sorted_p = jnp.sort(p, -1)[..., ::-1]
         csum = jnp.cumsum(sorted_p, -1)
-        # smallest set with cumulative mass >= top_p
-        cutoff_idx = jnp.sum(csum < top_p, -1, keepdims=True)
+        # smallest set with cumulative mass >= top_p; a float32 cumsum can
+        # stop short of a top_p just below 1, which would index past V
+        cutoff_idx = jnp.minimum(jnp.sum(csum < top_p, -1, keepdims=True),
+                                 p.shape[-1] - 1)
         cutoff = jnp.take_along_axis(sorted_p, cutoff_idx, -1)
         p = jnp.where(p >= cutoff, p, 0.0)
         p = p / jnp.maximum(p.sum(-1, keepdims=True), 1e-20)

@@ -521,14 +521,20 @@ def _cached_phased_tree_round(draft, target: Model, sdc: SDConfig, tree):
 
 @lru_cache(maxsize=64)
 def _cached_decode(model: Model, long_context: bool):
-    return jax.jit(partial(model.decode_step, long_context=long_context))
+    """Decode step with the cache (argument 3) donated: every caller rebinds
+    its cache to the result. Undonated, each call allocates a second cache
+    while the host runs ahead of the device — on a chip, chunked prefill of
+    one prompt then holds several full paged pools at once."""
+    return jax.jit(partial(model.decode_step, long_context=long_context),
+                   donate_argnums=(3,))
 
 
 @lru_cache(maxsize=64)
 def _cached_decode_hidden(model: Model, long_context: bool):
-    """Hidden-returning decode step (draft-head prefill needs the feature)."""
+    """Hidden-returning decode step (draft-head prefill needs the feature);
+    cache donated as in ``_cached_decode``."""
     return jax.jit(partial(model.decode_step, long_context=long_context,
-                           return_hidden=True))
+                           return_hidden=True), donate_argnums=(3,))
 
 
 def _prefill_state(draft, target, d_params, t_params, prompt, max_total,

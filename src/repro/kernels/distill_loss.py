@@ -18,7 +18,9 @@ Grid layout: (row_tiles, vocab_tiles) with the vocab dimension minor — on TPU
 the grid is executed sequentially over the last axis, so VMEM scratch
 accumulators carry across vocab tiles (the canonical online-softmax pattern).
 Tile sizes are MXU/VPU aligned: rows in multiples of 8 sublanes, vocab in
-multiples of 128 lanes.
+multiples of 128 lanes. Per-row statistics travel as (N, 1) columns (a
+rank-1 (Rt,) block does not tile on the TPU) and per-call scalars as (1, 1);
+the public wrappers take and return (N,) vectors and () scalars.
 
 Per-element weights w (so that grad = p * (w - c), c = sum p*w):
   tvdpp : w = -adv,            adv = sg[(r - mu) / sigma], r = 1{q > p}
@@ -61,9 +63,9 @@ def _lse_kernel(x_ref, out_ref, m_scr, l_scr, *, n_vtiles):
         l_scr[...] = jnp.zeros_like(l_scr)
 
     x = x_ref[...].astype(jnp.float32)                    # (Rt, Vt)
-    m_new = jnp.maximum(m_scr[...], jnp.max(x, axis=1))
+    m_new = jnp.maximum(m_scr[...], jnp.max(x, axis=1, keepdims=True))
     l_scr[...] = l_scr[...] * jnp.exp(m_scr[...] - m_new) + \
-        jnp.sum(jnp.exp(x - m_new[:, None]), axis=1)
+        jnp.sum(jnp.exp(x - m_new), axis=1, keepdims=True)
     m_scr[...] = m_new
 
     @pl.when(vidx == n_vtiles - 1)
@@ -71,20 +73,34 @@ def _lse_kernel(x_ref, out_ref, m_scr, l_scr, *, n_vtiles):
         out_ref[...] = m_scr[...] + jnp.log(l_scr[...])
 
 
+def _tiles(N, V):
+    rt, vt = _pick_tile(N, ROW_TILE), _pick_tile(V, VOCAB_TILE)
+    return rt, vt, (N // rt, V // vt)
+
+
+def _col(x):
+    """(N,) -> (N, 1) fp32 column."""
+    return x.astype(jnp.float32).reshape(-1, 1)
+
+
+def _scalar(x):
+    return jnp.asarray(x, jnp.float32).reshape(1, 1)
+
+
 def row_logsumexp(x, interpret=True):
     N, V = x.shape
-    rt, vt = _pick_tile(N, ROW_TILE), _pick_tile(V, VOCAB_TILE)
-    grid = (N // rt, V // vt)
-    return pl.pallas_call(
+    rt, vt, grid = _tiles(N, V)
+    out = pl.pallas_call(
         functools.partial(_lse_kernel, n_vtiles=grid[1]),
         grid=grid,
         in_specs=[pl.BlockSpec((rt, vt), lambda i, j: (i, j))],
-        out_specs=pl.BlockSpec((rt,), lambda i, j: (i,)),
-        out_shape=jax.ShapeDtypeStruct((N,), jnp.float32),
-        scratch_shapes=[pltpu.VMEM((rt,), jnp.float32),
-                        pltpu.VMEM((rt,), jnp.float32)],
+        out_specs=pl.BlockSpec((rt, 1), lambda i, j: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((N, 1), jnp.float32),
+        scratch_shapes=[pltpu.VMEM((rt, 1), jnp.float32),
+                        pltpu.VMEM((rt, 1), jnp.float32)],
         interpret=interpret,
     )(x)
+    return out[:, 0]
 
 
 # ----------------------------------------------------------- per-mode weight
@@ -100,66 +116,59 @@ def _weight(mode, p, q, mu, inv_sigma):
 
 def _probs(x_ref, lse_ref):
     x = x_ref[...].astype(jnp.float32)
-    return jnp.exp(x - lse_ref[...][:, None])
+    return jnp.exp(x - lse_ref[...])
 
 
 # ----------------------------------------------------------- 2: loss terms
 
 def _terms_kernel(s_ref, t_ref, lse_s_ref, lse_t_ref, mu_ref, isg_ref,
-                  loss_ref, c_ref, r1_ref, r2_ref, acc_scr, *, mode, n_vtiles):
+                  loss_ref, c_ref, r1_ref, r2_ref, *, mode):
     vidx = pl.program_id(1)
+    outs = (loss_ref, c_ref, r1_ref, r2_ref)
 
+    # the output blocks stay resident across the vocab axis: accumulate there
     @pl.when(vidx == 0)
     def _init():
-        acc_scr[...] = jnp.zeros_like(acc_scr)
+        for o in outs:
+            o[...] = jnp.zeros_like(o)
 
     p = _probs(s_ref, lse_s_ref)
     q = _probs(t_ref, lse_t_ref)
     if mode == "kld":
-        lq = t_ref[...].astype(jnp.float32) - lse_t_ref[...][:, None]
-        lp = s_ref[...].astype(jnp.float32) - lse_s_ref[...][:, None]
-        loss_part = jnp.sum(q * (lq - lp), axis=1)
+        lq = t_ref[...].astype(jnp.float32) - lse_t_ref[...]
+        lp = s_ref[...].astype(jnp.float32) - lse_s_ref[...]
+        loss_part = jnp.sum(q * (lq - lp), axis=1, keepdims=True)
         c_part = jnp.zeros_like(loss_part)
     else:
-        w = _weight(mode, p, q, mu_ref[0], isg_ref[0])
-        c_part = jnp.sum(p * w, axis=1)
+        w = _weight(mode, p, q, mu_ref[...], isg_ref[...])
+        c_part = jnp.sum(p * w, axis=1, keepdims=True)
         if mode == "tvdpp":
             loss_part = c_part                      # L_row = sum p*(-adv) = c
         else:
-            loss_part = jnp.sum(0.5 * jnp.abs(q - p), axis=1)
+            loss_part = jnp.sum(0.5 * jnp.abs(q - p), axis=1, keepdims=True)
     r = (q > p).astype(jnp.float32)
-    acc_scr[...] += jnp.stack(
-        [loss_part, c_part, jnp.sum(p * r, axis=1), jnp.sum(p * r * r, axis=1)],
-        axis=0)
-
-    @pl.when(vidx == n_vtiles - 1)
-    def _done():
-        loss_ref[...] = acc_scr[0]
-        c_ref[...] = acc_scr[1]
-        r1_ref[...] = acc_scr[2]
-        r2_ref[...] = acc_scr[3]
+    loss_ref[...] += loss_part
+    c_ref[...] += c_part
+    r1_ref[...] += jnp.sum(p * r, axis=1, keepdims=True)
+    r2_ref[...] += jnp.sum(p * r * r, axis=1, keepdims=True)
 
 
 def loss_terms(s, t, lse_s, lse_t, mu, inv_sigma, mode="tvdpp", interpret=True):
     """-> per-row (loss, c, sum p*r, sum p*r^2)."""
     N, V = s.shape
-    rt, vt = _pick_tile(N, ROW_TILE), _pick_tile(V, VOCAB_TILE)
-    grid = (N // rt, V // vt)
+    rt, vt, grid = _tiles(N, V)
+    tile = pl.BlockSpec((rt, vt), lambda i, j: (i, j))
+    col = pl.BlockSpec((rt, 1), lambda i, j: (i, 0))
+    one = pl.BlockSpec((1, 1), lambda i, j: (0, 0))
     out = pl.pallas_call(
-        functools.partial(_terms_kernel, mode=mode, n_vtiles=grid[1]),
+        functools.partial(_terms_kernel, mode=mode),
         grid=grid,
-        in_specs=[pl.BlockSpec((rt, vt), lambda i, j: (i, j)),
-                  pl.BlockSpec((rt, vt), lambda i, j: (i, j)),
-                  pl.BlockSpec((rt,), lambda i, j: (i,)),
-                  pl.BlockSpec((rt,), lambda i, j: (i,)),
-                  pl.BlockSpec((1,), lambda i, j: (0,)),
-                  pl.BlockSpec((1,), lambda i, j: (0,))],
-        out_specs=[pl.BlockSpec((rt,), lambda i, j: (i,))] * 4,
-        out_shape=[jax.ShapeDtypeStruct((N,), jnp.float32)] * 4,
-        scratch_shapes=[pltpu.VMEM((4, rt), jnp.float32)],
+        in_specs=[tile, tile, col, col, one, one],
+        out_specs=[col] * 4,
+        out_shape=[jax.ShapeDtypeStruct((N, 1), jnp.float32)] * 4,
         interpret=interpret,
-    )(s, t, lse_s, lse_t, mu.reshape(1), inv_sigma.reshape(1))
-    return tuple(out)
+    )(s, t, _col(lse_s), _col(lse_t), _scalar(mu), _scalar(inv_sigma))
+    return tuple(o[:, 0] for o in out)
 
 
 # ----------------------------------------------------------- 3: gradient
@@ -168,32 +177,28 @@ def _grad_kernel(s_ref, t_ref, lse_s_ref, lse_t_ref, c_ref, mu_ref, isg_ref,
                  g_ref, out_ref, *, mode):
     p = _probs(s_ref, lse_s_ref)
     q = _probs(t_ref, lse_t_ref)
-    g = g_ref[...][:, None]
+    g = g_ref[...]                                        # (Rt, 1)
     if mode == "kld":
         out_ref[...] = g * (p - q)
     else:
-        w = _weight(mode, p, q, mu_ref[0], isg_ref[0])
-        out_ref[...] = g * p * (w - c_ref[...][:, None])
+        w = _weight(mode, p, q, mu_ref[...], isg_ref[...])
+        out_ref[...] = g * p * (w - c_ref[...])
 
 
 def loss_grad(s, t, lse_s, lse_t, c, g_rows, mu, inv_sigma, mode="tvdpp",
               interpret=True):
     """-> dL/ds (N, V) fp32, given upstream per-row cotangents g_rows."""
     N, V = s.shape
-    rt, vt = _pick_tile(N, ROW_TILE), _pick_tile(V, VOCAB_TILE)
-    grid = (N // rt, V // vt)
+    rt, vt, grid = _tiles(N, V)
+    tile = pl.BlockSpec((rt, vt), lambda i, j: (i, j))
+    col = pl.BlockSpec((rt, 1), lambda i, j: (i, 0))
+    one = pl.BlockSpec((1, 1), lambda i, j: (0, 0))
     return pl.pallas_call(
         functools.partial(_grad_kernel, mode=mode),
         grid=grid,
-        in_specs=[pl.BlockSpec((rt, vt), lambda i, j: (i, j)),
-                  pl.BlockSpec((rt, vt), lambda i, j: (i, j)),
-                  pl.BlockSpec((rt,), lambda i, j: (i,)),
-                  pl.BlockSpec((rt,), lambda i, j: (i,)),
-                  pl.BlockSpec((rt,), lambda i, j: (i,)),
-                  pl.BlockSpec((1,), lambda i, j: (0,)),
-                  pl.BlockSpec((1,), lambda i, j: (0,)),
-                  pl.BlockSpec((rt,), lambda i, j: (i,))],
-        out_specs=pl.BlockSpec((rt, vt), lambda i, j: (i, j)),
+        in_specs=[tile, tile, col, col, col, one, one, col],
+        out_specs=tile,
         out_shape=jax.ShapeDtypeStruct((N, V), jnp.float32),
         interpret=interpret,
-    )(s, t, lse_s, lse_t, c, mu.reshape(1), inv_sigma.reshape(1), g_rows)
+    )(s, t, _col(lse_s), _col(lse_t), _col(c), _scalar(mu),
+      _scalar(inv_sigma), _col(g_rows))

@@ -1,63 +1,20 @@
-"""Flash-decode attention Pallas kernel (single-token decode, long KV cache).
+"""Flash-decode attention (single-token decode, long KV cache).
 
 SD verification and plain decode run the target over a KV cache of up to 512k
-positions — purely memory-bound. The kernel streams KV tiles HBM->VMEM with
-online-softmax accumulation, grid (batch, kv_head, kv_tiles); the kv-tile
-axis is minor/sequential so scratch accumulators carry across tiles.
-
-GQA layout: queries grouped per kv head, q: (B, Hkv, G, hd) with
-G = num_heads // num_kv_heads; each grid step does a (G, hd) x (hd, St)
-score matmul and a (G, St) x (St, hd) value matmul — MXU-shaped for
-St = 128..512, hd in {64, 128, 256}.
+positions — purely memory-bound. One decode position is the one-node case
+of the tree-attention kernel (kernels.tree_attention): KV tiles stream
+HBM->VMEM with online-softmax accumulation, grid (batch, kv_head, kv_tiles),
+and each grid step does a (G, hd) x (hd, St) score matmul and a
+(G, St) x (St, hd) value matmul with G = num_heads // num_kv_heads.
 
 Validity (causal + ring-buffer occupancy + sliding window) arrives as a
 precomputed bool mask (B, S) — position bookkeeping stays outside the kernel.
 """
 from __future__ import annotations
 
-import functools
-import math
-
-import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
-NEG_INF = -1e30
-KV_TILE = 128
-
-
-def _decode_kernel(q_ref, k_ref, v_ref, mask_ref, out_ref,
-                   acc_scr, m_scr, l_scr, *, n_tiles, scale, softcap):
-    tidx = pl.program_id(2)
-
-    @pl.when(tidx == 0)
-    def _init():
-        acc_scr[...] = jnp.zeros_like(acc_scr)
-        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[...] = jnp.zeros_like(l_scr)
-
-    q = q_ref[0, 0].astype(jnp.float32)                  # (G, hd)
-    k = k_ref[0, :, 0].astype(jnp.float32)               # (St, hd)
-    v = v_ref[0, :, 0].astype(jnp.float32)               # (St, hd)
-    mask = mask_ref[0]                                   # (St,)
-
-    s = jnp.dot(q, k.T) * scale                          # (G, St)
-    if softcap is not None:
-        s = softcap * jnp.tanh(s / softcap)
-    s = jnp.where(mask[None, :], s, NEG_INF)
-
-    m_new = jnp.maximum(m_scr[...], jnp.max(s, axis=1))
-    alpha = jnp.exp(m_scr[...] - m_new)
-    p = jnp.exp(s - m_new[:, None])
-    l_scr[...] = l_scr[...] * alpha + p.sum(axis=1)
-    acc_scr[...] = acc_scr[...] * alpha[:, None] + jnp.dot(p, v)
-    m_scr[...] = m_new
-
-    @pl.when(tidx == n_tiles - 1)
-    def _done():
-        out_ref[0, 0] = (acc_scr[...] /
-                         jnp.maximum(l_scr[...], 1e-30)[:, None]).astype(out_ref.dtype)
+from .tree_attention import masked_attention
 
 
 def flash_decode(q, k, v, mask, softcap=None, interpret=True):
@@ -65,23 +22,7 @@ def flash_decode(q, k, v, mask, softcap=None, interpret=True):
 
     Returns (B, Hkv, G, hd) fp32 attention output for one decode position.
     """
-    B, Hkv, G, hd = q.shape
-    S = k.shape[1]
-    st = min(KV_TILE, S)
-    assert S % st == 0, (S, st)
-    grid = (B, Hkv, S // st)
-    return pl.pallas_call(
-        functools.partial(_decode_kernel, n_tiles=grid[2],
-                          scale=1.0 / math.sqrt(hd), softcap=softcap),
-        grid=grid,
-        in_specs=[pl.BlockSpec((1, 1, G, hd), lambda b, h, s: (b, h, 0, 0)),
-                  pl.BlockSpec((1, st, 1, hd), lambda b, h, s: (b, s, h, 0)),
-                  pl.BlockSpec((1, st, 1, hd), lambda b, h, s: (b, s, h, 0)),
-                  pl.BlockSpec((1, st), lambda b, h, s: (b, s))],
-        out_specs=pl.BlockSpec((1, 1, G, hd), lambda b, h, s: (b, h, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, Hkv, G, hd), jnp.float32),
-        scratch_shapes=[pltpu.VMEM((G, hd), jnp.float32),
-                        pltpu.VMEM((G,), jnp.float32),
-                        pltpu.VMEM((G,), jnp.float32)],
-        interpret=interpret,
-    )(q, k, v, mask)
+    G = q.shape[2]
+    rows = jnp.broadcast_to(mask[:, None, :], (mask.shape[0], G, mask.shape[1]))
+    return masked_attention(q, k, v, rows, softcap=softcap,
+                            interpret=interpret)

@@ -8,8 +8,9 @@ frozen so its cotangent is zero).
 Mosaic. It is resolved lazily on first use (reading it at import would
 initialize the JAX backend as an import side effect): the
 ``REPRO_PALLAS_INTERPRET`` env var ("0"/"false" or "1"/"true") wins; unset,
-it defaults to compiled on TPU backends and interpret everywhere else — so
-TPU runs need no monkey-patching and CPU tests keep working out of the box.
+it is interpret on the CPU backend and compiled on every other backend — a
+backend that cannot compile the kernels fails loudly instead of falling
+back to the interpreter.
 Assigning ``ops.INTERPRET = ...`` still force-overrides it.
 """
 from __future__ import annotations
@@ -30,10 +31,7 @@ def _env_interpret() -> bool:
     v = os.environ.get("REPRO_PALLAS_INTERPRET")
     if v is not None:
         return v.strip().lower() not in ("0", "false", "no", "off")
-    try:
-        return jax.default_backend() != "tpu"
-    except Exception:
-        return True
+    return jax.default_backend() == "cpu"
 
 
 def _interpret() -> bool:

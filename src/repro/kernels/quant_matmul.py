@@ -17,7 +17,11 @@ Two layouts, matching ``repro.quant.qweight.QWeight``:
           the low nibble, odd in the high), scale (K//group, N) fp32 with
           ``group`` consecutive K rows per scale. Scales vary along K, so
           each tile is unpacked, sign-extended, and scaled before its MXU
-          contraction.
+          contraction. The wrapper splits x into its even and odd K columns,
+          so the low and high nibbles contract as two plain matmuls and no
+          row interleave happens in VMEM; a K tile spans a multiple of 8
+          scale groups, so the scale block is (8k, Nt) as the TPU's (8, 128)
+          tiling asks.
 
 Grid is (M tiles, N tiles, K tiles) with the K axis minor/sequential so the
 fp32 accumulator scratch carries across K tiles — the same convention as
@@ -56,28 +60,37 @@ def _int8_kernel(x_ref, q_ref, scale_ref, out_ref, acc_scr, *, n_k):
 
 
 def _unpack_int4(packed):
-    """(Kt//2, Nt) uint8 -> (Kt, Nt) fp32 in [-8, 7] (even K rows = low
-    nibble, odd = high)."""
-    lo = (packed & 0xF).astype(jnp.int32)
-    hi = ((packed >> 4) & 0xF).astype(jnp.int32)
+    """(Kt//2, Nt) uint8 -> low and high nibbles as (Kt//2, Nt) fp32 in
+    [-8, 7] (even K rows = low nibble, odd = high)."""
+    b = packed.astype(jnp.int32)
+    lo = b & 0xF
+    hi = (b >> 4) & 0xF
     lo = lo - 16 * (lo >= 8)
     hi = hi - 16 * (hi >= 8)
-    half, nt = packed.shape
-    return jnp.stack([lo, hi], axis=1).reshape(2 * half, nt).astype(jnp.float32)
+    return lo.astype(jnp.float32), hi.astype(jnp.float32)
 
 
-def _int4_kernel(x_ref, q_ref, scale_ref, out_ref, acc_scr, *, n_k, group):
+def _int4_kernel(xe_ref, xo_ref, q_ref, scale_ref, out_ref, acc_scr, *, n_k,
+                 group):
     kidx = pl.program_id(2)
 
     @pl.when(kidx == 0)
     def _init():
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    x = x_ref[...].astype(jnp.float32)                   # (Mt, Kt)
-    w = _unpack_int4(q_ref[...])                         # (Kt, Nt)
+    lo, hi = _unpack_int4(q_ref[...])                    # (Kt//2, Nt) each
     s = scale_ref[...]                                   # (Kt//group, Nt)
-    w = w * jnp.repeat(s, group, axis=0)
-    acc_scr[...] += jnp.dot(x, w, preferred_element_type=jnp.float32)
+    ng, nt = s.shape
+    half = group // 2                # packed rows per scale group
+
+    def scaled(w):
+        return (w.reshape(ng, half, nt) * s[:, None, :]).reshape(ng * half, nt)
+
+    xe = xe_ref[...].astype(jnp.float32)                 # (Mt, Kt//2)
+    xo = xo_ref[...].astype(jnp.float32)
+    acc_scr[...] += (
+        jnp.dot(xe, scaled(lo), preferred_element_type=jnp.float32)
+        + jnp.dot(xo, scaled(hi), preferred_element_type=jnp.float32))
 
     @pl.when(kidx == n_k - 1)
     def _done():
@@ -112,18 +125,24 @@ def quant_matmul(x, q, scale, *, bits: int, group: int = 0, interpret=True):
                            bits=bits, group=group, interpret=interpret)
         return out[:M]
     nt = _pick_tile(N, N_TILE)
-    k_mult = max(group, 2) if bits == 4 else 1
-    kt = _pick_tile(K, K_TILE, k_mult)
-    grid = (M // mt, N // nt, K // kt)
     if bits == 8:
+        kt = _pick_tile(K, K_TILE)
+        grid = (M // mt, N // nt, K // kt)
         kernel = functools.partial(_int8_kernel, n_k=grid[2])
+        x_specs = [pl.BlockSpec((mt, kt), lambda m, n, k: (m, k))]
+        xs = (x,)
         q_spec = pl.BlockSpec((kt, nt), lambda m, n, k: (k, n))
         s_spec = pl.BlockSpec((1, nt), lambda m, n, k: (0, n))
     elif bits == 4:
-        assert kt % 2 == 0 and (group == 0 or kt % group == 0), (kt, group)
-        g = group if group else kt
+        g = group if group else K
+        assert g % 2 == 0 and K % g == 0, (K, group)
         assert scale.shape[0] == K // g, (scale.shape, K, g)
+        # a K tile holds a multiple of 8 scale groups (or all of K)
+        kt = _pick_tile(K, max(K_TILE, 8 * g), 8 * g)
+        grid = (M // mt, N // nt, K // kt)
         kernel = functools.partial(_int4_kernel, n_k=grid[2], group=g)
+        x_specs = [pl.BlockSpec((mt, kt // 2), lambda m, n, k: (m, k))] * 2
+        xs = (x[:, 0::2], x[:, 1::2])
         q_spec = pl.BlockSpec((kt // 2, nt), lambda m, n, k: (k, n))
         s_spec = pl.BlockSpec((kt // g, nt), lambda m, n, k: (k, n))
     else:
@@ -131,10 +150,9 @@ def quant_matmul(x, q, scale, *, bits: int, group: int = 0, interpret=True):
     return pl.pallas_call(
         kernel,
         grid=grid,
-        in_specs=[pl.BlockSpec((mt, kt), lambda m, n, k: (m, k)),
-                  q_spec, s_spec],
+        in_specs=x_specs + [q_spec, s_spec],
         out_specs=pl.BlockSpec((mt, nt), lambda m, n, k: (m, n)),
         out_shape=jax.ShapeDtypeStruct((M, N), jnp.float32),
         scratch_shapes=[pltpu.VMEM((mt, nt), jnp.float32)],
         interpret=interpret,
-    )(x, q, scale)
+    )(*xs, q, scale)

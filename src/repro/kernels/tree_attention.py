@@ -6,12 +6,18 @@ query rows per (batch, kv-head) whose validity is an *ancestor mask* (each
 node sees its root path plus the committed prefix) instead of plain
 causality. The kernel streams KV tiles HBM->VMEM with online-softmax
 accumulation, grid (batch, kv_head, kv_tiles); the kv-tile axis is
-minor/sequential so the (N, G) accumulators carry across tiles.
+minor/sequential so the per-row accumulators carry across tiles.
 
-GQA layout mirrors ``flash_decode``: q (B, Hkv, N, G, hd) with
-G = num_heads // num_kv_heads. Each grid step computes an
-(N*G, hd) x (hd, St) score matmul and an (N*G, St) x (St, hd) value matmul —
-MXU-shaped for N*G multiples of 8 and hd in {64, 128, 256}.
+GQA layout: q (B, Hkv, N, G, hd) with G = num_heads // num_kv_heads. The
+wrapper flattens each (batch, kv-head)'s queries to R = N*G rows, so every
+grid step is one (R, hd) x (hd, St) score matmul and one (R, St) x (St, hd)
+value matmul. Flash-decode is the N = 1 case of the same kernel.
+
+Block layout (the TPU's (8, 128) tiling applies to a block's last two
+dims): the wrapper moves K/V to (B, Hkv, S, hd) so a KV block is an
+(St, hd) tile, and expands the mask to one int32 row per query row,
+(B, R, S), so a mask block is an (R, St) tile. Row statistics live in
+(R, 1) scratch.
 
 The ancestor/validity mask arrives precomputed as (B, N, S) bool
 (``spectree.tree.tree_attn_mask`` ANDed with slot occupancy) — tree
@@ -32,7 +38,18 @@ NEG_INF = -1e30
 KV_TILE = 128
 
 
-def _tree_kernel(q_ref, k_ref, v_ref, mask_ref, out_ref,
+def kv_window(n: int, page: int = 1) -> int:
+    """Smallest KV width >= ``n``, a whole number of ``page``-slot pages,
+    that the kernel tiles: up to one tile any width (the block is then the
+    whole axis), beyond it a multiple of ``KV_TILE``."""
+    w = -(-n // page) * page
+    if w <= KV_TILE:
+        return w
+    step = math.lcm(page, KV_TILE)
+    return -(-n // step) * step
+
+
+def _attn_kernel(q_ref, k_ref, v_ref, mask_ref, out_ref,
                  acc_scr, m_scr, l_scr, *, n_tiles, scale, softcap):
     tidx = pl.program_id(2)
 
@@ -42,32 +59,59 @@ def _tree_kernel(q_ref, k_ref, v_ref, mask_ref, out_ref,
         m_scr[...] = jnp.full_like(m_scr, NEG_INF)
         l_scr[...] = jnp.zeros_like(l_scr)
 
-    q = q_ref[0, 0].astype(jnp.float32)                  # (N, G, hd)
-    k = k_ref[0, :, 0].astype(jnp.float32)               # (St, hd)
-    v = v_ref[0, :, 0].astype(jnp.float32)               # (St, hd)
-    mask = mask_ref[0]                                   # (N, St)
-    N, G, hd = q.shape
-    St = k.shape[0]
+    q = q_ref[0, 0].astype(jnp.float32)                  # (R, hd)
+    k = k_ref[0, 0].astype(jnp.float32)                  # (St, hd)
+    v = v_ref[0, 0].astype(jnp.float32)                  # (St, hd)
+    mask = mask_ref[0] != 0                              # (R, St)
 
-    s = jnp.dot(q.reshape(N * G, hd), k.T) * scale       # (N*G, St)
-    s = s.reshape(N, G, St)
+    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                            preferred_element_type=jnp.float32) * scale
     if softcap is not None:
         s = softcap * jnp.tanh(s / softcap)
-    s = jnp.where(mask[:, None, :], s, NEG_INF)
+    s = jnp.where(mask, s, NEG_INF)
 
-    m_new = jnp.maximum(m_scr[...], jnp.max(s, axis=2))  # (N, G)
-    alpha = jnp.exp(m_scr[...] - m_new)
-    p = jnp.exp(s - m_new[:, :, None])                   # (N, G, St)
-    l_scr[...] = l_scr[...] * alpha + p.sum(axis=2)
-    pv = jnp.dot(p.reshape(N * G, St), v).reshape(N, G, hd)
-    acc_scr[...] = acc_scr[...] * alpha[:, :, None] + pv
+    m_prev = m_scr[...]                                  # (R, 1)
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+    alpha = jnp.exp(m_prev - m_new)
+    p = jnp.exp(s - m_new)                               # (R, St)
+    l_scr[...] = l_scr[...] * alpha + jnp.sum(p, axis=1, keepdims=True)
+    acc_scr[...] = acc_scr[...] * alpha + jnp.dot(
+        p, v, preferred_element_type=jnp.float32)
     m_scr[...] = m_new
 
     @pl.when(tidx == n_tiles - 1)
     def _done():
-        out_ref[0, 0] = (acc_scr[...] /
-                         jnp.maximum(l_scr[...], 1e-30)[:, :, None]
+        out_ref[0, 0] = (acc_scr[...] / jnp.maximum(l_scr[...], 1e-30)
                          ).astype(out_ref.dtype)
+
+
+def masked_attention(q, k, v, mask, softcap=None, interpret=True):
+    """q: (B, Hkv, R, hd) query rows; k/v: (B, S, Hkv, hd); mask: (B, R, S).
+
+    Returns (B, Hkv, R, hd) fp32: each query row attends exactly the slots
+    its mask row allows (rows are shared by every kv head)."""
+    B, Hkv, R, hd = q.shape
+    S = k.shape[1]
+    st = min(KV_TILE, S)
+    assert S % st == 0, (S, st)
+    grid = (B, Hkv, S // st)
+    kt = jnp.swapaxes(k, 1, 2)                           # (B, Hkv, S, hd)
+    vt = jnp.swapaxes(v, 1, 2)
+    return pl.pallas_call(
+        functools.partial(_attn_kernel, n_tiles=grid[2],
+                          scale=1.0 / math.sqrt(hd), softcap=softcap),
+        grid=grid,
+        in_specs=[pl.BlockSpec((1, 1, R, hd), lambda b, h, s: (b, h, 0, 0)),
+                  pl.BlockSpec((1, 1, st, hd), lambda b, h, s: (b, h, s, 0)),
+                  pl.BlockSpec((1, 1, st, hd), lambda b, h, s: (b, h, s, 0)),
+                  pl.BlockSpec((1, R, st), lambda b, h, s: (b, 0, s))],
+        out_specs=pl.BlockSpec((1, 1, R, hd), lambda b, h, s: (b, h, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((B, Hkv, R, hd), jnp.float32),
+        scratch_shapes=[pltpu.VMEM((R, hd), jnp.float32),
+                        pltpu.VMEM((R, 1), jnp.float32),
+                        pltpu.VMEM((R, 1), jnp.float32)],
+        interpret=interpret,
+    )(q, kt, vt, mask.astype(jnp.int32))
 
 
 def tree_attention(q, k, v, mask, softcap=None, interpret=True):
@@ -78,23 +122,7 @@ def tree_attention(q, k, v, mask, softcap=None, interpret=True):
     committed prefix).
     """
     B, Hkv, N, G, hd = q.shape
-    S = k.shape[1]
-    st = min(KV_TILE, S)
-    assert S % st == 0, (S, st)
-    grid = (B, Hkv, S // st)
-    return pl.pallas_call(
-        functools.partial(_tree_kernel, n_tiles=grid[2],
-                          scale=1.0 / math.sqrt(hd), softcap=softcap),
-        grid=grid,
-        in_specs=[pl.BlockSpec((1, 1, N, G, hd), lambda b, h, s: (b, h, 0, 0, 0)),
-                  pl.BlockSpec((1, st, 1, hd), lambda b, h, s: (b, s, h, 0)),
-                  pl.BlockSpec((1, st, 1, hd), lambda b, h, s: (b, s, h, 0)),
-                  pl.BlockSpec((1, N, st), lambda b, h, s: (b, 0, s))],
-        out_specs=pl.BlockSpec((1, 1, N, G, hd),
-                               lambda b, h, s: (b, h, 0, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, Hkv, N, G, hd), jnp.float32),
-        scratch_shapes=[pltpu.VMEM((N, G, hd), jnp.float32),
-                        pltpu.VMEM((N, G), jnp.float32),
-                        pltpu.VMEM((N, G), jnp.float32)],
-        interpret=interpret,
-    )(q, k, v, mask)
+    rows = jnp.repeat(mask, G, axis=1)                   # row n*G+g <- node n
+    out = masked_attention(q.reshape(B, Hkv, N * G, hd), k, v, rows,
+                           softcap=softcap, interpret=interpret)
+    return out.reshape(B, Hkv, N, G, hd)

@@ -1,5 +1,6 @@
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+os.environ["JAX_PLATFORMS"] = "cpu"     # a CPU compile tool: never take a chip
 
 """Multi-pod dry-run: lower + compile every (arch x input-shape) step on the
 production meshes, print memory/cost analysis, and emit roofline terms.
@@ -11,7 +12,9 @@ Usage:
 
 The 512-placeholder-device XLA flag above MUST precede every other import
 (jax locks the device count on first init) and must never leak into smoke
-tests or benches — hence dryrun-only."""
+tests or benches — hence dryrun-only. The CPU platform is pinned next to
+it, for this process and the ``--all`` children that inherit the
+environment, so a dry run on a machine with a TPU never holds the chip."""
 
 import argparse
 import json
@@ -85,8 +88,6 @@ def run_one(arch: str, shape_name: str, multi_pod: bool, tc=None,
         mem_info = {"error": str(e)[:200]}
     try:
         cost = compiled.cost_analysis()
-        if isinstance(cost, (list, tuple)):
-            cost = cost[0]
     except Exception as e:
         cost = {"error": str(e)[:200]}
     hlo = compiled.as_text()

@@ -80,11 +80,14 @@ latencies:
 from __future__ import annotations
 
 import argparse
+from dataclasses import dataclass
+from typing import Any, Optional
 
 import jax
 import numpy as np
 
 from ..configs import ARCHS, QuantConfig, get_config, reduced
+from ..configs.base import ModelConfig
 from ..core.datagen import DatagenConfig, generate_distillation_dataset
 from ..core.metrics import SDStats, latency_percentiles, mbsu
 from ..core.speculative import SDConfig
@@ -96,13 +99,153 @@ from ..obs import (MetricsRegistry, SLOConfig, Tracer, acceptance_report,
 from ..quant import quantize_params
 from ..serving import ContinuousEngine, Request, ServeRequest, ServingEngine
 from ..spectree import TreeSpec, tree_speculative_generate
+from .compile_cache import enable_compile_cache
 
 
 def count_params(params) -> int:
     return sum(int(np.prod(l.shape)) for l in jax.tree.leaves(params))
 
 
-def main():
+def init_weights(model: Model, key):
+    """Random weights built inside one jit: each float32 draw fuses with its
+    cast to the param dtype, so no float32 copy of a stacked layer tensor is
+    ever materialized next to the weights."""
+    return jax.jit(lambda k: model.init(k)[0])(key)
+
+
+@dataclass
+class Served:
+    """Target and drafter as the launcher serves them."""
+    cfg: ModelConfig
+    target: Model
+    t_params: Any
+    draft: Any                  # Model, or HeadDrafter with --draft-head
+    d_params: Any
+    draft_name: str
+    c: float                    # drafter/target parameter ratio (MBSU)
+
+
+def build_models(args, rng) -> Served:
+    """Target + drafter for ``args`` (see ``parse_args``). Served weights are
+    held in the compute dtype (bf16); training keeps float32 masters.
+    ``rng`` draws the AWQ calibration seeds under --quant-weights."""
+    cfg = get_config(args.arch)
+    if args.reduced:
+        cfg = reduced(cfg)
+    if cfg.num_codebooks > 1:
+        # The SD engine streams one token id per step; multi-codebook audio
+        # decodes K ids per step (flattened-sum interleave, DESIGN.md §4).
+        # The demo launcher serves the single-codebook variant; the full
+        # K-codebook decode path is exercised by dryrun + test_serving_system.
+        print(f"note: serving single-codebook variant of {cfg.name}")
+        cfg = cfg.replace(num_codebooks=1)
+    cfg = cfg.replace(param_dtype=cfg.dtype)
+    target = Model(cfg)
+    t_params = init_weights(target, jax.random.PRNGKey(0))
+    if args.draft_head is not None:
+        if args.no_draft:
+            raise SystemExit("--draft-head and --no-draft are exclusive")
+        if args.quant_weights is not None:
+            raise SystemExit("--quant-weights applies to the separate "
+                             "drafter model; not supported with --draft-head")
+        draft = HeadDrafter(HeadConfig.for_target(
+            args.draft_head, cfg, num_medusa_heads=args.medusa_heads))
+        if args.head_ckpt:
+            from ..checkpoint import load_draft_heads
+            d_params = load_draft_heads(args.head_ckpt, draft)
+        else:
+            d_params = draft.init(jax.random.PRNGKey(1))
+        draft_name = f"{args.draft_head}-head"
+        n_draft = draft.hc.param_count()
+    else:
+        d_cfg = cfg.drafter().replace(vocab_size=cfg.vocab_size,
+                                      param_dtype=cfg.dtype)
+        draft = Model(d_cfg)
+        d_params = init_weights(draft, jax.random.PRNGKey(1))
+        draft_name, n_draft = d_cfg.name, count_params(d_params)
+    c = n_draft / count_params(t_params)
+    print(f"arch={cfg.name} draft={draft_name} c={c:.4f}")
+
+    if args.quant_weights is not None:
+        if args.no_draft:
+            raise SystemExit("--quant-weights applies to the drafter")
+        qcfg = QuantConfig(weights=args.quant_weights,
+                           group_size=args.quant_group)
+        # AWQ calibration batches from the distillation datagen pipeline:
+        # target-generated responses are the drafter's serving distribution
+        seeds = rng.integers(3, cfg.vocab_size,
+                             (args.calib_seqs, args.prompt_len)).astype(np.int32)
+        calib = generate_distillation_dataset(
+            target, t_params, seeds,
+            DatagenConfig(temperatures=(0.0, 0.7), max_response_tokens=16,
+                          batch_size=args.calib_seqs))
+        d_params = quantize_params(draft, d_params, qcfg, calib_tokens=calib)
+        if args.quant_target:
+            t_params = quantize_params(target, t_params, qcfg,
+                                       calib_tokens=calib)
+        print(f"quantized weights={args.quant_weights} "
+              f"target={'yes' if args.quant_target else 'no'} "
+              f"kv={'int8' if args.quant_kv else 'fp'}")
+    return Served(cfg, target, t_params, draft, d_params, draft_name, c)
+
+
+def prompt_lengths(args, rng) -> np.ndarray:
+    if args.mixed_lens:
+        return rng.integers(max(args.prompt_len // 2, 1),
+                            2 * args.prompt_len + 1, args.requests)
+    return np.full(args.requests, args.prompt_len)
+
+
+def build_continuous(args, served: Served, rng, tracer=None, registry=None):
+    """The continuous engine for ``args`` and its requests, not yet
+    submitted. Returns (engine, requests, max_seq)."""
+    cfg, draft = served.cfg, served.draft
+    if args.traffic_mix is not None:
+        from ..traffic import make_mix
+        serve_reqs = make_mix(args.traffic_mix).build(
+            args.requests, args.arrival_rate, cfg.vocab_size, seed=0)
+        max_seq = max(len(r.prompt) + r.max_new_tokens for r in serve_reqs)
+    else:
+        lens = prompt_lengths(args, rng)
+        arrivals = (np.cumsum(rng.exponential(1.0 / args.arrival_rate,
+                                              args.requests))
+                    if args.arrival_rate > 0 else np.zeros(args.requests))
+        serve_reqs = [ServeRequest(
+            prompt=rng.integers(3, cfg.vocab_size, lens[i]).astype(np.int32),
+            max_new_tokens=args.max_new, request_id=i,
+            arrival_time_s=float(arrivals[i]))
+            for i in range(args.requests)]
+        max_seq = int(lens.max()) + args.max_new
+    head = isinstance(draft, HeadDrafter)
+    engine = ContinuousEngine(
+        target=served.target, target_params=served.t_params,
+        draft=None if head else draft,
+        draft_params=None if head else served.d_params,
+        draft_heads=draft if head else None,
+        draft_head_params=served.d_params if head else None,
+        sd=SDConfig(gamma=args.gamma, temperature=args.temperature,
+                    kv_quant=args.quant_kv),
+        tree=tree_spec(args),
+        max_batch=args.max_batch, max_seq_len=max_seq,
+        page_size=args.page_size, prefill_chunk=args.prefill_chunk,
+        policy=args.policy, aging_s=args.aging_s,
+        kv_quant=args.quant_kv, prefix_cache=args.prefix_cache,
+        tracer=tracer, registry=registry,
+        time_phases=args.time_phases, metrics_out=args.metrics_out,
+        quality=args.quality_telemetry,
+        flight_record=args.flight_record is not None,
+        flight_dir=args.flight_record or "flight",
+        slo=(SLOConfig(ttft_ms=args.slo_ttft_ms, tpot_ms=args.slo_tpot_ms)
+             if args.slo_ttft_ms is not None else None))
+    return engine, serve_reqs, max_seq
+
+
+def tree_spec(args) -> Optional[TreeSpec]:
+    return (TreeSpec((args.tree_branch,) * args.tree_depth)
+            if args.tree else None)
+
+
+def parse_args(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", choices=sorted(ARCHS), required=True)
     ap.add_argument("--reduced", action="store_true")
@@ -188,7 +331,7 @@ def main():
     ap.add_argument("--slo-tpot-ms", type=float, default=None,
                     help="TPOT SLO threshold; arms multi-window burn-rate "
                          "alerting (needs --slo-ttft-ms too)")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
     if args.quant_target and args.quant_weights is None:
         ap.error("--quant-target requires --quant-weights {int8,int4}")
     if args.traffic_mix is not None and not args.continuous:
@@ -207,76 +350,20 @@ def main():
     if (args.slo_ttft_ms is None) != (args.slo_tpot_ms is None):
         ap.error("--slo-ttft-ms and --slo-tpot-ms come as a pair (burn "
                  "rates are tracked per metric over the same windows)")
+    return args
 
-    cfg = get_config(args.arch)
-    if args.reduced:
-        cfg = reduced(cfg)
-    if cfg.num_codebooks > 1:
-        # The SD engine streams one token id per step; multi-codebook audio
-        # decodes K ids per step (flattened-sum interleave, DESIGN.md §4).
-        # The demo launcher serves the single-codebook variant; the full
-        # K-codebook decode path is exercised by dryrun + test_serving_system.
-        print(f"note: serving single-codebook variant of {cfg.name}")
-        cfg = cfg.replace(num_codebooks=1)
-    target = Model(cfg)
-    t_params, _ = target.init(jax.random.PRNGKey(0))
-    if args.draft_head is not None:
-        if args.no_draft:
-            raise SystemExit("--draft-head and --no-draft are exclusive")
-        if args.quant_weights is not None:
-            raise SystemExit("--quant-weights applies to the separate "
-                             "drafter model; not supported with --draft-head")
-        draft = HeadDrafter(HeadConfig.for_target(
-            args.draft_head, cfg, num_medusa_heads=args.medusa_heads))
-        if args.head_ckpt:
-            from ..checkpoint import load_draft_heads
-            d_params = load_draft_heads(args.head_ckpt, draft)
-        else:
-            d_params = draft.init(jax.random.PRNGKey(1))
-        draft_name = f"{args.draft_head}-head"
-        n_draft = draft.hc.param_count()
-    else:
-        d_cfg = cfg.drafter().replace(vocab_size=cfg.vocab_size)
-        draft = Model(d_cfg)
-        d_params, _ = draft.init(jax.random.PRNGKey(1))
-        draft_name, n_draft = d_cfg.name, None
 
+def main(argv=None):
+    args = parse_args(argv)
+    enable_compile_cache()
     rng = np.random.default_rng(0)
-    if args.mixed_lens:
-        lens = rng.integers(max(args.prompt_len // 2, 1),
-                            2 * args.prompt_len + 1, args.requests)
-    else:
-        lens = np.full(args.requests, args.prompt_len)
+    served = build_models(args, rng)
+    cfg, target, t_params = served.cfg, served.target, served.t_params
+    draft, d_params, c = served.draft, served.d_params, served.c
     sdc = SDConfig(gamma=args.gamma, temperature=args.temperature,
                    kv_quant=args.quant_kv)
-    if n_draft is None:
-        n_draft = count_params(d_params)
-    c = n_draft / count_params(t_params)
-    print(f"arch={cfg.name} draft={draft_name} c={c:.4f}")
 
-    if args.quant_weights is not None:
-        if args.no_draft:
-            raise SystemExit("--quant-weights applies to the drafter")
-        qcfg = QuantConfig(weights=args.quant_weights,
-                           group_size=args.quant_group)
-        # AWQ calibration batches from the distillation datagen pipeline:
-        # target-generated responses are the drafter's serving distribution
-        seeds = rng.integers(3, cfg.vocab_size,
-                             (args.calib_seqs, args.prompt_len)).astype(np.int32)
-        calib = generate_distillation_dataset(
-            target, t_params, seeds,
-            DatagenConfig(temperatures=(0.0, 0.7), max_response_tokens=16,
-                          batch_size=args.calib_seqs))
-        d_params = quantize_params(draft, d_params, qcfg, calib_tokens=calib)
-        if args.quant_target:
-            t_params = quantize_params(target, t_params, qcfg,
-                                       calib_tokens=calib)
-        print(f"quantized weights={args.quant_weights} "
-              f"target={'yes' if args.quant_target else 'no'} "
-              f"kv={'int8' if args.quant_kv else 'fp'}")
-
-    tree = (TreeSpec((args.tree_branch,) * args.tree_depth)
-            if args.tree else None)
+    tree = tree_spec(args)
     if tree is not None:
         if args.no_draft:
             raise SystemExit("--tree is speculative-only")
@@ -307,45 +394,12 @@ def main():
     if args.continuous:
         if args.no_draft:
             raise SystemExit("--continuous is speculative-only")
-        if args.traffic_mix is not None:
-            from ..traffic import make_mix
-            serve_reqs = make_mix(args.traffic_mix).build(
-                args.requests, args.arrival_rate, cfg.vocab_size, seed=0)
-            max_seq = max(len(r.prompt) + r.max_new_tokens for r in serve_reqs)
-        else:
-            arrivals = (np.cumsum(rng.exponential(1.0 / args.arrival_rate,
-                                                  args.requests))
-                        if args.arrival_rate > 0 else np.zeros(args.requests))
-            serve_reqs = [ServeRequest(
-                prompt=rng.integers(3, cfg.vocab_size,
-                                    lens[i]).astype(np.int32),
-                max_new_tokens=args.max_new, request_id=i,
-                arrival_time_s=float(arrivals[i]))
-                for i in range(args.requests)]
-            max_seq = int(lens.max()) + args.max_new
         head = isinstance(draft, HeadDrafter)
         tracer = Tracer() if args.trace_out else None
         registry = (MetricsRegistry()
                     if args.metrics_out or args.time_phases else None)
-        engine = ContinuousEngine(
-            target=target, target_params=t_params,
-            draft=None if head else draft,
-            draft_params=None if head else d_params,
-            draft_heads=draft if head else None,
-            draft_head_params=d_params if head else None,
-            sd=sdc, tree=tree,
-            max_batch=args.max_batch, max_seq_len=max_seq,
-            page_size=args.page_size, prefill_chunk=args.prefill_chunk,
-            policy=args.policy, aging_s=args.aging_s,
-            kv_quant=args.quant_kv, prefix_cache=args.prefix_cache,
-            tracer=tracer, registry=registry,
-            time_phases=args.time_phases, metrics_out=args.metrics_out,
-            quality=args.quality_telemetry,
-            flight_record=args.flight_record is not None,
-            flight_dir=args.flight_record or "flight",
-            slo=(SLOConfig(ttft_ms=args.slo_ttft_ms,
-                           tpot_ms=args.slo_tpot_ms)
-                 if args.slo_ttft_ms is not None else None))
+        engine, serve_reqs, max_seq = build_continuous(
+            args, served, rng, tracer=tracer, registry=registry)
         for r in serve_reqs:
             engine.submit(r)
         with jax_profile(args.jax_profile):
@@ -412,8 +466,8 @@ def main():
                 engine.phases, cfg, drafter_cfg,
                 batch=max(int(round(tel.mean_active_rows)), 1),
                 ctx=max_seq // 2, gamma=seq_draft_steps,
-                weights=args.quant_weights or "float32",
-                kv="int8" if args.quant_kv else "float32",
+                weights=args.quant_weights or cfg.param_dtype,
+                kv="int8" if args.quant_kv else cfg.dtype,
                 peak_gbps=args.peak_gbps)
             print("  " + format_attribution(rep).replace("\n", "\n  "))
         if registry is not None:
@@ -431,6 +485,7 @@ def main():
                   " — load at https://ui.perfetto.dev)")
         return
 
+    lens = prompt_lengths(args, rng)
     reqs = [Request(prompt=rng.integers(3, cfg.vocab_size,
                                         lens[i]).astype(np.int32),
                     max_new_tokens=args.max_new, request_id=i)

@@ -1,9 +1,9 @@
 """Training launcher.
 
-Production shape: builds the pod mesh, shards state via the logical rules,
-and drives the pretrain or distill loop. On this CPU container use
-``--reduced`` (reduced same-family config, synthetic corpus) — the full-size
-path is exercised via launch/dryrun.py.
+Drives the pretrain or distill loop on the default device; it builds no
+mesh (the sharded full-size step is compiled by launch/dryrun.py on virtual
+CPU devices). ``--reduced`` selects the reduced same-family config; the
+corpus is synthetic.
 
   PYTHONPATH=src python -m repro.launch.train --arch yi-9b --reduced \
       --steps 50 --phase pretrain
@@ -34,6 +34,7 @@ from ..data import SyntheticCorpus, pack_documents, simple_batches, mixed_batche
 from ..models.model import Model
 from ..training import make_train_state, train, finetune
 from ..checkpoint import save
+from .compile_cache import enable_compile_cache
 
 
 def main():
@@ -58,6 +59,7 @@ def main():
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--save", default=None)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:

@@ -28,7 +28,7 @@ from ..sharding import context as shctx
 NEG_INF = -2.0e38
 
 #: tree-attention fast-path override: None = auto (compiled Pallas only,
-#: i.e. on TPU when kernels.ops.INTERPRET is False), True/False = force.
+#: i.e. whenever kernels.ops.INTERPRET is False), True/False = force.
 TREE_FASTPATH = None
 
 
@@ -118,18 +118,23 @@ def _sdpa(q, k, v, mask, cfg):
 def _use_tree_kernel(S: int) -> bool:
     """Dispatch the Pallas tree-attention kernel for tree-masked decode?
 
-    Auto mode uses it only when Pallas compiles natively (TPU) — in
-    interpret mode the pure-JAX ``_sdpa`` is strictly faster — and only
-    when the KV length tiles (kernels.tree_attention.KV_TILE). A forced
-    ``TREE_FASTPATH = True`` dispatches unconditionally: an untileable KV
-    width then fails loudly in the kernel instead of silently measuring or
-    equivalence-testing the ``_sdpa`` path."""
+    Auto mode uses it whenever Pallas compiles natively (in interpret mode
+    the pure-JAX ``_sdpa`` is strictly faster). The KV width must then tile
+    (``kernels.tree_attention.kv_window`` — the engines size their windows
+    with it); a width that does not raises instead of quietly measuring or
+    equivalence-testing ``_sdpa``. ``TREE_FASTPATH`` True/False forces the
+    choice."""
     from ..kernels import ops, tree_attention as tk
     if TREE_FASTPATH is not None:
         return TREE_FASTPATH
-    if not (S < tk.KV_TILE or S % tk.KV_TILE == 0):
+    if ops.INTERPRET:
         return False
-    return not ops.INTERPRET
+    if tk.kv_window(S) != S:
+        raise ValueError(
+            f"tree verify over a {S}-slot KV window: the tree-attention "
+            f"kernel tiles widths up to {tk.KV_TILE} or multiples of it; "
+            "size the cache with kernels.tree_attention.kv_window")
+    return True
 
 
 def _tree_attend(q, k, v, mask, cfg):

@@ -19,7 +19,6 @@ from typing import Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from .layers import _normal
@@ -176,11 +175,11 @@ def moe_ffn(params, x, cfg, dense: bool = False):
     in_pspecs["w_up"] = P(None, gather_axes, maxis)
     in_pspecs["w_down"] = P(None, maxis, gather_axes)
     batch_spec = P(daxes, None, None) if daxes else P(None, None, None)
-    y, aux = shard_map(
+    y, aux = jax.shard_map(
         local_fn, mesh=mesh,
         in_specs=(in_pspecs, batch_spec),
         out_specs=(batch_spec, P()),
-        check_rep=False,
+        check_vma=False,
     )(params, x)
     return y, aux
 
@@ -267,10 +266,10 @@ def _moe_weight_stationary(params, x, cfg, mesh, daxes, gather_axes, maxis):
         "w_down": P(None, maxis, gather_axes),
     }
     batch_spec = P(daxes, None, None) if daxes else P(None, None, None)
-    y, aux = shard_map(
+    y, aux = jax.shard_map(
         local_fn, mesh=mesh,
         in_specs=(in_pspecs, batch_spec),
         out_specs=(batch_spec, P()),
-        check_rep=False,
+        check_vma=False,
     )(params, x)
     return y, aux

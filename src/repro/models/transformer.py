@@ -166,7 +166,8 @@ def _block_cache(cfg, kind, batch, max_len, dtype, long_context,
                  "pos": jnp.full((batch, size), -1, jnp.int32)}
         if kv_quant:
             s = jnp.zeros((batch, size, cfg.num_kv_heads), jnp.float32)
-            cache.update(k_scale=s, v_scale=s)
+            # distinct buffers: a donated cache may not alias two leaves
+            cache.update(k_scale=s, v_scale=jnp.zeros_like(s))
         return cache
     if kind == MAMBA:
         return ssm_mod.init_mamba_cache(cfg, batch, dtype)
@@ -221,7 +222,7 @@ def init_paged_cache(cfg, num_pages, page_size, kv_quant=False):
                  "page_pos": jnp.full((num_pages, page_size), -1, jnp.int32)}
         if kv_quant:
             s = jnp.zeros((num_pages, page_size, cfg.num_kv_heads), jnp.float32)
-            cache.update(k_scale=s, v_scale=s)
+            cache.update(k_scale=s, v_scale=jnp.zeros_like(s))
         return cache
 
     def stacked(kind, count):

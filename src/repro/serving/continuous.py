@@ -57,6 +57,7 @@ from ..core.speculative import (SDConfig, _cached_decode,
                                 _cached_tree_round_donated, attention_only,
                                 init_quality_buffer, trim_paged_cache)
 from ..draftheads import HeadDrafter
+from ..kernels.tree_attention import kv_window
 from ..models.model import Model
 from ..obs import (NULL_TRACER, FlightRecorder, PhaseTimer, QualityStats,
                    SLOTracker)
@@ -191,6 +192,11 @@ class ContinuousEngine:
         self._slack = (self.tree.num_nodes + 1) if self.tree else (g + 2)
         self._row_cap = self.max_seq_len + self._slack
         max_pages = ceil_div(self._row_cap + self.prefill_chunk, self.page_size)
+        if self.tree is not None:
+            # tree verify runs the tree-attention kernel over the gathered
+            # max_pages * page_size window: give it a width the kernel tiles
+            max_pages = kv_window(max_pages * self.page_size,
+                                  self.page_size) // self.page_size
         if self.num_pages is None:
             self.num_pages = 1 + self.max_batch * max_pages
         self.pool = PagedKVPool(self.num_pages, self.page_size, max_pages)

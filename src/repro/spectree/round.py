@@ -54,6 +54,7 @@ from ..core.speculative import (SDConfig, _leaf_batch_axis, _leaf_name,
                                 _prefill_state, attention_only,
                                 init_quality_buffer, masked_page_table,
                                 quality_buffer)
+from ..kernels.tree_attention import kv_window
 from ..models.model import Model
 from .tree import TreeSpec, tree_attn_mask
 
@@ -403,7 +404,8 @@ def tree_speculative_generate(draft, target: Model, d_params, t_params,
     from ..core.speculative import _cached_tree_round_donated
     key = key if key is not None else jax.random.PRNGKey(0)
     B, S = prompt.shape
-    max_total = S + max_new_tokens + spec.num_nodes + 2
+    # the tree-attention kernel needs a KV width it can tile
+    max_total = kv_window(S + max_new_tokens + spec.num_nodes + 2)
     k0, key = jax.random.split(key)
     state = _prefill_state(draft, target, d_params, t_params, prompt,
                            max_total, sdc, k0)

@@ -14,6 +14,7 @@ from repro.core.speculative import (SDConfig, autoregressive_generate,
                                     speculative_generate)
 from repro.draftheads import (HeadConfig, HeadDrafter, finetune_heads,
                               is_head_drafter, make_head_train_state)
+from repro.draftheads.heads import medusa_logits
 from repro.models import Model
 from repro.models.model import capture_hidden
 from repro.spectree import TreeSpec, tree_speculative_generate
@@ -79,10 +80,36 @@ def test_tree_temp0_matches_ar(target, drafters, kind):
 
 def test_medusa_untrained_warm_start(target, drafters):
     """Medusa's near-zero residual init makes every head ~= the target's own
-    next-token distribution, so even untrained heads accept drafts."""
+    next-token distribution at the hidden state it reads.
+
+    Head k drafts position +k from the same hidden state the target's LM
+    head reads, so at warm start a draft is accepted exactly where the
+    target's greedy continuation repeats its current token. Whether a random
+    target does that depends on the draw, so acceptance is checked on a
+    target that repeats by construction: tied embeddings and blocks zeroed
+    to the identity make every greedy step return the current token."""
     t, tp = target
     drafter, hp = drafters["medusa"]
-    _, stats = speculative_generate(drafter, t, hp, tp, _prompt(), 32,
+    with capture_hidden() as box:
+        lg, _ = t.logits(tp, _prompt())
+    heads = medusa_logits(hp, tp, TCFG, drafter.hc, box["hidden"])
+    # residual weights ~1e-2/sqrt(D) move logits by ~5e-3; bf16 logits near
+    # |3| are rounded to 1/64
+    assert jnp.allclose(heads, lg[..., None, :], atol=0.05), \
+        float(jnp.max(jnp.abs(heads - lg[..., None, :])))
+
+    cfg = TCFG.replace(tie_embeddings=True)
+    rep = Model(cfg)
+    rp, _ = rep.init(jax.random.PRNGKey(0))
+    g = rp["groups"][0]
+    g = {**g, "attn": {**g["attn"], "wo": jnp.zeros_like(g["attn"]["wo"])},
+         "mlp": {**g["mlp"], "w_down": jnp.zeros_like(g["mlp"]["w_down"])}}
+    rp = {**rp, "groups": (g,)}
+    rep_heads = HeadDrafter(HeadConfig.for_target("medusa", cfg,
+                                                  num_medusa_heads=4))
+    _, stats = speculative_generate(rep_heads, rep,
+                                    rep_heads.init(jax.random.PRNGKey(3)),
+                                    rp, _prompt(), 32,
                                     SDConfig(gamma=3, temperature=0.0))
     assert stats.tau > 1.05, stats.tau
 

@@ -124,3 +124,15 @@ def test_flash_decode_matches_model_decode_attention():
     out_k = out_k.reshape(B, 4, 64).reshape(B, 1, 256)
     out_k = jnp.einsum("bsh,hd->bsd", out_k.astype(x.dtype), params["wo"])
     assert jnp.allclose(out_ref, out_k, atol=1e-4)
+
+
+def test_interpret_mode_resolution(monkeypatch):
+    """Interpret mode only on the CPU backend or when asked for; nothing
+    else falls back to the interpreter."""
+    from repro.kernels import ops
+    monkeypatch.delenv("REPRO_PALLAS_INTERPRET", raising=False)
+    assert ops._env_interpret() == (jax.default_backend() == "cpu")
+    monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "0")
+    assert ops._env_interpret() is False
+    monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "1")
+    assert ops._env_interpret() is True

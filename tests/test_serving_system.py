@@ -82,3 +82,45 @@ def test_end_to_end_micro_pipeline():
     for task in ("dolly", "cnndm", "xsum"):
         assert 1.0 <= res.tau["tvdpp"][task]["3"] <= 4.0
     assert res.ood["base"] >= 1.0
+
+
+def test_serve_builds_bf16_weights_under_jit():
+    """launch.serve holds served weights in the compute dtype (bf16), drawn
+    under one jit with the same values as the eager float32 init cast."""
+    from repro.launch import serve
+    args = serve.parse_args(["--arch", "phi4-mini-3.8b", "--reduced"])
+    served = serve.build_models(args, np.random.default_rng(0))
+    for params in (served.t_params, served.d_params):
+        for path, leaf in jax.tree_util.tree_leaves_with_path(params):
+            want = jnp.float32 if "norm" in str(path) else jnp.bfloat16
+            assert leaf.dtype == want, path
+    eager, _ = Model(served.cfg.replace(param_dtype="float32")).init(
+        jax.random.PRNGKey(0))
+    for got, ref in zip(jax.tree.leaves(served.t_params),
+                        jax.tree.leaves(eager)):
+        np.testing.assert_array_equal(np.asarray(got, np.float32),
+                                      np.asarray(ref.astype(got.dtype),
+                                                 np.float32))
+
+
+def test_compile_cache_placement(monkeypatch):
+    """JAX_COMPILATION_CACHE_DIR wins when set; otherwise one fixed
+    directory at the root of the checkout."""
+    from pathlib import Path
+
+    from jax.experimental.compilation_cache import compilation_cache
+    from repro.launch.compile_cache import CACHE_DIR, enable_compile_cache
+    prev = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere/cache")
+        assert enable_compile_cache() == "/elsewhere/cache"
+        assert jax.config.jax_compilation_cache_dir == prev
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        assert enable_compile_cache() == str(CACHE_DIR)
+        assert jax.config.jax_compilation_cache_dir == str(CACHE_DIR)
+        root = Path(__file__).resolve().parents[1]
+        assert CACHE_DIR == root / ".jax_cache"
+        assert ".jax_cache/" in (root / ".gitignore").read_text().split()
+    finally:
+        jax.config.update("jax_compilation_cache_dir", prev)
+        compilation_cache.reset_cache()

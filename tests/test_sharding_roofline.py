@@ -75,8 +75,6 @@ def test_flops_model_vs_cost_analysis_unrolled():
 
     compiled = jax.jit(fwd).lower(params, toks).compile()
     ca = compiled.cost_analysis()
-    if isinstance(ca, list):          # older jax returns one dict per device
-        ca = ca[0]
     xla_flops = ca["flops"]
     shape = ShapeConfig("x", S, B, "prefill")
     ours = flops_model(cfg, shape)

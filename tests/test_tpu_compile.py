@@ -1,0 +1,104 @@
+"""The Pallas kernels compile for a TPU v5e at real widths.
+
+Interpret mode cannot see the TPU's tiling rules (a block's last two dims
+must tile (8, 128) or span the array), so every kernel is compiled here with
+``interpret=False`` for one chip of a described v5e topology — the chip's
+own compiler, no chip needed. The topology is described inside a fixture,
+never at import: only the process that runs these tests loads the TPU
+library.
+"""
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.kernels import distill_loss, flash_decode, quant_matmul, \
+    tree_attention
+
+B, S, HD = 8, 1024, 128
+TREE_NODES = 7                     # a (2, 2) tree: root + 2 + 4
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:   # noqa: BLE001 - any failure means no TPU compiler
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def no_compile_cache():
+    """A compile for a described chip is written to the persistent cache but
+    cannot be read back without one: keep the cache off around them."""
+    from jax.experimental.compilation_cache import compilation_cache
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", prev)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture
+def compile_tpu(one_chip, no_compile_cache):
+    def run(fn, *shapes):
+        args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+                for s, d in shapes]
+        hlo = jax.jit(fn).lower(*args).compile().as_text()
+        assert "tpu_custom_call" in hlo      # the kernel, not a fallback
+    return run
+
+
+@pytest.mark.parametrize("hkv,g", [(8, 3), (32, 1)])
+def test_tree_attention_compiles(compile_tpu, hkv, g):
+    compile_tpu(
+        lambda q, k, v, m: tree_attention.tree_attention(q, k, v, m,
+                                                         interpret=False),
+        ((B, hkv, TREE_NODES, g, HD), jnp.bfloat16),
+        ((B, S, hkv, HD), jnp.bfloat16), ((B, S, hkv, HD), jnp.bfloat16),
+        ((B, TREE_NODES, S), jnp.bool_))
+
+
+@pytest.mark.parametrize("hkv,g", [(8, 3), (32, 1)])
+def test_flash_decode_compiles(compile_tpu, hkv, g):
+    compile_tpu(
+        lambda q, k, v, m: flash_decode.flash_decode(q, k, v, m,
+                                                     interpret=False),
+        ((B, hkv, g, HD), jnp.bfloat16),
+        ((B, S, hkv, HD), jnp.bfloat16), ((B, S, hkv, HD), jnp.bfloat16),
+        ((B, S), jnp.bool_))
+
+
+@pytest.mark.parametrize("bits,group", [(8, 0), (4, 64)])
+def test_quant_matmul_compiles(compile_tpu, bits, group):
+    K, N = 3072, 8192
+    if bits == 8:
+        w, s = ((K, N), jnp.int8), ((1, N), jnp.float32)
+    else:
+        w, s = ((K // 2, N), jnp.uint8), ((K // group, N), jnp.float32)
+    compile_tpu(
+        lambda x, q, sc: quant_matmul.quant_matmul(
+            x, q, sc, bits=bits, group=group, interpret=False),
+        ((8, K), jnp.bfloat16), w, s)
+
+
+def test_row_logsumexp_compiles(compile_tpu):
+    compile_tpu(lambda x: distill_loss.row_logsumexp(x, interpret=False),
+                ((512, 32000), jnp.float32))
+
+
+def test_loss_terms_compiles(compile_tpu):
+    f = jnp.float32
+    compile_tpu(
+        lambda s, t, ls, lt, mu, isg: distill_loss.loss_terms(
+            s, t, ls, lt, mu, isg, interpret=False),
+        ((512, 32000), f), ((512, 32000), f), ((512,), f), ((512,), f),
+        ((), f), ((), f))
