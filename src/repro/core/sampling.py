@@ -10,6 +10,7 @@ import jax
 import jax.numpy as jnp
 
 
+@jax.named_scope("sampling")
 def probs_from_logits(logits, temperature: float = 1.0, top_p: float = 1.0):
     """logits (..., V) -> sampling distribution (..., V), fp32.
 
@@ -33,6 +34,7 @@ def probs_from_logits(logits, temperature: float = 1.0, top_p: float = 1.0):
     return p
 
 
+@jax.named_scope("sampling")
 def sample_from_probs(key, probs):
     """Categorical sample; probs (..., V) -> ids (...)."""
     return jax.random.categorical(key, jnp.log(jnp.maximum(probs, 1e-30)), -1)
@@ -43,6 +45,7 @@ def sample(key, logits, temperature: float = 1.0, top_p: float = 1.0):
     return sample_from_probs(key, p), p
 
 
+@jax.named_scope("sampling")
 def residual_sample(key, q, p):
     """Leviathan rejection-sampling residual: sample from norm(max(q - p, 0)).
 

@@ -198,6 +198,7 @@ def masked_page_table(state):
     return jnp.where(active[:, None], page_table, 0)
 
 
+@jax.named_scope("draft")
 def sd_draft_phase(draft, target: Model, sdc: SDConfig,
                    d_params, t_params, state, key):
     """Draft phase of a chain round: sample x_1..x_gamma and their draft
@@ -265,6 +266,7 @@ def sd_draft_phase(draft, target: Model, sdc: SDConfig,
     return {"x": x, "p_stack": p_stack, "d_cache": d_cache, "d_snaps": d_snaps}
 
 
+@jax.named_scope("verify")
 def sd_verify_phase(draft, target: Model, sdc: SDConfig,
                     t_params, state, draft_out):
     """Target verify: score the gamma+1 speculated tokens. Returns
@@ -311,6 +313,7 @@ def sd_verify_phase(draft, target: Model, sdc: SDConfig,
             "t_hid": t_hid}
 
 
+@jax.named_scope("commit")
 def sd_commit_phase(draft, target: Model, sdc: SDConfig,
                     state, draft_out, verify_out, key):
     """Acceptance + residual sampling + token commit + cache rewind.

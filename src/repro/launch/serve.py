@@ -56,7 +56,9 @@ periodic metrics-registry snapshots (final Prometheus exposition to
 per-phase jits and prints the draft/verify/commit/host wall-time split plus
 a roofline-vs-measured report (``--peak-gbps`` turns achieved GB/s into an
 MBU estimate), and ``--jax-profile DIR`` captures a jax.profiler device
-trace of the serve loop:
+trace of the serve loop, whose ops carry the named scopes draft / verify /
+commit, attention / kv_write / kv_gather / sdpa, mlp, lm_head and sampling
+(with ``--trace-out`` too, the engine's spans land on its host plane):
 
   PYTHONPATH=src python -m repro.launch.serve --arch yi-9b --reduced \
       --continuous --requests 8 --trace-out trace.json --time-phases \
@@ -395,7 +397,9 @@ def main(argv=None):
         if args.no_draft:
             raise SystemExit("--continuous is speculative-only")
         head = isinstance(draft, HeadDrafter)
-        tracer = Tracer() if args.trace_out else None
+        # with a device trace too, the spans also land on its host plane
+        tracer = (Tracer(annotate=bool(args.jax_profile))
+                  if args.trace_out else None)
         registry = (MetricsRegistry()
                     if args.metrics_out or args.time_phases else None)
         engine, serve_reqs, max_seq = build_continuous(

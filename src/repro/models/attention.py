@@ -301,24 +301,32 @@ def paged_decode_attention(params, x, cache, page_table, pos, cfg,
     new_cache = {}
     if kv_quant:
         from ..quant.kvcache import dequantize_kv_entry, quantize_kv_entry
-        kq, ks = quantize_kv_entry(k)
-        vq, vs = quantize_kv_entry(v)
-        kpool = kpool.at[phys, off].set(kq)
-        vpool = vpool.at[phys, off].set(vq)
-        k_scale = cache["k_scale"].at[phys, off].set(ks)
-        v_scale = cache["v_scale"].at[phys, off].set(vs)
-        kc = dequantize_kv_entry(kpool[page_table], k_scale[page_table],
-                                 q.dtype).reshape(B, W, Hkv, hd)
-        vc = dequantize_kv_entry(vpool[page_table], v_scale[page_table],
-                                 q.dtype).reshape(B, W, Hkv, hd)
-        new_cache.update(k_scale=k_scale, v_scale=v_scale)
-    else:
-        kpool = kpool.at[phys, off].set(k.astype(kpool.dtype))
-        vpool = vpool.at[phys, off].set(v.astype(vpool.dtype))
-        kc = kpool[page_table].reshape(B, W, Hkv, hd).astype(q.dtype)
-        vc = vpool[page_table].reshape(B, W, Hkv, hd).astype(q.dtype)
-    page_pos = page_pos.at[phys, off].set(write_pos.astype(jnp.int32))
-    cp = jnp.where((page_table == 0)[:, :, None], -1, page_pos[page_table])
+    # device scopes: the pool scatter, the per-row gather of every mapped
+    # page (with its reshape and cast), and the attention over the view
+    with jax.named_scope("kv_write"):
+        if kv_quant:
+            kq, ks = quantize_kv_entry(k)
+            vq, vs = quantize_kv_entry(v)
+            kpool = kpool.at[phys, off].set(kq)
+            vpool = vpool.at[phys, off].set(vq)
+            k_scale = cache["k_scale"].at[phys, off].set(ks)
+            v_scale = cache["v_scale"].at[phys, off].set(vs)
+            new_cache.update(k_scale=k_scale, v_scale=v_scale)
+        else:
+            kpool = kpool.at[phys, off].set(k.astype(kpool.dtype))
+            vpool = vpool.at[phys, off].set(v.astype(vpool.dtype))
+        page_pos = page_pos.at[phys, off].set(write_pos.astype(jnp.int32))
+    with jax.named_scope("kv_gather"):
+        if kv_quant:
+            kc = dequantize_kv_entry(kpool[page_table], k_scale[page_table],
+                                     q.dtype).reshape(B, W, Hkv, hd)
+            vc = dequantize_kv_entry(vpool[page_table], v_scale[page_table],
+                                     q.dtype).reshape(B, W, Hkv, hd)
+        else:
+            kc = kpool[page_table].reshape(B, W, Hkv, hd).astype(q.dtype)
+            vc = vpool[page_table].reshape(B, W, Hkv, hd).astype(q.dtype)
+        cp = page_pos[page_table]
+    cp = jnp.where((page_table == 0)[:, :, None], -1, cp)
     cp = cp.reshape(B, W)
     if attn_mask is None:
         m = (cp[:, None, :] >= 0) & (cp[:, None, :] <= pos[:, :, None])
@@ -326,7 +334,8 @@ def paged_decode_attention(params, x, cache, page_table, pos, cfg,
         m = (cp[:, None, :] >= 0) & attn_mask
     if window is not None:
         m &= cp[:, None, :] > pos[:, :, None] - window
-    out = _attend(q, kc, vc, m, cfg, tree=attn_mask is not None)
+    with jax.named_scope("sdpa"):
+        out = _attend(q, kc, vc, m, cfg, tree=attn_mask is not None)
     out = out.reshape(B, T, cfg.num_heads * cfg.head_dim_)
     out = matmul_param(out, params["wo"])
     new_cache.update(k=kpool, v=vpool, page_pos=page_pos)

@@ -86,19 +86,21 @@ def apply_block(params, x, kind, cfg, mode, positions, cache,
     h = rms_norm(x, params["norm1"], cfg.norm_eps)
     window = _block_window(cfg, kind, long_context)
     if kind in _ATTN_KINDS:
-        if mode == "train":
-            y, new_cache = attn_mod.causal_attention(params["attn"], h, positions, cfg, window), None
-        elif mode == "prefill":
-            y, new_cache = attn_mod.prefill_attention(
-                params["attn"], h, positions, cfg, cache_len, window)
-        elif page_table is not None:
-            y, new_cache = attn_mod.paged_decode_attention(
-                params["attn"], h, cache, page_table, positions, cfg, window,
-                slots=slots, attn_mask=attn_mask)
-        else:
-            y, new_cache = attn_mod.decode_attention(
-                params["attn"], h, cache, positions, cfg, window,
-                slots=slots, attn_mask=attn_mask)
+        with jax.named_scope("attention"):
+            if mode == "train":
+                y, new_cache = attn_mod.causal_attention(
+                    params["attn"], h, positions, cfg, window), None
+            elif mode == "prefill":
+                y, new_cache = attn_mod.prefill_attention(
+                    params["attn"], h, positions, cfg, cache_len, window)
+            elif page_table is not None:
+                y, new_cache = attn_mod.paged_decode_attention(
+                    params["attn"], h, cache, page_table, positions, cfg,
+                    window, slots=slots, attn_mask=attn_mask)
+            else:
+                y, new_cache = attn_mod.decode_attention(
+                    params["attn"], h, cache, positions, cfg, window,
+                    slots=slots, attn_mask=attn_mask)
     elif kind == MAMBA:
         if mode == "decode":
             y, st = ssm_mod.mamba_decode(params["mamba"], h, cfg,
@@ -120,12 +122,13 @@ def apply_block(params, x, kind, cfg, mode, positions, cache,
         raise ValueError(kind)
     x = x + y
     if _has_ffn(cfg, kind):
-        h = rms_norm(x, params["norm2"], cfg.norm_eps)
-        if cfg.is_moe:
-            y, aux = moe_ffn(params["moe"], h, cfg)
-        else:
-            h = _maybe_seq_shard_ffn(h)      # §Perf it.3: context-parallel FFN
-            y = swiglu(params["mlp"], h)
+        with jax.named_scope("mlp"):
+            h = rms_norm(x, params["norm2"], cfg.norm_eps)
+            if cfg.is_moe:
+                y, aux = moe_ffn(params["moe"], h, cfg)
+            else:
+                h = _maybe_seq_shard_ffn(h)  # §Perf it.3: context-parallel FFN
+                y = swiglu(params["mlp"], h)
         x = x + y
     return x, new_cache, aux
 
@@ -369,6 +372,7 @@ def backbone(params, tokens, cfg, mode="train", positions=None, cache=None,
     return x, out_cache, aux_total
 
 
+@jax.named_scope("lm_head")
 def logits_from_hidden(params, hidden, cfg):
     w = params["embed"] if cfg.tie_embeddings else params["lm_head"]
     if cfg.tie_embeddings:

@@ -1,4 +1,4 @@
-from .trace import Tracer, NULL_TRACER, get_tracer, set_tracer, span  # noqa: F401
+from .trace import Tracer, NULL_TRACER                                # noqa: F401
 from .registry import (Counter, Gauge, Histogram,                     # noqa: F401
                        MetricsRegistry, log_buckets)
 from .phases import PhaseTimer, jax_profile                           # noqa: F401

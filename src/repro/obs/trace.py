@@ -21,6 +21,11 @@ tuple per event to a plain list; JSON serialization happens only in
 ``write()``. All timestamps are ``time.perf_counter()`` seconds, exported as
 microseconds relative to the first event (Perfetto-loadable via
 ``chrome://tracing`` or https://ui.perfetto.dev).
+
+On the profiler clock: ``Tracer(annotate=True)`` also enters a
+``jax.profiler.TraceAnnotation`` of the span's name for every span, so that
+a ``jax.profiler`` capture holds the spans on its host plane beside the
+device ops (``launch/serve.py --trace-out --jax-profile``).
 """
 from __future__ import annotations
 
@@ -28,6 +33,8 @@ import json
 import threading
 import time
 from typing import Optional
+
+import jax
 
 
 class _NullSpan:
@@ -45,12 +52,16 @@ _NULL_SPAN = _NullSpan()
 
 
 class _Span:
-    __slots__ = ("tracer", "name", "args", "t0")
+    __slots__ = ("tracer", "name", "args", "t0", "ann")
 
     def __init__(self, tracer: "Tracer", name: str, args: Optional[dict]):
         self.tracer, self.name, self.args = tracer, name, args
+        self.ann = None
 
     def __enter__(self):
+        if self.tracer.annotate:
+            self.ann = jax.profiler.TraceAnnotation(self.name)
+            self.ann.__enter__()
         self.t0 = time.perf_counter()
         return self
 
@@ -59,14 +70,18 @@ class _Span:
         self.tracer._events.append(
             ("X", self.name, self.t0, t1 - self.t0,
              threading.get_ident(), self.args))
+        if self.ann is not None:
+            self.ann.__exit__(None, None, None)
         return False
 
 
 class Tracer:
-    """Event buffer + span factory. ``enabled=False`` is (near) free."""
+    """Event buffer + span factory. ``enabled=False`` is (near) free;
+    ``annotate`` also puts each span on the jax profiler's host plane."""
 
-    def __init__(self, enabled: bool = True):
+    def __init__(self, enabled: bool = True, annotate: bool = False):
         self.enabled = enabled
+        self.annotate = annotate
         self._events: list = []       # (ph, name, ts, dur/id, tid, args)
 
     # ------------------------------------------------------------- spans
@@ -151,20 +166,3 @@ class Tracer:
 
 
 NULL_TRACER = Tracer(enabled=False)
-_default: Tracer = NULL_TRACER
-
-
-def get_tracer() -> Tracer:
-    return _default
-
-
-def set_tracer(tracer: Optional[Tracer]) -> Tracer:
-    """Install a process-default tracer (None -> disabled); returns it."""
-    global _default
-    _default = tracer if tracer is not None else NULL_TRACER
-    return _default
-
-
-def span(name: str, **args):
-    """Module-level convenience: span on the process-default tracer."""
-    return _default.span(name, **args)

@@ -39,7 +39,9 @@ K/V to what the request's own prefill would have produced.
 """
 from __future__ import annotations
 
+import gc
 import time
+import weakref
 from dataclasses import dataclass, field, replace
 from functools import lru_cache
 from typing import Dict, List, Optional, Sequence
@@ -77,6 +79,33 @@ def _cached_window_gather(span: int):
             toks, base[:, None] + jnp.arange(span, dtype=base.dtype)[None],
             axis=1)
     return jax.jit(_window_gather)
+
+
+def _trace_gc(engine):
+    """Record every Python garbage collection as a ``gc`` span on the
+    engine's tracer (generation; objects collected where the span keeps
+    args) for as long as the engine lives. The hook holds the engine weakly
+    and is removed when the engine is collected."""
+    ref = weakref.ref(engine)
+    open_spans = []
+
+    def hook(phase, info):
+        eng = ref()
+        if eng is None:
+            return
+        if phase == "start":
+            span = eng._tr.span("gc", generation=info["generation"])
+            span.__enter__()
+            open_spans.append(span)
+        elif open_spans:
+            span = open_spans.pop()
+            args = getattr(span, "args", None)
+            if args is not None:
+                args["collected"] = info["collected"]
+            span.__exit__(None, None, None)
+
+    gc.callbacks.append(hook)
+    weakref.finalize(engine, gc.callbacks.remove, hook)
 
 
 @dataclass
@@ -209,6 +238,8 @@ class ContinuousEngine:
         self.telemetry = ServingTelemetry(registry=self.registry)
         self.stats: Dict[int, RequestStats] = {}
         self._tr = self.tracer if self.tracer is not None else NULL_TRACER
+        if self._tr.enabled:
+            _trace_gc(self)
         self.phases = PhaseTimer()
         if self.registry is not None:
             # accepted-draft-tokens-per-round histogram: the live acceptance
@@ -486,7 +517,8 @@ class ContinuousEngine:
         slot.stats.first_token_time_s = self._now()
         self._tr.async_instant("first_token", slot.req.request_id,
                                ts=self._t0 + slot.stats.first_token_time_s)
-        return int(jax.device_get(tok))
+        with self._tr.span("prefill_wait"):
+            return int(jax.device_get(tok))
 
     # ---------------------------------------------------------------- step
     def step(self) -> List[tuple]:
@@ -495,6 +527,10 @@ class ContinuousEngine:
         Returns a list of events: ("token", request_id, np.ndarray of new
         token ids) and ("finish", request_id, Result).
         """
+        with self._tr.span("engine_step"):
+            return self._step()
+
+    def _step(self) -> List[tuple]:
         t_step = time.perf_counter()
         now = self._now()
         events: List[tuple] = []
@@ -532,7 +568,10 @@ class ContinuousEngine:
             self._last_sanitize = self.telemetry.decode_rounds
             self._sanitize_check()
 
-        if did_work:   # idle ticks (waiting on arrivals) don't skew telemetry
+        if not did_work:   # idle ticks (waiting on arrivals) don't skew telemetry
+            time.sleep(5e-4)
+            return events
+        with self._tr.span("telemetry"):
             qd = self.scheduler.ready_depth(self._now())
             act = sum(s.state == "decode" for s in self._slots)
             self.telemetry.sample(qd, act, self.pool.num_free,
@@ -553,8 +592,6 @@ class ContinuousEngine:
                 if self.metrics_out and \
                         self.telemetry.steps % self.metrics_every == 0:
                     self.registry.write_snapshot(self.metrics_out)
-        else:
-            time.sleep(5e-4)
         return events
 
     def _run_round_phased(self, st, kr):
@@ -576,27 +613,33 @@ class ContinuousEngine:
         return st, n_acc
 
     def _decode_round(self) -> List[tuple]:
-        st = self._state
-        self._key, kr = jax.random.split(self._key)
-        old_len = self._lengths_h.copy()
-        # device copy of the pre-round lengths for the window gather below:
-        # a distinct buffer, so donating st["lengths"] into the round cannot
-        # invalidate it, and no host index arrays ever cross to the device
-        base_dev = st["lengths"].copy()
-        t_round = time.perf_counter()
-        if self._phased is not None:
-            st, n_acc = self._run_round_phased(st, kr)
-        else:
-            st, n_acc = self._round(self._d_params, self.target_params, st, kr)
-        self._state = st
-        # one transfer: lengths + committed windows + the fresh pending token
-        # (+ the quality buffers when enabled — they ride the same sync)
-        win = self._win_fn(st["tokens"], base_dev)
-        fetch = [st["lengths"], win, st["pending"]]
-        if self.quality:
-            q = st["qual"]
-            fetch += [q["tvd"], q["ent"], q["acc"], q["drafted"]]
-        got = [np.asarray(a) for a in jax.device_get(tuple(fetch))]
+        tr = self._tr
+        with tr.span("round_dispatch"):
+            st = self._state
+            self._key, kr = jax.random.split(self._key)
+            old_len = self._lengths_h.copy()
+            # device copy of the pre-round lengths for the window gather
+            # below: a distinct buffer, so donating st["lengths"] into the
+            # round cannot invalidate it, and no host index arrays ever
+            # cross to the device
+            base_dev = st["lengths"].copy()
+            t_round = time.perf_counter()
+            if self._phased is not None:
+                st, n_acc = self._run_round_phased(st, kr)
+            else:
+                st, n_acc = self._round(self._d_params, self.target_params,
+                                        st, kr)
+            self._state = st
+            # one transfer: lengths + committed windows + the fresh pending
+            # token (+ the quality buffers when enabled — they ride the
+            # same sync)
+            win = self._win_fn(st["tokens"], base_dev)
+            fetch = [st["lengths"], win, st["pending"]]
+            if self.quality:
+                q = st["qual"]
+                fetch += [q["tvd"], q["ent"], q["acc"], q["drafted"]]
+        with tr.span("round_wait"):
+            got = [np.asarray(a) for a in jax.device_get(tuple(fetch))]
         lengths_h, win_h, pending_h = got[:3]
         qual_h = got[3:] if self.quality else None
         # the device_get above synchronizes, so this spans the real round
@@ -605,34 +648,37 @@ class ContinuousEngine:
         self.telemetry.decode_rounds += 1
 
         events: List[tuple] = []
-        retiring: List[int] = []
-        for i, slot in enumerate(self._slots):
-            if slot.state != "decode":
-                continue
-            n_committed = int(lengths_h[i] - old_len[i])
-            slot.stats.sd.update(n_committed)
-            # per-request wall time: every active row paid this round
-            # (pooled tokens_per_s on merged stats was silently 0 before)
-            slot.stats.sd.wall_time_s += round_dt
-            if self._m_accept is not None:
-                self._m_accept.observe(n_committed)
-                self.registry.counter("sd_tokens_total",
-                                      "committed tokens").inc(n_committed)
-                self.registry.counter("sd_blocks_total",
-                                      "speculation rounds").inc()
-            # stream: window[0] is the previous pending (already emitted);
-            # the new pending is available now and always commits next round.
-            fresh = np.concatenate([win_h[i, 1:n_committed],
-                                    [pending_h[i]]]).astype(np.int64)
-            events.extend(self._emit(i, fresh))
-            if lengths_h[i] >= slot.target_len:
-                retiring.append(i)
-        if self.quality:
-            self._pool_quality(qual_h)
-        if self.recorder is not None:
-            self._record_round(qual_h, old_len, lengths_h)
-        for i in retiring:
-            events.append(self._retire(i))
+        with tr.span("round_emit"):
+            retiring: List[int] = []
+            for i, slot in enumerate(self._slots):
+                if slot.state != "decode":
+                    continue
+                n_committed = int(lengths_h[i] - old_len[i])
+                slot.stats.sd.update(n_committed)
+                # per-request wall time: every active row paid this round
+                # (pooled tokens_per_s on merged stats was silently 0 before)
+                slot.stats.sd.wall_time_s += round_dt
+                if self._m_accept is not None:
+                    self._m_accept.observe(n_committed)
+                    self.registry.counter("sd_tokens_total",
+                                          "committed tokens").inc(n_committed)
+                    self.registry.counter("sd_blocks_total",
+                                          "speculation rounds").inc()
+                # stream: window[0] is the previous pending (already
+                # emitted); the new pending is available now and always
+                # commits next round.
+                fresh = np.concatenate([win_h[i, 1:n_committed],
+                                        [pending_h[i]]]).astype(np.int64)
+                events.extend(self._emit(i, fresh))
+                if lengths_h[i] >= slot.target_len:
+                    retiring.append(i)
+            if self.quality:
+                self._pool_quality(qual_h)
+            if self.recorder is not None:
+                self._record_round(qual_h, old_len, lengths_h)
+            for i in retiring:
+                with tr.span("retire"):
+                    events.append(self._retire(i))
         return events
 
     def _pool_quality(self, qual_h):

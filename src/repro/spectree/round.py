@@ -164,6 +164,7 @@ def commit_tree_path_paged(cache, page_table, lengths, path_nodes, n_acc,
 
 # ------------------------------------------------------------------ round
 
+@jax.named_scope("draft")
 def tree_draft_phase(draft, target: Model, sdc: SDConfig, spec: TreeSpec,
                      d_params, t_params, state, key):
     """Level-by-level tree expansion: sample every node's token and keep its
@@ -226,6 +227,7 @@ def tree_draft_phase(draft, target: Model, sdc: SDConfig, spec: TreeSpec,
     return {"node_tok": node_tok, "p_node": p_node, "d_cache": d_cache}
 
 
+@jax.named_scope("verify")
 def tree_verify_phase(draft, target: Model, sdc: SDConfig, spec: TreeSpec,
                       t_params, state, draft_out):
     """Target verify: ONE decode over all N tree nodes with the ancestor
@@ -254,6 +256,7 @@ def tree_verify_phase(draft, target: Model, sdc: SDConfig, spec: TreeSpec,
     return {"q_node": q_node, "t_cache": t_cache, "t_hid": t_hid}
 
 
+@jax.named_scope("commit")
 def tree_commit_phase(draft, target: Model, sdc: SDConfig, spec: TreeSpec,
                       state, draft_out, verify_out, key):
     """Recursive-rejection acceptance, token commit, and root-path cache

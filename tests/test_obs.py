@@ -2,6 +2,7 @@
 timer, roofline attribution, bench persistence, and the engine integration —
 trace-reconstructed latencies must match RequestStats, and the phased decode
 path must be token-identical to the fused round it decomposes."""
+import gc
 import json
 import time
 
@@ -17,6 +18,7 @@ from repro.models import Model
 from repro.obs import (Histogram, MetricsRegistry, PhaseTimer, Tracer,
                        attribution_report, format_attribution)
 from repro.serving import ContinuousEngine, ServeRequest
+from repro.spectree import TreeSpec
 
 BASE = dict(d_model=64, num_heads=4, num_kv_heads=2, d_ff=128, vocab_size=64,
             attn_chunk=16, remat=False)
@@ -77,6 +79,38 @@ def test_disabled_tracer_is_free():
             pass
     assert time.perf_counter() - t0 < 0.5   # ~no overhead at 100k spans
     assert tr.events() == []
+
+
+def test_annotated_tracer_enters_a_trace_annotation_per_span(monkeypatch):
+    """``annotate`` puts every span, under its exact name, on the jax
+    profiler's clock; a plain tracer does not touch the profiler."""
+    log = []
+
+    class FakeAnnotation:
+        def __init__(self, name, **kw):
+            self.name = name
+
+        def __enter__(self):
+            log.append(("enter", self.name))
+            return self
+
+        def __exit__(self, *exc):
+            log.append(("exit", self.name))
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", FakeAnnotation)
+    tr = Tracer(annotate=True)
+    with tr.span("decode_round", slot=1):
+        with tr.span("round_wait"):
+            pass
+    assert log == [("enter", "decode_round"), ("enter", "round_wait"),
+                   ("exit", "round_wait"), ("exit", "decode_round")]
+    assert [e["name"] for e in tr.events()] == ["round_wait", "decode_round"]
+    log.clear()
+    with Tracer().span("decode_round"):
+        pass
+    with Tracer(enabled=False, annotate=True).span("decode_round"):
+        pass
+    assert log == []
 
 
 # ------------------------------------------------------------------ registry
@@ -331,3 +365,77 @@ def test_telemetry_ring_is_bounded(models):
     assert tel.mean_active_rows == pytest.approx(2.0)
     assert tel.mean_shared_frac == pytest.approx(0.5)
     assert tel.steps == 10
+
+
+def _engine_kw(models, **kw):
+    t, d, tp, dp = models
+    return dict(target=t, target_params=tp, draft=d, draft_params=dp,
+                sd=SDConfig(gamma=2, temperature=0.0), max_batch=2,
+                max_seq_len=32, page_size=4, prefill_chunk=8, **kw)
+
+
+def test_gc_spans_only_while_an_enabled_tracer_lives(models):
+    """A disabled tracer installs no gc hook; an enabled one records each
+    collection as a ``gc`` span, and its hook goes with the engine."""
+    n = len(gc.callbacks)
+    ContinuousEngine(**_engine_kw(models))
+    ContinuousEngine(**_engine_kw(models, tracer=Tracer(enabled=False)))
+    assert len(gc.callbacks) == n
+    tr = Tracer()
+    eng = ContinuousEngine(**_engine_kw(models, tracer=tr))
+    assert len(gc.callbacks) == n + 1
+    gc.collect()
+    spans = [e for e in tr.events() if e["name"] == "gc"]
+    assert spans and spans[-1]["ph"] == "X"
+    assert spans[-1]["args"]["generation"] == 2
+    assert spans[-1]["args"]["collected"] >= 0
+    del eng
+    gc.collect()
+    assert len(gc.callbacks) == n
+
+
+def _within(child, parent):
+    return (parent["ts"] - 1e-3 <= child["ts"] and child["ts"] + child["dur"]
+            <= parent["ts"] + parent["dur"] + 1e-3)
+
+
+def test_engine_spans_nest(models):
+    """The round's host spans nest as the profiler reads them: dispatch,
+    wait and emit in decode_round, retire in round_emit, the first-token
+    wait in prefill_chunk, and everything in engine_step."""
+    tr = Tracer()
+    eng = ContinuousEngine(**_engine_kw(models, tracer=tr))
+    eng.serve(_requests(np.random.default_rng(5), [6, 10, 8], [8, 6, 7]))
+    spans = [e for e in tr.events() if e["ph"] == "X" and e["name"] != "gc"]
+    by = {}
+    for e in spans:
+        by.setdefault(e["name"], []).append(e)
+    assert {"engine_step", "admit", "prefill_chunk", "prefill_wait",
+            "decode_round", "round_dispatch", "round_wait", "round_emit",
+            "retire", "telemetry"} <= set(by)
+    assert len(by["retire"]) == 3 and len(by["prefill_wait"]) == 3
+    parents = {"round_dispatch": "decode_round", "round_wait": "decode_round",
+               "round_emit": "decode_round", "retire": "round_emit",
+               "prefill_wait": "prefill_chunk"}
+    for child, parent in parents.items():
+        for e in by[child]:
+            assert any(_within(e, p) for p in by[parent]), (child, e)
+    for e in spans:
+        if e["name"] != "engine_step":
+            assert any(_within(e, p) for p in by["engine_step"]), e
+    assert len(by["decode_round"]) == len(by["round_wait"]) \
+        == eng.telemetry.decode_rounds
+
+
+@pytest.mark.parametrize("tree", [None, TreeSpec((2, 1))],
+                         ids=["chain", "tree"])
+def test_round_hlo_carries_named_scopes(models, tree):
+    """The engine's round program names its phases and the model step's
+    parts in its HLO metadata, and keeps its program name."""
+    eng = ContinuousEngine(**_engine_kw(models, tree=tree))
+    txt = eng._round.lower(eng._d_params, eng.target_params, eng._state,
+                           jax.random.PRNGKey(0)).as_text(debug_info=True)
+    for scope in ("draft/", "verify/", "commit", "attention", "kv_write",
+                  "kv_gather", "sdpa", "mlp", "lm_head", "sampling"):
+        assert scope in txt, scope
+    assert "module @jit__unknown" in txt
