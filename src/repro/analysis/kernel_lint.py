@@ -1,11 +1,12 @@
 """Pallas kernel lint: static BlockSpec/grid validation across swept shapes.
 
-The repo's kernels (quant_matmul, flash_decode, tree_attention, the distill
-loss trio) run in interpret mode in CI — nothing there exercises the TPU
-resource constraints they were tiled for. This linter recovers the *actual*
-``pl.pallas_call`` invocation each wrapper would make for a given problem
-shape — by monkeypatching ``pallas_call`` and tracing the wrapper under
-``jax.eval_shape``, so the real tiling code runs but no kernel executes —
+The repo's kernels (quant_matmul, flash_decode, tree_attention,
+paged_attention, the distill loss trio) run in interpret mode in CI —
+nothing there exercises the TPU resource constraints they were tiled for.
+This linter recovers the *actual* ``pl.pallas_call`` invocation each
+wrapper would make for a given problem shape — by monkeypatching
+``pallas_call`` and tracing the wrapper under ``jax.eval_shape``, so the
+real tiling code runs but no kernel executes —
 and then validates, per (kernel, shape):
 
   KN001  VMEM footprint: every pipelined in/out block is double-buffered
@@ -55,12 +56,15 @@ class PallasCallRecord:
     scratch: List[Tuple[Tuple[int, ...], str]]
     operand_shapes: List[Tuple[int, ...]]
     out_shapes: List[Tuple[int, ...]]
+    # scratch of an HBM operand's dtype: what the kernel's DMAs copy into
+    dma_buffers: List[Tuple[Tuple[int, ...], str]] = field(
+        default_factory=list)
 
     def block_bytes(self) -> int:
         per_step = 0
         for shape, dtype in self.in_blocks + self.out_blocks:
             per_step += _nbytes(shape, dtype) * DOUBLE_BUFFER
-        for shape, dtype in self.scratch:
+        for shape, dtype in self.scratch + self.dma_buffers:
             per_step += _nbytes(shape, dtype)
         return per_step
 
@@ -76,6 +80,15 @@ def _kernel_name(fn) -> str:
     while isinstance(fn, functools.partial):
         fn = fn.func
     return getattr(fn, "__name__", repr(fn))
+
+
+def _is_buffer(scratch) -> bool:
+    """A scratch entry that holds data (not a DMA or sync semaphore)."""
+    try:
+        jnp.dtype(scratch.dtype)
+    except TypeError:
+        return False
+    return True
 
 
 def _block_shape(spec, operand_shape) -> Tuple[int, ...]:
@@ -96,23 +109,40 @@ def capture_pallas_calls(fn: Callable, *abstract_args,
     real = pl.pallas_call
 
     def recorder(kernel, *, grid=(), in_specs=None, out_specs=None,
-                 out_shape=None, scratch_shapes=(), **unused):
+                 out_shape=None, scratch_shapes=(), grid_spec=None,
+                 **unused):
+        n_prefetch = 0
+        if grid_spec is not None:   # scalar-prefetch grid (SMEM operands)
+            grid, in_specs = grid_spec.grid, grid_spec.in_specs
+            out_specs = grid_spec.out_specs
+            scratch_shapes = grid_spec.scratch_shapes
+            n_prefetch = grid_spec.num_scalar_prefetch
+
         def fake_call(*operands):
             out_list, out_def = jax.tree_util.tree_flatten(out_shape)
             specs = (out_specs if isinstance(out_specs, (list, tuple))
                      else [out_specs])
+            # operands left in HBM (memory_space ANY, copied in by the
+            # kernel's own DMAs) take no pipelined VMEM block
+            pairs = list(zip(in_specs or [], operands[n_prefetch:]))
+            in_hbm = {str(o.dtype) for s, o in pairs
+                      if getattr(s, "memory_space", None) is pl.ANY}
+            blocked = [(s, o) for s, o in pairs
+                       if getattr(s, "memory_space", None) is not pl.ANY]
+            buffers = [(tuple(int(d) for d in s.shape),
+                        str(jnp.dtype(s.dtype)))
+                       for s in scratch_shapes if _is_buffer(s)]
             records.append(PallasCallRecord(
                 kernel_name=_kernel_name(kernel),
                 grid=tuple(int(g) for g in (grid if isinstance(
                     grid, (list, tuple)) else (grid,))),
                 in_blocks=[(_block_shape(s, o.shape), str(o.dtype))
-                           for s, o in zip(in_specs or [], operands)],
+                           for s, o in blocked],
                 out_blocks=[(_block_shape(s, o.shape), str(o.dtype))
                             for s, o in zip(specs, out_list)],
-                scratch=[(tuple(int(d) for d in s.shape),
-                          str(jnp.dtype(s.dtype)))
-                         for s in scratch_shapes],
-                operand_shapes=[tuple(o.shape) for o in operands],
+                scratch=[b for b in buffers if b[1] not in in_hbm],
+                dma_buffers=[b for b in buffers if b[1] in in_hbm],
+                operand_shapes=[tuple(o.shape) for _, o in blocked],
                 out_shapes=[tuple(o.shape) for o in out_list],
             ))
             zeros = [jnp.zeros(o.shape, o.dtype) for o in out_list]
@@ -211,6 +241,7 @@ def build_kernel_cases() -> List[KernelCase]:
     from ..kernels.quant_matmul import quant_matmul
     from ..kernels.flash_decode import flash_decode
     from ..kernels.tree_attention import tree_attention
+    from ..kernels.paged_attention import paged_attention
     from ..kernels.distill_loss import loss_grad, loss_terms, row_logsumexp
 
     t, _ = _tiny_models()
@@ -255,6 +286,20 @@ def build_kernel_cases() -> List[KernelCase]:
             args=(_sds((B, Hkv, N, G, hd), f32),
                   _sds((B, S, Hkv, hd), bf16), _sds((B, S, Hkv, hd), bf16),
                   _sds((B, N, S), jnp.bool_))))
+
+    # paged_attention: stacked (L, P, page, Hkv, hd) pools read in place
+    for tag, (B, T, H, Hkv, hd, L, P, mp, dt) in [
+            ("tiny", (4, 4, cfg.num_heads, cfg.num_kv_heads, hd_small, 2,
+                      64, 8, f32)),
+            ("phi4_verify", (32, 4, 24, 8, hd_big, 32, 1200, 257, bf16)),
+            ("phi4_prefill", (1, 256, 24, 8, hd_big, 32, 1200, 257, bf16)),
+            ("phi4_drafter", (32, 1, 8, 4, hd_big, 4, 1200, 257, bf16))]:
+        cases.append(KernelCase(
+            name=f"paged_attention_{tag}", fn=paged_attention,
+            args=(_sds((B, T, H, hd), dt), _sds((L, P, 16, Hkv, hd), dt),
+                  _sds((L, P, 16, Hkv, hd), dt),
+                  _sds((L, P, 16), jnp.int32), _sds((B, mp), jnp.int32),
+                  _sds((B, T), jnp.int32))))
 
     # distill loss trio: (rows, vocab)
     for tag, (R, V) in [("tiny", (64, cfg.vocab_size)),

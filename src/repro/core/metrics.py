@@ -224,6 +224,10 @@ class ServingTelemetry:
     prefill_chunks: int = 0
     admitted: int = 0
     completed: int = 0
+    # per decode round, summed: the pages each active row's attention
+    # reads (its live span) and the pages its table spans (max_pages)
+    kv_pages_read: int = 0
+    kv_pages_spanned: int = 0
 
     def __post_init__(self):
         for name in ("queue_depth", "active_rows", "free_pages",
@@ -263,8 +267,24 @@ class ServingTelemetry:
                 ("serve_prefill_chunks_total", "prefill chunks fed",
                  self.prefill_chunks),
                 ("serve_admitted_total", "requests admitted", self.admitted),
-                ("serve_completed_total", "requests finished", self.completed)):
+                ("serve_completed_total", "requests finished", self.completed),
+                ("serve_kv_pages_read_total", "live KV pages read by rounds",
+                 self.kv_pages_read),
+                ("serve_kv_pages_spanned_total", "KV pages rounds' tables span",
+                 self.kv_pages_spanned)):
             registry.counter(name, help_).set_total(v)
+
+    def count_kv_pages(self, read: int, spanned: int):
+        """One decode round's live pages read and table pages spanned."""
+        self.kv_pages_read += int(read)
+        self.kv_pages_spanned += int(spanned)
+
+    @property
+    def kv_read_share(self) -> float:
+        """Live pages read over pages spanned: the share of a whole-table
+        gather that reading only live pages still reads."""
+        return (self.kv_pages_read / self.kv_pages_spanned
+                if self.kv_pages_spanned else float("nan"))
 
     @property
     def max_queue_depth(self) -> int:

@@ -23,6 +23,7 @@ import jax.numpy as jnp
 
 from . import distill_loss as dk
 from . import flash_decode as fk
+from . import paged_attention as pk
 from . import quant_matmul as qk
 from . import tree_attention as tk
 
@@ -142,3 +143,16 @@ def tree_verify_attention(q, k, v, mask, softcap=None):
     tree node of a speculative draft tree in one kernel launch."""
     return tk.tree_attention(q, k, v, mask, softcap=softcap,
                              interpret=_interpret())
+
+
+# ------------------------------------------------------ paged attention
+
+def paged_attention(q, k_pool, v_pool, page_pos, page_table, pos, layer=0,
+                    softcap=None, window=None):
+    """See kernels.paged_attention.paged_attention; oracle in kernels.ref.
+
+    q (B, T, H, hd) over stacked (L, P, page, Hkv, hd) pools read in place
+    through ``page_table``: only each row's live pages move from HBM."""
+    return pk.paged_attention(q, k_pool, v_pool, page_pos, page_table, pos,
+                              layer, softcap=softcap, window=window,
+                              interpret=_interpret())

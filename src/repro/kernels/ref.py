@@ -113,3 +113,33 @@ def ref_tree_attention(q, k, v, mask, softcap=None):
     s = jnp.where(mask[:, None, :, None, :], s, -1e30)
     p = jax.nn.softmax(s, axis=-1)
     return jnp.einsum("bhngs,bshd->bhngd", p, v.astype(jnp.float32))
+
+
+def ref_paged_attention(q, k_pool, v_pool, page_pos, page_table, pos,
+                        layer=0, softcap=None, window=None):
+    """The gather path of ``models.attention.paged_decode_attention``: every
+    row's whole table span gathered into a view, masked by the positions
+    written there, softmaxed in float32. Oracle for kernels.paged_attention.
+
+    q: (B, T, H, hd); k_pool/v_pool: (L, P, page, Hkv, hd); page_pos:
+    (L, P, page); page_table: (B, max_pages), null page 0 masked; pos:
+    (B, T). A query row with no valid slot (an inactive row) reads zeros."""
+    B, T, H, hd = q.shape
+    _, _, page, hkv, _ = k_pool.shape
+    W = page_table.shape[1] * page
+    k = k_pool[layer][page_table].reshape(B, W, hkv, hd).astype(jnp.float32)
+    v = v_pool[layer][page_table].reshape(B, W, hkv, hd).astype(jnp.float32)
+    cp = jnp.where((page_table == 0)[:, :, None], -1,
+                   page_pos[layer][page_table]).reshape(B, W)
+    m = (cp[:, None, :] >= 0) & (cp[:, None, :] <= pos[:, :, None])
+    if window is not None:
+        m &= cp[:, None, :] > pos[:, :, None] - window
+    qg = q.reshape(B, T, hkv, H // hkv, hd).astype(jnp.float32)
+    s = jnp.einsum("btkgd,bskd->btkgs", qg, k) / math.sqrt(hd)
+    if softcap is not None:
+        s = softcap * jnp.tanh(s / softcap)
+    s = jnp.where(m[:, :, None, None, :], s, -1e30)
+    p = jax.nn.softmax(s, axis=-1)
+    out = jnp.einsum("btkgs,bskd->btkgd", p, v)
+    out = jnp.where(jnp.any(m, -1)[:, :, None, None, None], out, 0.0)
+    return out.reshape(B, T, H, hd)

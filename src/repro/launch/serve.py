@@ -425,6 +425,8 @@ def main(argv=None):
               f"prefill_chunks={tel.prefill_chunks} "
               f"max_queue={tel.max_queue_depth} "
               f"mean_active={tel.mean_active_rows:.2f}")
+        print(f"  kv pages read/spanned={tel.kv_pages_read}"
+              f"/{tel.kv_pages_spanned} ({tel.kv_read_share:.3f})")
         if engine.prefix is not None:
             print(f"  prefix cache: {engine.prefix.tel.summary()} "
                   f"shared_page_frac={tel.mean_shared_frac:.2f}")

@@ -31,6 +31,10 @@ NEG_INF = -2.0e38
 #: i.e. whenever kernels.ops.INTERPRET is False), True/False = force.
 TREE_FASTPATH = None
 
+#: paged-attention kernel override for eligible calls (``_use_paged_kernel``):
+#: None = auto (compiled Pallas only), True/False = force.
+PAGED_FASTPATH = None
+
 
 def _opt_seq_shard(q, k, v, cfg):
     """Optimized-profile fix (§Perf it.1, phi4-class archs): when num_heads
@@ -249,14 +253,36 @@ def decode_attention(params, x, cache, pos, cfg,
     return out, new_cache
 
 
+def _use_paged_kernel(kpool, attn_mask) -> bool:
+    """Read the paged pool in place with the Pallas kernel
+    (kernels.paged_attention) instead of gathering every row's span?
+
+    Eligible: no slot mask (tree verify has its own kernel), a float pool
+    (int8 pools dequantize in the gather) whose KV heads the kernel can
+    load (``paged_attention.supports``). Auto mode then uses it whenever
+    Pallas compiles natively; in interpret mode the gather is faster.
+    ``PAGED_FASTPATH`` True/False forces the choice for eligible calls."""
+    from ..kernels import ops, paged_attention as pk
+    if attn_mask is not None or not jnp.issubdtype(kpool.dtype, jnp.floating):
+        return False
+    if not pk.supports(kpool.shape[-2], kpool.dtype):
+        return False
+    if PAGED_FASTPATH is not None:
+        return PAGED_FASTPATH
+    return not ops.INTERPRET
+
+
 def paged_decode_attention(params, x, cache, page_table, pos, cfg,
                            window: Optional[int] = None, slots=None,
-                           attn_mask=None):
+                           attn_mask=None, layer=None):
     """Decode step against a shared paged KV pool.
 
     cache: {"k": (P, page, Hkv, hd), "v": same, "page_pos": (P, page)} — one
       physical pool shared by every sequence; "page_pos" holds the absolute
-      position written into each pool slot (-1 = empty).
+      position written into each pool slot (-1 = empty). With ``layer``
+      given, every leaf carries the layer scan's leading axis (the stacked
+      pools of all layers) and this call reads and writes entry ``layer``:
+      the kernel then reads the scan's own buffer, with nothing sliced out.
     page_table: (B, max_pages) int32 mapping a row's logical page index
       (position // page) to a physical page id. Physical page 0 is reserved
       as a null/trash page: unallocated table entries point there, writes
@@ -268,6 +294,11 @@ def paged_decode_attention(params, x, cache, page_table, pos, cfg,
       "page_pos" then records the storage position.
     attn_mask: optional (B, T, max_pages*page) mask over the gathered view
       replacing positional causality (column = storage position).
+
+    Two read paths (``_use_paged_kernel``): the Pallas paged-attention
+    kernel reads only each row's live pages in place; otherwise every
+    mapped page of every row is gathered into a (B, max_pages*page) view
+    and ``_attend`` scores all of it. Both apply the same validity mask.
 
     Pools carrying "k_scale"/"v_scale" (P, page, Hkv) leaves are int8
     (repro.quant.kvcache): entries are quantized per (page slot, kv head) on
@@ -285,10 +316,16 @@ def paged_decode_attention(params, x, cache, page_table, pos, cfg,
     boundary case — resuming prefill inside the last shared page — is
     COWed to a private copy before the write).
     """
+    if layer is None:
+        cache = jax.tree.map(lambda a: a[None], cache)
+        out, new_cache = paged_decode_attention(
+            params, x, cache, page_table, pos, cfg, window, slots, attn_mask,
+            layer=0)
+        return out, jax.tree.map(lambda a: a[0], new_cache)
     B, T, D = x.shape
     kpool, vpool, page_pos = cache["k"], cache["v"], cache["page_pos"]
     kv_quant = "k_scale" in cache
-    P, page = page_pos.shape
+    page = page_pos.shape[-1]
     max_pages = page_table.shape[1]
     q, k, v = _project_qkv(params, x, cfg, pos)
     # scatter new tokens through the page table
@@ -302,40 +339,52 @@ def paged_decode_attention(params, x, cache, page_table, pos, cfg,
     if kv_quant:
         from ..quant.kvcache import dequantize_kv_entry, quantize_kv_entry
     # device scopes: the pool scatter, the per-row gather of every mapped
-    # page (with its reshape and cast), and the attention over the view
+    # page (with its reshape and cast), and the attention over the view —
+    # or, on the kernel path, the kernel's in-place reads under ``sdpa``
     with jax.named_scope("kv_write"):
         if kv_quant:
             kq, ks = quantize_kv_entry(k)
             vq, vs = quantize_kv_entry(v)
-            kpool = kpool.at[phys, off].set(kq)
-            vpool = vpool.at[phys, off].set(vq)
-            k_scale = cache["k_scale"].at[phys, off].set(ks)
-            v_scale = cache["v_scale"].at[phys, off].set(vs)
+            kpool = kpool.at[layer, phys, off].set(kq)
+            vpool = vpool.at[layer, phys, off].set(vq)
+            k_scale = cache["k_scale"].at[layer, phys, off].set(ks)
+            v_scale = cache["v_scale"].at[layer, phys, off].set(vs)
             new_cache.update(k_scale=k_scale, v_scale=v_scale)
         else:
-            kpool = kpool.at[phys, off].set(k.astype(kpool.dtype))
-            vpool = vpool.at[phys, off].set(v.astype(vpool.dtype))
-        page_pos = page_pos.at[phys, off].set(write_pos.astype(jnp.int32))
-    with jax.named_scope("kv_gather"):
-        if kv_quant:
-            kc = dequantize_kv_entry(kpool[page_table], k_scale[page_table],
-                                     q.dtype).reshape(B, W, Hkv, hd)
-            vc = dequantize_kv_entry(vpool[page_table], v_scale[page_table],
-                                     q.dtype).reshape(B, W, Hkv, hd)
-        else:
-            kc = kpool[page_table].reshape(B, W, Hkv, hd).astype(q.dtype)
-            vc = vpool[page_table].reshape(B, W, Hkv, hd).astype(q.dtype)
-        cp = page_pos[page_table]
-    cp = jnp.where((page_table == 0)[:, :, None], -1, cp)
-    cp = cp.reshape(B, W)
-    if attn_mask is None:
-        m = (cp[:, None, :] >= 0) & (cp[:, None, :] <= pos[:, :, None])
+            kpool = kpool.at[layer, phys, off].set(k.astype(kpool.dtype))
+            vpool = vpool.at[layer, phys, off].set(v.astype(vpool.dtype))
+        page_pos = page_pos.at[layer, phys, off].set(
+            write_pos.astype(jnp.int32))
+    if _use_paged_kernel(kpool, attn_mask):
+        from ..kernels import ops
+        with jax.named_scope("sdpa"):
+            out = ops.paged_attention(q, kpool, vpool, page_pos, page_table,
+                                      pos, layer, softcap=cfg.attn_softcap,
+                                      window=window).astype(q.dtype)
     else:
-        m = (cp[:, None, :] >= 0) & attn_mask
-    if window is not None:
-        m &= cp[:, None, :] > pos[:, :, None] - window
-    with jax.named_scope("sdpa"):
-        out = _attend(q, kc, vc, m, cfg, tree=attn_mask is not None)
+        with jax.named_scope("kv_gather"):
+            if kv_quant:
+                kc = dequantize_kv_entry(
+                    kpool[layer, page_table], k_scale[layer, page_table],
+                    q.dtype).reshape(B, W, Hkv, hd)
+                vc = dequantize_kv_entry(
+                    vpool[layer, page_table], v_scale[layer, page_table],
+                    q.dtype).reshape(B, W, Hkv, hd)
+            else:
+                kc = kpool[layer, page_table].reshape(B, W, Hkv, hd)
+                vc = vpool[layer, page_table].reshape(B, W, Hkv, hd)
+                kc, vc = kc.astype(q.dtype), vc.astype(q.dtype)
+            cp = page_pos[layer, page_table]
+        cp = jnp.where((page_table == 0)[:, :, None], -1, cp)
+        cp = cp.reshape(B, W)
+        if attn_mask is None:
+            m = (cp[:, None, :] >= 0) & (cp[:, None, :] <= pos[:, :, None])
+        else:
+            m = (cp[:, None, :] >= 0) & attn_mask
+        if window is not None:
+            m &= cp[:, None, :] > pos[:, :, None] - window
+        with jax.named_scope("sdpa"):
+            out = _attend(q, kc, vc, m, cfg, tree=attn_mask is not None)
     out = out.reshape(B, T, cfg.num_heads * cfg.head_dim_)
     out = matmul_param(out, params["wo"])
     new_cache.update(k=kpool, v=vpool, page_pos=page_pos)

@@ -80,8 +80,9 @@ def _block_window(cfg, kind, long_context):
 
 def apply_block(params, x, kind, cfg, mode, positions, cache,
                 long_context=False, cache_len=0, page_table=None,
-                slots=None, attn_mask=None):
-    """Returns (y, new_cache, aux)."""
+                slots=None, attn_mask=None, layer=None):
+    """Returns (y, new_cache, aux). ``layer``: a paged cache holds every
+    layer's pool stacked and this block uses entry ``layer`` of it."""
     aux = jnp.zeros((), jnp.float32)
     h = rms_norm(x, params["norm1"], cfg.norm_eps)
     window = _block_window(cfg, kind, long_context)
@@ -96,7 +97,7 @@ def apply_block(params, x, kind, cfg, mode, positions, cache,
             elif page_table is not None:
                 y, new_cache = attn_mod.paged_decode_attention(
                     params["attn"], h, cache, page_table, positions, cfg,
-                    window, slots=slots, attn_mask=attn_mask)
+                    window, slots=slots, attn_mask=attn_mask, layer=layer)
             else:
                 y, new_cache = attn_mod.decode_attention(
                     params["attn"], h, cache, positions, cfg, window,
@@ -297,7 +298,7 @@ def _select_shared(shared_params, idx, nsets):
 
 def _run_pattern(params_list, kinds, x, cfg, mode, positions, caches,
                  shared_params, group_idx, long_context, cache_len,
-                 page_table=None, slots=None, attn_mask=None):
+                 page_table=None, slots=None, attn_mask=None, layer=None):
     """Apply one group's sublayers in order. caches: tuple aligned w/ kinds."""
     new_caches = []
     aux_total = jnp.zeros((), jnp.float32)
@@ -309,7 +310,7 @@ def _run_pattern(params_list, kinds, x, cfg, mode, positions, caches,
             bp = params_list[j]
         x, nc, aux = apply_block(bp, x, kind, cfg, mode, positions, cache_j,
                                  long_context, cache_len, page_table,
-                                 slots, attn_mask)
+                                 slots, attn_mask, layer)
         new_caches.append(nc)
         aux_total = aux_total + aux
     return x, tuple(new_caches), aux_total
@@ -339,20 +340,29 @@ def backbone(params, tokens, cfg, mode="train", positions=None, cache=None,
 
     if n:
         group_caches = cache["groups"] if cache is not None else None
+        # paged pools ride the scan's carry whole, each layer updating its
+        # own entry in place; scanned as xs -> ys they would be copied into
+        # a fresh output stack, and a kernel reading them would need each
+        # layer's pool sliced out into a buffer of its own
+        paged = page_table is not None and group_caches is not None
 
         def body(carry, xs):
-            h, aux_acc = carry
+            h, aux_acc, pools = carry
             gp, gc, idx = xs
-            h, ncs, aux = _run_pattern(gp, g, h, cfg, mode, positions, gc,
+            h, ncs, aux = _run_pattern(gp, g, h, cfg, mode, positions,
+                                       pools if paged else gc,
                                        shared_params, idx, long_context,
                                        cache_len, page_table, slots,
-                                       attn_mask)
-            return (h, aux_acc + aux), ncs
+                                       attn_mask, idx if paged else None)
+            if paged:
+                return (h, aux_acc + aux, ncs), ()
+            return (h, aux_acc + aux, pools), ncs
 
         body_fn = jax.checkpoint(body) if (cfg.remat and mode == "train") else body
-        xs = (params["groups"], group_caches, jnp.arange(n))
-        (x, aux_total), new_group_caches = jax.lax.scan(body_fn, (x, aux_total), xs)
-        caches_out["groups"] = new_group_caches
+        xs = (params["groups"], () if paged else group_caches, jnp.arange(n))
+        carry = (x, aux_total, group_caches if paged else ())
+        (x, aux_total, pools), ys = jax.lax.scan(body_fn, carry, xs)
+        caches_out["groups"] = pools if paged else ys
 
     if rem:
         rem_caches = cache["rem"] if cache is not None else [None] * len(rem)

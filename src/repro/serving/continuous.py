@@ -646,6 +646,13 @@ class ContinuousEngine:
         round_dt = time.perf_counter() - t_round
         self._lengths_h = lengths_h.astype(np.int64)
         self.telemetry.decode_rounds += 1
+        # pages the round's attention reads per active row: its committed
+        # length plus the positions the round writes, against the table
+        live = np.array([s.state == "decode" for s in self._slots])
+        self.telemetry.count_kv_pages(
+            np.sum((old_len[live] + self._span + self.page_size - 1)
+                   // self.page_size),
+            int(live.sum()) * self._table_h.shape[1])
 
         events: List[tuple] = []
         with tr.span("round_emit"):

@@ -184,6 +184,76 @@ def test_priority_policy_orders_admission(models):
     assert order == [1, 2, 0]      # lowest priority value first
 
 
+def _clear_compiled():
+    """Drop every compiled round and step so the next engine retraces
+    (the attention read path is chosen at trace time)."""
+    from repro.core import speculative
+    for f in vars(speculative).values():
+        if hasattr(f, "cache_clear"):
+            f.cache_clear()
+    jax.clear_caches()
+
+
+@pytest.mark.parametrize("prefix_cache", [False, True],
+                         ids=["private_pages", "prefix_cache"])
+def test_paged_kernel_engine_matches_gather(monkeypatch, prefix_cache):
+    """At temperature 0 the engine emits the same tokens with the paged-
+    attention kernel forced on (interpret mode) as on the gather path, with
+    and without rows sharing prefix pages; the page counters read each
+    round's live pages against the tables' span."""
+    from repro.models import attention as A
+    f32 = dict(BASE, dtype="float32")
+    t = Model(ModelConfig(name="t32", arch_type="dense", num_layers=2, **f32))
+    d = Model(ModelConfig(name="d32", arch_type="dense", num_layers=1, **f32))
+    tp, _ = t.init(jax.random.PRNGKey(0))
+    dp, _ = d.init(jax.random.PRNGKey(1))
+    rng = np.random.default_rng(5)
+    shared = rng.integers(0, 64, 8)
+    reqs = [ServeRequest(prompt=np.concatenate(
+                [shared, rng.integers(0, 64, n)]).astype(np.int32),
+                max_new_tokens=m, request_id=i)
+            for i, (n, m) in enumerate([(3, 9), (7, 6), (1, 11)])]
+
+    def serve(kernel):
+        monkeypatch.setattr(A, "PAGED_FASTPATH", kernel)
+        _clear_compiled()
+        eng = ContinuousEngine(target=t, target_params=tp, draft=d,
+                               draft_params=dp,
+                               sd=SDConfig(gamma=3, temperature=0.0),
+                               max_batch=2, max_seq_len=32, page_size=4,
+                               prefill_chunk=8, prefix_cache=prefix_cache)
+        live, rows, run_round = [0], [0], eng._decode_round
+
+        def counted_round():
+            for i, slot in enumerate(eng._slots):
+                if slot.state == "decode":
+                    last = int(eng._lengths_h[i]) + eng._span - 1
+                    live[0] += int(np.count_nonzero(
+                        eng._table_h[i, :last // eng.page_size + 1]))
+                    rows[0] += 1
+            return run_round()
+
+        eng._decode_round = counted_round
+        for r in reqs:
+            eng.submit(r)
+        out = {r.request_id: r.tokens for r in eng.run()}
+        tel = eng.telemetry
+        assert tel.kv_pages_read == live[0] > 0
+        assert tel.kv_pages_spanned == rows[0] * eng._table_h.shape[1]
+        assert tel.kv_pages_read <= tel.kv_pages_spanned
+        if prefix_cache:
+            assert eng.prefix.tel.hits >= 1
+        return out
+
+    try:
+        gathered, kernel = serve(False), serve(True)
+    finally:
+        _clear_compiled()
+    assert sorted(kernel) == sorted(gathered) == [0, 1, 2]
+    for rid in gathered:
+        assert np.array_equal(kernel[rid], gathered[rid]), rid
+
+
 def test_engine_rejects_oversized_and_recurrent(models):
     t, d, tp, dp = models
     eng = ContinuousEngine(target=t, target_params=tp, draft=d,

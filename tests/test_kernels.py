@@ -136,3 +136,67 @@ def test_interpret_mode_resolution(monkeypatch):
     assert ops._env_interpret() is False
     monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "1")
     assert ops._env_interpret() is True
+
+
+# ------------------------------------------------------ paged attention
+
+def _paged_rows(T, page=8, max_pages=6, P=20, L=2):
+    """A pool and table in the engine's conventions: page 0 is null, each
+    row's slots up to its last query position are written, rows differ in
+    length. Rows: last query at 7 (a page's last slot), at 8 (one slot into
+    the next page), sharing row 0's first page then running to 20, inactive
+    (all-null table), and long (37) with stale writes past its queries."""
+    import numpy as np
+    rng = np.random.default_rng(0)
+    lasts = [7, 8, 20 + T - 1, 5, 37 + T - 1]
+    pp = np.full((L, P, page), -1, np.int32)
+    tab = np.zeros((len(lasts), max_pages), np.int32)
+    nxt = 1
+    for b, last in enumerate(lasts):
+        if b == 3:
+            continue
+        written = last + (4 if b == 4 else 0)
+        for j in range(written // page + 1):
+            if b == 2 and j == 0:
+                tab[b, 0] = tab[0, 0]       # shared, read-only prefix page
+                continue
+            tab[b, j], nxt = nxt, nxt + 1
+            for s in range(page):
+                if j * page + s <= written:
+                    pp[:, tab[b, j], s] = j * page + s
+    pos = np.array(lasts)[:, None] - T + 1 + np.arange(T)[None]
+    perm = rng.permutation(np.arange(1, P))      # physical ids out of order
+    tab = np.where(tab > 0, perm[tab - 1], 0)
+    pp_perm = np.full_like(pp, -1)
+    pp_perm[:, perm] = pp[:, 1:]
+    return jnp.asarray(pp_perm), jnp.asarray(tab), jnp.asarray(pos, jnp.int32)
+
+
+@pytest.mark.parametrize("T,G,window,cap,dtype,npb", [
+    (1, 2, None, None, jnp.float32, 4),
+    (4, 3, None, None, jnp.float32, 4),
+    (4, 2, None, None, jnp.bfloat16, 4),
+    (1, 3, 11, None, jnp.float32, 2),
+    (4, 2, None, 5.0, jnp.float32, 4),
+], ids=["t1_g2", "t4_g3", "t4_g2_bf16", "window", "softcap"])
+def test_paged_attention_matches_gather(T, G, window, cap, dtype, npb):
+    """The in-place paged kernel equals the gather + masked-softmax oracle
+    (``ref.ref_paged_attention``); an all-null row reads zeros, not NaN.
+    Four pages a block leave the 6-page table's last block half past its
+    end; two a block let the window skip the long row's first block."""
+    from repro.kernels import paged_attention as pk
+    Hkv, hd, L = 2, 32, 2
+    page_pos, table, pos = _paged_rows(T)
+    P, page = page_pos.shape[1:]
+    kk = jax.random.split(KEY, 3)
+    k = jax.random.normal(kk[0], (L, P, page, Hkv, hd)).astype(dtype)
+    v = jax.random.normal(kk[1], (L, P, page, Hkv, hd)).astype(dtype)
+    q = jax.random.normal(kk[2], (table.shape[0], T, Hkv * G, hd)).astype(dtype)
+    args = (q, k, v, page_pos, table, pos)
+    got = pk.paged_attention(*args, layer=1, softcap=cap, window=window,
+                             interpret=True, pages_per_block=npb)
+    want = ref.ref_paged_attention(*args, layer=1, softcap=cap, window=window)
+    assert bool(jnp.all(jnp.isfinite(got)))
+    assert float(jnp.max(jnp.abs(got[3]))) == 0.0          # inactive row
+    tol = 2e-2 if dtype == jnp.bfloat16 else 1e-5
+    assert jnp.allclose(got, want, atol=tol), float(jnp.max(jnp.abs(got - want)))

@@ -12,8 +12,8 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from repro.kernels import distill_loss, flash_decode, quant_matmul, \
-    tree_attention
+from repro.kernels import distill_loss, flash_decode, paged_attention, \
+    quant_matmul, tree_attention
 
 B, S, HD = 8, 1024, 128
 TREE_NODES = 7                     # a (2, 2) tree: root + 2 + 4
@@ -75,6 +75,21 @@ def test_flash_decode_compiles(compile_tpu, hkv, g):
         ((B, hkv, g, HD), jnp.bfloat16),
         ((B, S, hkv, HD), jnp.bfloat16), ((B, S, hkv, HD), jnp.bfloat16),
         ((B, S), jnp.bool_))
+
+
+@pytest.mark.parametrize("b,t,h,hkv,layers", [
+    (32, 4, 24, 8, 32), (32, 1, 8, 4, 4), (1, 256, 24, 8, 32)],
+    ids=["target_verify", "drafter_feed", "target_prefill"])
+def test_paged_attention_compiles(compile_tpu, b, t, h, hkv, layers):
+    """phi4-chat's widths: 1,200 pages of 16 slots, 257-page tables."""
+    P, page, max_pages = 1200, 16, 257
+    pool = ((layers, P, page, hkv, HD), jnp.bfloat16)
+    compile_tpu(
+        lambda q, k, v, pp, tab, pos: paged_attention.paged_attention(
+            q, k, v, pp, tab, pos, 3, interpret=False),
+        ((b, t, h, HD), jnp.bfloat16), pool, pool,
+        ((layers, P, page), jnp.int32), ((b, max_pages), jnp.int32),
+        ((b, t), jnp.int32))
 
 
 @pytest.mark.parametrize("bits,group", [(8, 0), (4, 64)])
