@@ -5,15 +5,19 @@ window, inside a ``TraceAnnotation("bench_window")`` that marks its bounds
 on the host line. The reduction reads the ``.xplane.pb`` with
 ``jax.profiler.ProfileData``:
 
-  device planes   "/device:TPU:<n>"; line "XLA Ops" holds one event per
-                  device operation, line "XLA Modules" one per execution
-                  of a compiled program (named after the jitted function).
+  device planes   "/device:TPU:<n>", those of the cell's chips; line "XLA
+                  Ops" holds one event per device operation, line "XLA
+                  Modules" one per execution of a compiled program (named
+                  after the jitted function).
   host plane      "/host:CPU"; the engine's spans (admit, prefill_chunk,
                   decode_round, mirrored by ``driver.EngineTracer``) and
                   the harness's span around each ``engine.step()`` (step).
 
 busy    union of the device-op intervals inside the window, per device,
         averaged over devices; idle share = 1 - busy / window.
+op time each op name's device seconds inside the window, per chip: the
+        mean over the device planes, so a cell across chips reads as one
+        of its chips does.
 modules each execution of a compiled program, in order; programs are
         named ``jit_<function>(<fingerprint>)``. The engine's jitted
         partials (the round, the prefill steps) all carry the function name
@@ -40,8 +44,8 @@ HOST_SPANS = ("admit", "prefill_chunk", "decode_round", "step")
 class Trace:
     window: Tuple[float, float]                  # seconds, trace clock
     ops: Dict[str, List[Tuple[float, float]]]    # device -> [(start, end)]
-    op_time: Dict[str, float]                    # op name -> seconds (all
-                                                 # devices, inside window)
+    op_time: Dict[str, float]                    # op name -> seconds per
+                                                 # device, inside window
     modules: List[Tuple[str, float, float]]      # first device, by start
     host: List[Tuple[float, float, str]]         # (start, end, span name)
     planes: List[str] = field(default_factory=list)
@@ -108,11 +112,20 @@ def reduce_events(device_lines, host_events) -> Trace:
                               if lo <= a and b <= hi), key=lambda e: e[1])
     host = [(a, b, n) for n, a, b in host_events
             if n in HOST_SPANS and b > lo and a < hi]
-    return Trace((lo, hi), ops, dict(op_time), modules, host)
+    n = max(len(device_lines), 1)
+    return Trace((lo, hi), ops, {k: v / n for k, v in op_time.items()},
+                 modules, host)
 
 
-def load(trace_dir: str) -> Trace:
-    """Read the one ``.xplane.pb`` under ``trace_dir``."""
+def _device_id(plane_name: str):
+    """n of "/device:TPU:<n>", None for any other plane."""
+    head, _, n = plane_name.rpartition(":")
+    return int(n) if head == "/device:TPU" and n.isdigit() else None
+
+
+def load(trace_dir: str, device_ids) -> Trace:
+    """Read the one ``.xplane.pb`` under ``trace_dir``; of the device
+    planes, those of ``device_ids``."""
     from jax.profiler import ProfileData
     paths = glob.glob(f"{trace_dir}/**/*.xplane.pb", recursive=True)
     if len(paths) != 1:
@@ -122,7 +135,8 @@ def load(trace_dir: str) -> Trace:
     device_lines, host_events, names = {}, [], []
     for plane in pd.planes:
         names.append(plane.name)
-        if plane.name.startswith("/device:TPU"):
+        dev = _device_id(plane.name)
+        if dev in device_ids:
             lines = {"ops": [], "modules": []}
             for line in plane.lines:
                 kind = {"XLA Ops": "ops", "XLA Modules": "modules"}.get(
@@ -202,8 +216,8 @@ def _label(t: Trace, mid: float) -> str:
 
 
 def breakdown(t: Trace, top: int = 10) -> dict:
-    """Device ops that took most time, and the longest idle gaps labelled
-    by the host span they fell in (first device's gaps)."""
+    """Device ops that took most time (per chip), and the longest idle gaps
+    labelled by the host span they fell in (first device's gaps)."""
     ops = sorted(t.op_time.items(), key=lambda kv: -kv[1])[:top]
     gaps = []
     if t.ops:

@@ -1,9 +1,13 @@
 """Served weights: the program's parameter layout filled from ``draw``.
 
-The layout (leaf names, stacked layer axis, dtypes) is read from the
-program's own ``Model.init`` by ``jax.eval_shape``; every value comes from
-``draw``, so the reference can regenerate it. Both models are built on the
-device in one jitted call, in the dtype they are served in.
+The layout (leaf names, stacked layer axis, dtypes) and the logical
+sharding specs are read from the program's own ``Model.init`` by
+``jax.eval_shape``; every value comes from ``draw``, so the reference can
+regenerate it. Both models are built on the device in one jitted call, in
+the dtype they are served in. On a mesh each leaf is made straight into the
+sharding that the program's specs give it under ``INFERENCE_RULES``, so no
+chip ever holds a whole model; the draw does not depend on the layout
+(partitionable threefry), so the values are those of one device.
 """
 from __future__ import annotations
 
@@ -45,13 +49,30 @@ def _fill(shapes, key, dims: dict, tag: str):
     return jax.tree_util.tree_map_with_path(leaf, shapes)
 
 
-def build(models, key):
+def layout(model):
+    """(shapes of the program's params, their logical specs), nothing
+    allocated."""
+    specs = []
+
+    def init(k):
+        params, s = model.init(k)
+        specs.append(s)
+        return params
+    return jax.eval_shape(init, jax.random.PRNGKey(0)), specs[0]
+
+
+def build(models, key, mesh=None):
     """models: [(program Model, canonical dims, tag)]. Returns the params
-    of each, made in one jitted call from ``key``."""
-    layouts = [jax.eval_shape(lambda k, m=m: m.init(k)[0],
-                              jax.random.PRNGKey(0)) for m, _, _ in models]
+    of each, made in one jitted call from ``key``: on the default device,
+    or with ``mesh`` in the program's shardings over it."""
+    layouts = [layout(m) for m, _, _ in models]
 
     def make(k):
         return tuple(_fill(lay, k, dims, tag)
-                     for lay, (_, dims, tag) in zip(layouts, models))
-    return jax.jit(make)(key)
+                     for (lay, _), (_, dims, tag) in zip(layouts, models))
+    if mesh is None:
+        return jax.jit(make)(key)
+    from repro.sharding.rules import INFERENCE_RULES, make_param_shardings
+    shardings = tuple(make_param_shardings(specs, lay, mesh, INFERENCE_RULES)
+                      for lay, specs in layouts)
+    return jax.jit(make, out_shardings=shardings)(key)

@@ -19,6 +19,13 @@ A cell of ``BENCHMARK.json`` names a model configuration
      (``lib/replay.py``); ``correct`` holds if the widest gap is within the
      cell's limit.
 
+A cell of ``chips`` > 1 is served across its first ``chips`` devices: a
+(1, chips) mesh of the axes ("data", "model") that the program's sharding
+rules map, the weights drawn straight into the program's shardings, and
+set-up and window inside ``repro.sharding.context.use_mesh``; the check
+runs on the first chip, outside the mesh. A cell of one chip takes no mesh.
+Memory and the trace are read per chip.
+
 The last stdout line is the result JSON. The run exits non-zero, printing
 no result, without a TPU or with fewer chips than the cell asks for.
 """
@@ -29,6 +36,7 @@ import time
 T_PROCESS = time.perf_counter()
 
 import argparse  # noqa: E402
+import contextlib  # noqa: E402
 import gc  # noqa: E402
 import json  # noqa: E402
 import os  # noqa: E402
@@ -80,7 +88,7 @@ class Context:
     traced_rounds: list
     counts: tuple
     gamma: int
-    peaks: dict
+    peaks: dict          # the cell's chips together
 
 
 def program_models(config: dict):
@@ -122,13 +130,24 @@ def warm_up(models, params, serving: dict, mix: dict, lines=None):
     through prefill, decode rounds and retirement; then the engine's eager
     per-shape programs run once for every shape the mix can bring: the
     prompt copy into the token buffer (one program per prompt length) and
-    the page invalidation at retirement (one per page count)."""
+    the page invalidation at retirement (one per page count). The prompt
+    copy also runs on the engine's state as made, before any round: across
+    chips a round returns that state laid out otherwise, and the first
+    prompts of the window meet the state as made."""
     import jax
     import jax.numpy as jnp
     from repro.serving import ServeRequest
     from repro.serving.kv_pool import invalidate_pages
     lines = [] if lines is None else lines
     engine = make_engine(models, params, serving, EngineTracer())
+    pairs = traffic_mod.shapes(mix)
+    plens = sorted({p for p, _ in pairs})
+
+    def copy_prompts(tokens):
+        for n in plens:
+            tokens.at[0, :n].set(jnp.zeros((n,), jnp.int32))
+        jax.block_until_ready(tokens)
+    copy_prompts(engine._state["tokens"])
     g, c = serving["gamma"], serving["prefill_chunk"]
     for i in range(serving["max_batch"]):
         # the first prompt spans two chunks: a chunk at a non-zero offset
@@ -139,11 +158,7 @@ def warm_up(models, params, serving: dict, mix: dict, lines=None):
         engine.step()
     _log(lines, "warm-up: every slot served")
     st = engine._state
-    pairs = traffic_mod.shapes(mix)
-    plens = sorted({p for p, _ in pairs})
-    for n in plens:
-        st["tokens"].at[0, :n].set(jnp.zeros((n,), jnp.int32))
-    jax.block_until_ready(st)
+    copy_prompts(st["tokens"])
     _log(lines, f"warm-up: {len(plens)} prompt lengths")
     pages = sorted({engine.pool.pages_needed(engine._worst_case_tokens(
         ServeRequest(prompt=np.zeros((p,), np.int32), max_new_tokens=m)))
@@ -217,6 +232,7 @@ def reference_gaps(samples, dims, key, serving: dict, control=False):
 
 
 def device_info(chips: int, allow_cpu: bool):
+    """The cell's chips: the first ``chips`` devices."""
     import jax
     devs = jax.devices()
     if devs[0].platform != "tpu" and not allow_cpu:
@@ -225,19 +241,45 @@ def device_info(chips: int, allow_cpu: bool):
     if len(devs) < chips:
         raise SystemExit(f"bench: the cell needs {chips} chips, JAX sees "
                          f"{len(devs)}")
-    return devs[0]
+    return devs[:chips]
+
+
+def cell_mesh(devices):
+    """None for one chip; else the (1, chips) mesh of the axes the
+    program's sharding rules name, tensor parallel over "model"."""
+    if len(devices) == 1:
+        return None
+    from jax.sharding import Mesh
+    return Mesh(np.array(devices).reshape(1, len(devices)), ("data", "model"))
+
+
+def serving_mesh(mesh):
+    """The program's launch contract for a mesh (``use_mesh``); nothing for
+    one chip."""
+    if mesh is None:
+        return contextlib.nullcontext()
+    from repro.sharding.context import use_mesh
+    return use_mesh(mesh, (), "model")
+
+
+def memory_peaks(devices):
+    """Peak bytes in use on each chip; None where the backend reports
+    none."""
+    return [(d.memory_stats() or {}).get("peak_bytes_in_use")
+            for d in devices]
 
 
 @dataclass
 class Prepared:
-    """A cell's files, its device and the program's models for it."""
+    """A cell's files, its chips and mesh, and the program's models."""
     benchmark: dict
     cell: dict
     config: dict
     mix: dict
     serving: dict
     check: dict
-    dev: object
+    devices: list
+    mesh: object
     models: list
     dims: list
 
@@ -252,10 +294,11 @@ def prepare(workload: str, bench_dir: Path = BENCH, benchmark=None,
     if serving["top_p"] != 1.0 or serving["temperature"] <= 0:
         raise ValueError("the replay check handles temperature > 0 with "
                          "top_p 1.0 only")
-    dev = device_info(cell["chips"], allow_cpu)
+    devices = device_info(cell["chips"], allow_cpu)
     models = program_models(config)
-    return Prepared(benchmark, cell, config, mix, serving, mix["check"], dev,
-                    models, [m[1] for m in models])
+    return Prepared(benchmark, cell, config, mix, serving, mix["check"],
+                    devices, cell_mesh(devices), models,
+                    [m[1] for m in models])
 
 
 def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
@@ -273,32 +316,11 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
 
     c = prepare(workload, bench_dir, benchmark, allow_cpu)
     benchmark, mix, serving, check = c.benchmark, c.mix, c.serving, c.check
-    dev, models, dims = c.dev, c.models, c.dims
+    devices, models, dims = c.devices, c.models, c.dims
+    dev = devices[0]
     vocab = dims[0]["vocab_size"]
     lines = []
-
-    # ---------------------------------------------------------- set-up
     key = base_key(seed)
-    with CompileWatcher() as setup_clock:
-        t0 = time.perf_counter()
-        params = weights.build([(m, d, tag) for m, d, tag in models], key)
-        jax.block_until_ready(params)
-        t_weights = time.perf_counter() - t0
-        reqs = traffic_mod.generate(mix, seed, seconds, vocab)
-        t0 = time.perf_counter()
-        _log(lines, f"weights: {t_weights:.3f}s")
-        warm_up(models, params, serving, mix, lines)
-        t_warm = time.perf_counter() - t0
-        tracer = EngineTracer(annotate=trace)
-        engine = make_engine(models, params, serving, tracer)
-        if fault is not None:
-            fault(engine)
-    setup_s = time.perf_counter() - t_process
-    _log(lines, f"setup: {setup_s:.3f}s (weights {t_weights:.3f}s, warm-up "
-                 f"{t_warm:.3f}s, compile {setup_clock.seconds:.3f}s in "
-                 f"{setup_clock.n_compiles} compiles)")
-
-    # ---------------------------------------------------------- window
     ramp = mix["ramp_s"]
     mid = ramp + seconds / 2
     profile = {"on": False, "done": False, "ann": None, "t": None}
@@ -318,22 +340,47 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
             profile["on"], profile["done"] = False, True
             profile["t"][1] = t
 
-    with CompileWatcher() as window_clock:
-        rec = run_window(engine, reqs, mix, seconds, tracer, on_tick)
-    if profile["on"]:
-        on_tick(float("inf"), rec)
-    mem = dev.memory_stats() or {}
-    peak = mem.get("peak_bytes_in_use")
+    with serving_mesh(c.mesh):
+        # ------------------------------------------------------ set-up
+        with CompileWatcher() as setup_clock:
+            t0 = time.perf_counter()
+            params = weights.build(models, key, c.mesh)
+            jax.block_until_ready(params)
+            t_weights = time.perf_counter() - t0
+            reqs = traffic_mod.generate(mix, seed, seconds, vocab)
+            t0 = time.perf_counter()
+            _log(lines, f"weights: {t_weights:.3f}s")
+            warm_up(models, params, serving, mix, lines)
+            t_warm = time.perf_counter() - t0
+            tracer = EngineTracer(annotate=trace)
+            engine = make_engine(models, params, serving, tracer)
+            if fault is not None:
+                fault(engine)
+        setup_s = time.perf_counter() - t_process
+        _log(lines, f"setup: {setup_s:.3f}s (weights {t_weights:.3f}s, "
+                     f"warm-up {t_warm:.3f}s, compile "
+                     f"{setup_clock.seconds:.3f}s in "
+                     f"{setup_clock.n_compiles} compiles)")
+
+        # ------------------------------------------------------ window
+        with CompileWatcher() as window_clock:
+            rec = run_window(engine, reqs, mix, seconds, tracer, on_tick)
+        if profile["on"]:
+            on_tick(float("inf"), rec)
+    per_chip = memory_peaks(devices)
+    peak = max((p for p in per_chip if p is not None), default=None)
     _log(lines, f"window: {len(rec.counted)} counted requests, "
                  f"{rec.window_tokens} tokens in {seconds}s; "
                  f"{window_clock.n_compiles} compiles in the window "
                  f"{window_clock.signatures}; generator late "
                  f"max {1e3 * max(rec.late.values(), default=0):.1f}ms; "
-                 f"drain ended at {rec.drain_end:.1f}s")
+                 f"drain ended at {rec.drain_end:.1f}s; memory peak per "
+                 f"chip {per_chip}")
 
     failed = sum(1 for rid in rec.counted if rid not in rec.finish)
     device = {"platform": dev.platform, "kind": dev.device_kind,
-              "count": len(jax.devices()), "memory_peak_bytes": peak}
+              "count": len(devices), "memory_peak_bytes": peak,
+              "memory_peak_bytes_per_chip": per_chip}
     result = {"correct": False, "attempted": len(rec.counted),
               "failed": failed, "metrics": {}, "device": device}
     units = {m["name"]: m["unit"] for m in
@@ -344,15 +391,17 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
         e2e = stats.end_to_end(rec, setup_s)
         values = {n: e2e[n] for n in wanted}
     else:
-        tr = tracing.load(str(TRACE_DIR)) if profile["done"] else None
+        tr = (tracing.load(str(TRACE_DIR), {d.id for d in devices})
+              if profile["done"] else None)
         lo, hi = profile["t"] or (0, 0)
         traced = [[c for _, c in rows] for t, rows in rec.rounds
                   if lo <= t < hi and rows]
+        chip = peaks(dev.device_kind) if dev.platform == "tpu" else {}
         ctx = Context(rec, engine.stats, setup_clock.seconds, tr, traced,
                       (counts.model_counts(params[0], engine._state["t_cache"]),
                        counts.model_counts(params[1], engine._state["d_cache"])),
-                      serving["gamma"], peaks(dev.device_kind)
-                      if dev.platform == "tpu" else {})
+                      serving["gamma"],
+                      {k: v * len(devices) for k, v in chip.items()})
         values = spec.read_metrics(wanted, ctx, bench_dir)
         if tr is not None:
             device["busy_s"] = tracing.busy_s(tr)

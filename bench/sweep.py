@@ -39,38 +39,39 @@ def main(argv=None):
 
     c = run.prepare(args.workload)
     rates = [float(r) for r in args.rates.split(",")]
-    params = weights.build(c.models, base_key(args.seed))
-    jax.block_until_ready(params)
-    run.warm_up(c.models, params, c.serving, c.mix)
-    for rate in rates:
-        mix = dict(c.mix, rate_per_s=rate, drain_cap_s=args.drain_cap)
-        reqs = traffic.generate(mix, args.seed, args.seconds,
-                                c.dims[0]["vocab_size"])
-        tracer = EngineTracer()
-        engine = run.make_engine(c.models, params, c.serving, tracer)
-        depth = {}
-        marks = {"start": mix["ramp_s"],
-                 "mid": mix["ramp_s"] + args.seconds / 2,
-                 "end": mix["ramp_s"] + args.seconds}
+    with run.serving_mesh(c.mesh):
+        params = weights.build(c.models, base_key(args.seed), c.mesh)
+        jax.block_until_ready(params)
+        run.warm_up(c.models, params, c.serving, c.mix)
+        for rate in rates:
+            mix = dict(c.mix, rate_per_s=rate, drain_cap_s=args.drain_cap)
+            reqs = traffic.generate(mix, args.seed, args.seconds,
+                                    c.dims[0]["vocab_size"])
+            tracer = EngineTracer()
+            engine = run.make_engine(c.models, params, c.serving, tracer)
+            depth = {}
+            marks = {"start": mix["ramp_s"],
+                     "mid": mix["ramp_s"] + args.seconds / 2,
+                     "end": mix["ramp_s"] + args.seconds}
 
-        def on_tick(t, rec, engine=engine, depth=depth):
-            for name, at in marks.items():
-                if name not in depth and t >= at:
-                    depth[name] = len(engine.scheduler)
+            def on_tick(t, rec, engine=engine, depth=depth):
+                for name, at in marks.items():
+                    if name not in depth and t >= at:
+                        depth[name] = len(engine.scheduler)
 
-        rec = run_window(engine, reqs, mix, args.seconds, tracer, on_tick)
-        ttft, tpot = stats.latencies(rec)
-        out = {"workload": args.workload, "rate_per_s": rate,
-               "counted": len(rec.counted), "finished": len(ttft),
-               "tokens_per_s": rec.window_tokens / args.seconds,
-               "ttft_p50_ms": 1e3 * stats.percentile(ttft, 50),
-               "ttft_p90_ms": 1e3 * stats.percentile(ttft, 90),
-               "tpot_p50_ms": 1e3 * stats.percentile(tpot, 50),
-               "tpot_p90_ms": 1e3 * stats.percentile(tpot, 90),
-               "queue_depth": depth}
-        print(json.dumps(out), flush=True)
-        del engine
-        gc.collect()
+            rec = run_window(engine, reqs, mix, args.seconds, tracer, on_tick)
+            ttft, tpot = stats.latencies(rec)
+            out = {"workload": args.workload, "rate_per_s": rate,
+                   "counted": len(rec.counted), "finished": len(ttft),
+                   "tokens_per_s": rec.window_tokens / args.seconds,
+                   "ttft_p50_ms": 1e3 * stats.percentile(ttft, 50),
+                   "ttft_p90_ms": 1e3 * stats.percentile(ttft, 90),
+                   "tpot_p50_ms": 1e3 * stats.percentile(tpot, 50),
+                   "tpot_p90_ms": 1e3 * stats.percentile(tpot, 90),
+                   "queue_depth": depth}
+            print(json.dumps(out), flush=True)
+            del engine
+            gc.collect()
 
 
 if __name__ == "__main__":

@@ -1,7 +1,7 @@
 """Parameter, K/V and round counts against hand counts, read from the
 served layouts (shapes only, nothing allocated) of the benchmark's
-configuration and of yi-9b at 24 layers, whose file waits in the test data
-for the cell that will run it."""
+configuration and of yi-9b, whole and at 24 layers, whose files wait in the
+test data for the cell that will run it."""
 import sys
 from pathlib import Path
 
@@ -41,6 +41,8 @@ def _dense(layers, d, heads, kv_heads, hd, ff, vocab, tied):
      (4, 1024, 8, 4, 128, 2816, 200064, False), (3.836, 0.457), 139_264),
     ("yi-9b-24l", (24, 4096, 32, 4, 128, 11008, 64000, False),
      (4, 1024, 8, 4, 128, 2816, 64000, False), (4.677, 0.178), 57_344),
+    ("yi-9b", (48, 4096, 32, 4, 128, 11008, 64000, False),
+     (4, 1024, 8, 4, 128, 2816, 64000, False), (8.829, 0.178), 106_496),
 ])
 def test_params_and_kv_bytes_per_token(name, target, drafter, billions, kv):
     (tp, tpool), (dp, dpool) = _served(name)

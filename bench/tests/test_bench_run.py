@@ -86,7 +86,14 @@ def test_broken_timed_path_is_not_correct(tiny, fault, monkeypatch):
     from repro.core import sampling, speculative
     monkeypatch.setattr(speculative, "residual_sample",
                         lambda key, q, p: sampling.sample_from_probs(key, q))
-    res = _run(tiny, fault=fault)
+    try:
+        res = _run(tiny, fault=fault)
+    finally:
+        # a round traced under the patch would be served by later tests
+        for f in vars(speculative).values():
+            if hasattr(f, "cache_clear"):
+                f.cache_clear()
+        jax.clear_caches()
     assert res["correct"] is False
 
 

@@ -9,7 +9,7 @@ sys.path.insert(0, str(Path(__file__).resolve().parents[1]))
 from lib import tracing  # noqa: E402
 
 
-def _trace():
+def _events():
     # window [0, 10); device ops: [1, 3), [2, 4) overlap -> busy [1, 4);
     # [6, 7); an op [9, 12) is clipped to [9, 10). Busy 3 + 1 + 1 = 5.
     ops = [("fusion.1", 1.0, 3.0), ("fusion.2", 2.0, 4.0),
@@ -27,8 +27,13 @@ def _trace():
     host = [("bench_window", 0.0, 10.0), ("decode_round", 0.5, 4.5),
             ("prefill_chunk", 5.0, 7.5), ("step", 4.0, 9.0),
             ("admit", 8.5, 8.9)]
+    return {"ops": ops, "modules": modules}, host
+
+
+def _trace(planes=1):
+    lines, host = _events()
     return tracing.reduce_events(
-        {"/device:TPU:0": {"ops": ops, "modules": modules}}, host)
+        {f"/device:TPU:{i}": lines for i in range(planes)}, host)
 
 
 def test_busy_union_and_idle_share():
@@ -80,3 +85,22 @@ def test_breakdown_labels_gaps_by_innermost_host_span():
 def test_missing_window_span_is_an_error():
     with pytest.raises(ValueError, match="bench_window"):
         tracing.reduce_events({}, [("decode_round", 0.0, 1.0)])
+
+
+def test_four_identical_chips_read_as_one():
+    """A cell across four chips reads per chip: the same op times,
+    breakdown and idle share as one chip running the same ops."""
+    one, four = _trace(), _trace(planes=4)
+    assert four.op_time == pytest.approx(one.op_time)
+    assert tracing.breakdown(four) == tracing.breakdown(one)
+    assert tracing.busy_s(four) == pytest.approx(tracing.busy_s(one))
+    assert tracing.round_time(four) == tracing.round_time(one)
+
+
+@pytest.mark.parametrize("plane,dev", [
+    ("/device:TPU:0", 0), ("/device:TPU:3", 3), ("/host:CPU", None),
+    ("/device:TPU:0 SparseCore 1", None), ("/device:CPU:0", None),
+    ("#Chip0 Host Interface", None),
+])
+def test_device_planes_by_id(plane, dev):
+    assert tracing._device_id(plane) == dev
