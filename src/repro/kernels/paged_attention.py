@@ -13,7 +13,10 @@ Layout: the pools are the model's stacked per-layer pools
 ``(L, P, page, Hkv, hd)`` with the layer as a scalar operand, so the layer
 scan hands the kernel the loop's own buffer and nothing is sliced out of
 it. The queries of one row and KV head, ``R = T * G`` rows (GQA), are
-scored in one matmul per block.
+scored in one matmul per block. A pool of one 16-bit KV head (one chip's
+share of a pool split by head) is read as ``(L, P, page, hd)``: XLA keeps
+the unit head axis outside the tiled ``(page, hd)`` pair, so dropping it is
+a bitcast, and each page is one ``page x hd`` block, with no second head.
 
 What a row reads: logical pages ``first .. end - 1`` where ``end`` is one
 past the page holding the row's largest query position, and ``first`` is
@@ -48,8 +51,11 @@ Q_ROWS = 256            # query rows per grid step when a row has more
 def supports(num_kv_heads: int, dtype) -> bool:
     """Can the kernel read a pool of ``dtype`` with ``num_kv_heads`` heads?
     Heads narrower than 32 bits are loaded a 32-bit row at a time
-    (``_heads``), so their count must fill whole rows."""
-    return num_kv_heads % max(1, 4 // jnp.dtype(dtype).itemsize) == 0
+    (``_heads``), so their count must fill whole rows; a single 16-bit head
+    is read without its head axis."""
+    size = jnp.dtype(dtype).itemsize
+    return num_kv_heads % max(1, 4 // size) == 0 or (
+        num_kv_heads == 1 and size == 2)
 
 
 def _live_span(page_table, pos, page: int, window=None,
@@ -69,8 +75,14 @@ def _live_span(page_table, pos, page: int, window=None,
 
 def _heads(kref, vref):
     """Yield (head, K, V) of one block, each (npb * page, hd), from VMEM
-    blocks (npb, page, Hkv, hd). 16-bit heads pack two to a 32-bit row:
-    one sublane-strided 32-bit load gives a pair, split by shifts."""
+    blocks (npb, page, Hkv, hd), or (npb, page, hd) for a single head.
+    16-bit heads pack two to a 32-bit row: one sublane-strided 32-bit load
+    gives a pair, split by shifts."""
+    if kref.ndim == 3:
+        npb, page, hd = kref.shape
+        yield 0, kref[...].reshape(npb * page, hd), \
+            vref[...].reshape(npb * page, hd)
+        return
     npb, page, hkv, hd = kref.shape
     S = npb * page
     if jnp.dtype(kref.dtype).itemsize == 4:
@@ -229,6 +241,10 @@ def paged_attention(q, k_pool, v_pool, page_pos, page_table, pos, layer=0,
     page_table = page_table.astype(jnp.int32)
     pos = pos.astype(jnp.int32)
     layer = jnp.asarray(layer, jnp.int32).reshape(1)
+    if hkv == 1 and jnp.dtype(k_pool.dtype).itemsize == 2:
+        k_pool = k_pool.reshape(k_pool.shape[:3] + (hd,))
+        v_pool = v_pool.reshape(v_pool.shape[:3] + (hd,))
+    buf = (2, npb) + k_pool.shape[2:]
 
     span = _live_span(page_table, pos, page, window, npb)
     # slot positions through the table; null entries and pages past the
@@ -261,8 +277,8 @@ def paged_attention(q, k_pool, v_pool, page_pos, page_table, pos, layer=0,
             out_specs=pl.BlockSpec((1, hkv, rb, hd),
                                    lambda b, i, *_: (b, 0, i, 0)),
             scratch_shapes=[
-                pltpu.VMEM((2, npb, page, hkv, hd), k_pool.dtype),
-                pltpu.VMEM((2, npb, page, hkv, hd), v_pool.dtype),
+                pltpu.VMEM(buf, k_pool.dtype),
+                pltpu.VMEM(buf, v_pool.dtype),
                 pltpu.SemaphoreType.DMA((2, 2)),
                 pltpu.VMEM((hkv, rb, 1), jnp.float32),
                 pltpu.VMEM((hkv, rb, 1), jnp.float32),

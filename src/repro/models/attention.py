@@ -253,23 +253,57 @@ def decode_attention(params, x, cache, pos, cfg,
     return out, new_cache
 
 
+def _model_shards() -> int:
+    """Chips of the installed mesh's model axis (1 without a mesh)."""
+    mesh, axis = shctx.get_mesh(), shctx.model_axis()
+    return 1 if mesh is None or axis is None else mesh.shape[axis]
+
+
 def _use_paged_kernel(kpool, attn_mask) -> bool:
     """Read the paged pool in place with the Pallas kernel
     (kernels.paged_attention) instead of gathering every row's span?
 
     Eligible: no slot mask (tree verify has its own kernel), a float pool
-    (int8 pools dequantize in the gather) whose KV heads the kernel can
-    load (``paged_attention.supports``). Auto mode then uses it whenever
-    Pallas compiles natively; in interpret mode the gather is faster.
+    (int8 pools dequantize in the gather) whose KV heads split evenly over
+    the mesh's model axis and whose heads on one chip the kernel can load
+    (``paged_attention.supports``). Auto mode then uses it whenever Pallas
+    compiles natively; in interpret mode the gather is faster.
     ``PAGED_FASTPATH`` True/False forces the choice for eligible calls."""
     from ..kernels import ops, paged_attention as pk
     if attn_mask is not None or not jnp.issubdtype(kpool.dtype, jnp.floating):
         return False
-    if not pk.supports(kpool.shape[-2], kpool.dtype):
+    hkv, n = kpool.shape[-2], _model_shards()
+    if hkv % n or not pk.supports(hkv // n, kpool.dtype):
         return False
     if PAGED_FASTPATH is not None:
         return PAGED_FASTPATH
     return not ops.INTERPRET
+
+
+def _paged_kernel(q, kpool, vpool, page_pos, page_table, pos, layer,
+                  softcap, window):
+    """``ops.paged_attention``, on each chip over its own KV heads where a
+    mesh splits the model: a Mosaic kernel cannot be partitioned
+    automatically, so it runs under ``shard_map`` over the model axis with
+    q and the output split on heads and the pools on KV heads (a chip's
+    query heads are those of its KV heads), the page table, slot and query
+    positions replicated. Attention needs no collective."""
+    from ..kernels import ops
+
+    def call(q, k, v, pp, table, qpos, layer):
+        return ops.paged_attention(q, k, v, pp, table, qpos, layer,
+                                   softcap=softcap, window=window)
+    if _model_shards() == 1:
+        return call(q, kpool, vpool, page_pos, page_table, pos, layer)
+    from jax.sharding import PartitionSpec as P
+    axis = shctx.model_axis()
+    heads, pools = P(None, None, axis), P(None, None, None, axis)
+    return jax.shard_map(
+        call, mesh=shctx.get_mesh(),
+        in_specs=(heads, pools, pools, P(), P(), P(), P()),
+        out_specs=heads, check_vma=False,
+    )(q, kpool, vpool, page_pos, page_table, pos,
+      jnp.asarray(layer, jnp.int32))
 
 
 def paged_decode_attention(params, x, cache, page_table, pos, cfg,
@@ -340,7 +374,8 @@ def paged_decode_attention(params, x, cache, page_table, pos, cfg,
         from ..quant.kvcache import dequantize_kv_entry, quantize_kv_entry
     # device scopes: the pool scatter, the per-row gather of every mapped
     # page (with its reshape and cast), and the attention over the view —
-    # or, on the kernel path, the kernel's in-place reads under ``sdpa``
+    # or, on the kernel path, the kernel's in-place reads under
+    # ``sdpa/paged_kernel``
     with jax.named_scope("kv_write"):
         if kv_quant:
             kq, ks = quantize_kv_entry(k)
@@ -356,11 +391,10 @@ def paged_decode_attention(params, x, cache, page_table, pos, cfg,
         page_pos = page_pos.at[layer, phys, off].set(
             write_pos.astype(jnp.int32))
     if _use_paged_kernel(kpool, attn_mask):
-        from ..kernels import ops
-        with jax.named_scope("sdpa"):
-            out = ops.paged_attention(q, kpool, vpool, page_pos, page_table,
-                                      pos, layer, softcap=cfg.attn_softcap,
-                                      window=window).astype(q.dtype)
+        with jax.named_scope("sdpa"), jax.named_scope("paged_kernel"):
+            out = _paged_kernel(q, kpool, vpool, page_pos, page_table, pos,
+                                layer, cfg.attn_softcap,
+                                window).astype(q.dtype)
     else:
         with jax.named_scope("kv_gather"):
             if kv_quant:

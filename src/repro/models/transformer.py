@@ -237,6 +237,22 @@ def init_paged_cache(cfg, num_pages, page_size, kv_quant=False):
             "rem": tuple(one(kind) for kind in rem)}
 
 
+def paged_cache_specs(cache):
+    """Logical sharding specs of a paged pool's leaves (``init_paged_cache``,
+    arrays or shapes): K/V ``(..., Hkv, hd)`` and the int8 scales ``(...,
+    Hkv)`` split on the KV-head axis as the heads' tensor-parallel axis
+    ("tp", as ``wk``/``wv`` split their columns), slot positions replicated.
+    The pool has no per-row sequence axis, so "cache_seq" never applies."""
+    def spec(path, x):
+        name = path[-1].key
+        if name in ("k", "v"):
+            return (None,) * (x.ndim - 2) + ("tp", None)
+        if name in ("k_scale", "v_scale"):
+            return (None,) * (x.ndim - 1) + ("tp",)
+        return (None,) * x.ndim
+    return jax.tree_util.tree_map_with_path(spec, cache)
+
+
 # ---------------------------------------------------------------- model init
 
 def init_params(key, cfg):

@@ -43,7 +43,7 @@ import gc
 import time
 import weakref
 from dataclasses import dataclass, field, replace
-from functools import lru_cache
+from functools import lru_cache, partial
 from typing import Dict, List, Optional, Sequence
 
 import jax
@@ -57,15 +57,18 @@ from ..core.speculative import (SDConfig, _cached_decode,
                                 _cached_phased_tree_round,
                                 _cached_round_donated,
                                 _cached_tree_round_donated, attention_only,
-                                init_quality_buffer, trim_paged_cache)
+                                init_quality_buffer, sd_round,
+                                trim_paged_cache, tree_sd_round)
 from ..draftheads import HeadDrafter
 from ..kernels.tree_attention import kv_window
 from ..models.model import Model
 from ..obs import (NULL_TRACER, FlightRecorder, PhaseTimer, QualityStats,
                    SLOTracker)
+from ..sharding import context as shctx
 from ..spectree.tree import TreeSpec
 from .engine import Request, Result
-from .kv_pool import PagedKVPool, ceil_div, copy_pages, invalidate_pages
+from .kv_pool import (PagedKVPool, ceil_div, copy_pages, invalidate_pages,
+                      state_shardings)
 from .prefix_cache import PrefixCache
 from .scheduler import Scheduler, ServeRequest
 
@@ -106,6 +109,68 @@ def _trace_gc(engine):
 
     gc.callbacks.append(hook)
     weakref.finalize(engine, gc.callbacks.remove, hook)
+
+
+def _init_state(target, draft, batch, buf, max_pages, num_pages, page_size,
+                kv_quant, qdepth):
+    """The engine's state as made: token buffer, lengths, pending tokens,
+    active mask, page table and the paged pools, empty. Without a
+    ``draft`` model (draft heads) there is no drafter pool, and ``h_feat``
+    carries the target feature per slot; ``qdepth`` seeds the quality
+    buffers so the round's input pytree matches its output from round 1
+    (one compilation, not two)."""
+    state = {
+        "tokens": jnp.zeros((batch, buf), jnp.int32),
+        "lengths": jnp.zeros((batch,), jnp.int32),
+        "pending": jnp.zeros((batch,), jnp.int32),
+        "active": jnp.zeros((batch,), bool),
+        "page_table": jnp.zeros((batch, max_pages), jnp.int32),
+        "t_cache": target.init_paged_cache(num_pages, page_size,
+                                           kv_quant=kv_quant),
+    }
+    if draft is None:
+        state["h_feat"] = jnp.zeros((batch, target.cfg.d_model),
+                                    target.cfg.compute_dtype)
+    else:
+        state["d_cache"] = draft.init_paged_cache(num_pages, page_size,
+                                                  kv_quant=kv_quant)
+    if qdepth is not None:
+        state["qual"] = init_quality_buffer(batch, qdepth)
+    return state
+
+
+def _jit_keeping(step, at, sharding, donate):
+    """``step`` jitted with argument ``donate`` donated and its output
+    ``at`` constrained to ``sharding``, the layout that argument comes in,
+    so what was donated aliases what comes out."""
+    def kept(*args, **kw):
+        out = list(step(*args, **kw))
+        out[at] = jax.lax.with_sharding_constraint(out[at], sharding)
+        return tuple(out)
+    return jax.jit(kept, donate_argnums=(donate,))
+
+
+@lru_cache(maxsize=8)
+def _cached_mesh_programs(mesh, drafter, sd, tree, *shape):
+    """Across chips, the engine's layout over ``mesh`` and the programs
+    that keep it: (jitted ``_init_state(*shape)`` that makes every leaf
+    straight into its sharding, those shardings, the donated round, the
+    drafter's and the target's donated prefill steps). The round returns
+    the state, a prefill step its pool, in that layout. Module-level, so a
+    fresh engine of the same shapes compiles nothing."""
+    target, draft = shape[:2]
+    make = partial(_init_state, *shape)
+    layout = state_shardings(jax.eval_shape(make), mesh)
+    round_fn = (partial(sd_round, drafter, target, sd) if tree is None
+                else partial(tree_sd_round, drafter, target, sd, tree))
+    lc = sd.long_context
+    d_step = (None if draft is None else _jit_keeping(
+        partial(draft.decode_step, long_context=lc), 1, layout["d_cache"], 3))
+    t_step = _jit_keeping(partial(target.decode_step, long_context=lc,
+                                  return_hidden=draft is None),
+                          1, layout["t_cache"], 3)
+    return (jax.jit(make, out_shardings=layout), layout,
+            _jit_keeping(round_fn, 0, layout, 2), d_step, t_step)
 
 
 @dataclass
@@ -252,37 +317,35 @@ class ContinuousEngine:
             self._m_accept = None
 
         B, buf = self.max_batch, self._row_cap + self._span + 1
-        self._state = {
-            "tokens": jnp.zeros((B, buf), jnp.int32),
-            "lengths": jnp.zeros((B,), jnp.int32),
-            "pending": jnp.zeros((B,), jnp.int32),
-            "active": jnp.zeros((B,), bool),
-            "page_table": jnp.zeros((B, max_pages), jnp.int32),
-            "t_cache": self.target.init_paged_cache(
-                self.num_pages, self.page_size, kv_quant=self.kv_quant),
-        }
-        if self.draft_heads is not None:
-            # no drafter pool at all — the drafter-memory win of self-
-            # speculation. h_feat carries the target feature per slot.
-            self._state["h_feat"] = jnp.zeros(
-                (B, self.target.cfg.d_model), self.target.cfg.compute_dtype)
-        else:
-            self._state["d_cache"] = self.draft.init_paged_cache(
-                self.num_pages, self.page_size, kv_quant=self.kv_quant)
         # draft positions per round the quality buffers cover (tree rounds
         # report along the committed root path, depth-indexed like the chain)
         self._qdepth = self.tree.depth if self.tree is not None else g
+
+        shape = (self.target, self.draft, B, buf, max_pages, self.num_pages,
+                 self.page_size, self.kv_quant,
+                 self._qdepth if self.quality else None)
+        drafter = self.draft_heads if self.draft_heads is not None else self.draft
+        # across chips (the mesh the launch code installed, tensor parallel
+        # over its model axis) every leaf is made straight into its layout:
+        # the pools split by KV head, all else replicated. The donated round
+        # and prefill steps return that layout and ``_pin`` restores it
+        # after eager updates, so donated state aliases and no chip ever
+        # holds a whole pool.
+        mesh = shctx.get_mesh()
+        self._layout = None
+        if mesh is not None and mesh.size > 1:
+            make, self._layout, *steps = _cached_mesh_programs(
+                mesh, drafter, self.sd, self.tree, *shape)
+            self._state = make()
+        else:
+            self._state = _init_state(*shape)
         self.quality_stats: Optional[QualityStats] = None
         self.tenant_quality: Dict[str, QualityStats] = {}
         if self.quality:
-            # seed the buffer so the round's input pytree structure matches
-            # its output from round 1 — one compilation, not two
-            self._state["qual"] = init_quality_buffer(B, self._qdepth)
             self.quality_stats = QualityStats(depth=self._qdepth)
         self.recorder = (FlightRecorder(out_dir=self.flight_dir)
                          if self.flight_record else None)
         self.slo_tracker = SLOTracker(self.slo) if self.slo is not None else None
-        drafter = self.draft_heads if self.draft_heads is not None else self.draft
         self._d_params = (self.draft_head_params
                           if self.draft_heads is not None else self.draft_params)
         self._slots = [_Slot() for _ in range(B)]
@@ -318,6 +381,9 @@ class ContinuousEngine:
                         if self.draft_heads is not None
                         else _cached_decode(self.target, self.sd.long_context))
         self._key = jax.random.PRNGKey(0)
+        if self._layout is not None:
+            self._round, self._d_step, self._t_step = steps
+            self._key = jax.device_put(self._key, self._layout["lengths"])
         self._admit_seq = 0
         self._t0: Optional[float] = None
         self._last_sanitize = 0
@@ -327,6 +393,15 @@ class ContinuousEngine:
         if self._t0 is None:
             self._t0 = time.perf_counter()
         return time.perf_counter() - self._t0
+
+    def _pin(self):
+        """Across chips, put every state leaf back in the layout the donated
+        programs were compiled for (a no-op for a leaf already there): eager
+        updates leave their output's layout to the compiler."""
+        if self._layout is not None:
+            self._state = jax.tree.map(
+                lambda x, s: x if x.sharding == s else jax.device_put(x, s),
+                self._state, self._layout)
 
     # ---------------------------------------------------------------- submit
     def _worst_case_tokens(self, req: ServeRequest) -> int:
@@ -465,6 +540,7 @@ class ContinuousEngine:
 
     # ---------------------------------------------------------------- prefill
     def _prefill_one_chunk(self, i: int):
+        self._pin()
         slot, st = self._slots[i], self._state
         req, C = slot.req, self.prefill_chunk
         start = slot.prefill_pos
@@ -615,6 +691,7 @@ class ContinuousEngine:
     def _decode_round(self) -> List[tuple]:
         tr = self._tr
         with tr.span("round_dispatch"):
+            self._pin()
             st = self._state
             self._key, kr = jax.random.split(self._key)
             old_len = self._lengths_h.copy()

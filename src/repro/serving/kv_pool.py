@@ -38,11 +38,13 @@ permutation to apply to the device pools (``apply_page_permutation``).
 from __future__ import annotations
 
 from dataclasses import dataclass, field
+from functools import lru_cache, partial
 from typing import Dict, Iterable, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding
 
 from ..core.speculative import _leaf_batch_axis, _leaf_name
 
@@ -273,17 +275,45 @@ def invalidate_pages(cache, page_ids):
     With prefix sharing, apply this only to the pages ``free_slot``/
     ``release`` actually freed — a retiring request's prefix pages may still
     back other rows (or the prefix cache).
+
+    A pool across chips is updated by one jitted program per page count
+    that returns each leaf in the sharding it came in, so the engine's
+    donated steps meet the layout they were compiled for; a pool on one
+    device takes the eager update.
     """
     idx = jnp.asarray(page_ids, jnp.int32)
 
     def f(path, leaf):
         if _leaf_name(path) == "page_pos":
-            if _leaf_batch_axis(path) == 1:   # stacked groups: (n, P, page)
-                return leaf.at[:, idx].set(-1)
-            return leaf.at[idx].set(-1)       # (P, page)
+            # stacked groups: (n, P, page); else (P, page)
+            axis = _leaf_batch_axis(path)
+            sharding = _across_chips(leaf)
+            if sharding is not None:
+                return _cached_set_empty(sharding, axis)(leaf, idx)
+            return _set_empty(leaf, idx, axis)
         return leaf
 
     return jax.tree_util.tree_map_with_path(f, cache)
+
+
+def _set_empty(leaf, idx, axis):
+    if axis == 1:
+        return leaf.at[:, idx].set(-1)
+    return leaf.at[idx].set(-1)
+
+
+def _across_chips(leaf):
+    """The leaf's NamedSharding where it lies on a mesh of several devices;
+    None on one device (or while tracing)."""
+    if isinstance(leaf, jax.core.Tracer):
+        return None
+    s = getattr(leaf, "sharding", None)
+    return s if isinstance(s, NamedSharding) and s.mesh.size > 1 else None
+
+
+@lru_cache(maxsize=16)
+def _cached_set_empty(sharding, axis):
+    return jax.jit(partial(_set_empty, axis=axis), out_shardings=sharding)
 
 
 def copy_pages(cache, src_ids, dst_ids):
@@ -316,3 +346,24 @@ def apply_page_permutation(cache, perm):
         return jnp.take(leaf, perm, axis=_leaf_batch_axis(path))
 
     return jax.tree_util.tree_map_with_path(f, cache)
+
+
+def pool_shardings(cache, mesh):
+    """NamedShardings of a paged pool (arrays or shapes) over ``mesh``: its
+    logical specs (``transformer.paged_cache_specs``) under the inference
+    rules, so K/V are split by KV head over the model axis wherever the
+    heads divide it, and replicated otherwise."""
+    from ..models.transformer import paged_cache_specs
+    from ..sharding.rules import INFERENCE_RULES, make_param_shardings
+    return make_param_shardings(paged_cache_specs(cache), cache, mesh,
+                                INFERENCE_RULES)
+
+
+def state_shardings(state, mesh):
+    """The continuous engine's round state over ``mesh``: each paged pool
+    as ``pool_shardings`` lays it out, every other leaf (tokens, lengths,
+    pending, active, page table, quality buffers) replicated."""
+    from jax.sharding import PartitionSpec
+    rep = NamedSharding(mesh, PartitionSpec())
+    return {name: pool_shardings(x, mesh) if name in ("t_cache", "d_cache")
+            else jax.tree.map(lambda _: rep, x) for name, x in state.items()}

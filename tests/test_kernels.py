@@ -172,20 +172,26 @@ def _paged_rows(T, page=8, max_pages=6, P=20, L=2):
     return jnp.asarray(pp_perm), jnp.asarray(tab), jnp.asarray(pos, jnp.int32)
 
 
-@pytest.mark.parametrize("T,G,window,cap,dtype,npb", [
-    (1, 2, None, None, jnp.float32, 4),
-    (4, 3, None, None, jnp.float32, 4),
-    (4, 2, None, None, jnp.bfloat16, 4),
-    (1, 3, 11, None, jnp.float32, 2),
-    (4, 2, None, 5.0, jnp.float32, 4),
-], ids=["t1_g2", "t4_g3", "t4_g2_bf16", "window", "softcap"])
-def test_paged_attention_matches_gather(T, G, window, cap, dtype, npb):
+@pytest.mark.parametrize("T,G,window,cap,dtype,npb,Hkv", [
+    (1, 2, None, None, jnp.float32, 4, 2),
+    (4, 3, None, None, jnp.float32, 4, 2),
+    (4, 2, None, None, jnp.bfloat16, 4, 2),
+    (1, 3, 11, None, jnp.float32, 2, 2),
+    (4, 2, None, 5.0, jnp.float32, 4, 2),
+    (4, 8, None, None, jnp.bfloat16, 4, 1),
+    (4, 8, 11, None, jnp.bfloat16, 2, 1),
+], ids=["t1_g2", "t4_g3", "t4_g2_bf16", "window", "softcap",
+        "t4_g8_bf16_one_head", "window_bf16_one_head"])
+def test_paged_attention_matches_gather(T, G, window, cap, dtype, npb, Hkv):
     """The in-place paged kernel equals the gather + masked-softmax oracle
     (``ref.ref_paged_attention``); an all-null row reads zeros, not NaN.
     Four pages a block leave the 6-page table's last block half past its
-    end; two a block let the window skip the long row's first block."""
+    end; two a block let the window skip the long row's first block. A
+    single 16-bit KV head (a chip's share of a pool split by head) is read
+    without its head axis."""
     from repro.kernels import paged_attention as pk
-    Hkv, hd, L = 2, 32, 2
+    hd, L = 32, 2
+    assert pk.supports(Hkv, dtype)
     page_pos, table, pos = _paged_rows(T)
     P, page = page_pos.shape[1:]
     kk = jax.random.split(KEY, 3)

@@ -77,12 +77,19 @@ def test_flash_decode_compiles(compile_tpu, hkv, g):
         ((B, S), jnp.bool_))
 
 
-@pytest.mark.parametrize("b,t,h,hkv,layers", [
-    (32, 4, 24, 8, 32), (32, 1, 8, 4, 4), (1, 256, 24, 8, 32)],
-    ids=["target_verify", "drafter_feed", "target_prefill"])
-def test_paged_attention_compiles(compile_tpu, b, t, h, hkv, layers):
-    """phi4-chat's widths: 1,200 pages of 16 slots, 257-page tables."""
-    P, page, max_pages = 1200, 16, 257
+@pytest.mark.parametrize("b,t,h,hkv,layers,P", [
+    (32, 4, 24, 8, 32, 1200), (32, 1, 8, 4, 4, 1200),
+    (1, 256, 24, 8, 32, 1200),
+    (32, 4, 8, 1, 48, 4096), (32, 1, 2, 1, 4, 4096),
+    (1, 256, 8, 1, 48, 4096)],
+    ids=["target_verify", "drafter_feed", "target_prefill",
+         "one_head_target_verify", "one_head_drafter_feed",
+         "one_head_target_prefill"])
+def test_paged_attention_compiles(compile_tpu, b, t, h, hkv, layers, P):
+    """phi4-chat's widths: 1,200 pages of 16 slots, 257-page tables; and
+    one chip's share of yi9b-chat's pools split by head over four chips:
+    one bf16 KV head of 4,096 pages."""
+    page, max_pages = 16, 257
     pool = ((layers, P, page, hkv, HD), jnp.bfloat16)
     compile_tpu(
         lambda q, k, v, pp, tab, pos: paged_attention.paged_attention(
@@ -90,6 +97,35 @@ def test_paged_attention_compiles(compile_tpu, b, t, h, hkv, layers):
         ((b, t, h, HD), jnp.bfloat16), pool, pool,
         ((layers, P, page), jnp.int32), ((b, max_pages), jnp.int32),
         ((b, t), jnp.int32))
+
+
+def test_paged_kernel_under_shard_map_compiles(topo, no_compile_cache,
+                                              monkeypatch):
+    """Across the four chips of a v5e 2x2 the paged kernel runs per chip
+    under ``shard_map`` (a Mosaic call cannot be partitioned
+    automatically): Yi-9B's verify, q split on heads and the pools on KV
+    heads, one bf16 head a chip."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    from repro.kernels import ops
+    from repro.models import attention
+    from repro.sharding.context import use_mesh
+    monkeypatch.setattr(ops, "INTERPRET", False)   # Mosaic, as on the chip
+    mesh = Mesh(np.array(topo.devices).reshape(1, 4), ("data", "model"))
+    heads, pools, rep = (NamedSharding(mesh, P(None, None, "model")),
+                         NamedSharding(mesh, P(None, None, None, "model")),
+                         NamedSharding(mesh, P()))
+    pool = (48, 4096, 16, 4, HD)
+    args = [jax.ShapeDtypeStruct(s, d, sharding=sh) for s, d, sh in [
+        ((32, 4, 32, HD), jnp.bfloat16, heads),
+        (pool, jnp.bfloat16, pools), (pool, jnp.bfloat16, pools),
+        ((48, 4096, 16), jnp.int32, rep), ((32, 257), jnp.int32, rep),
+        ((32, 4), jnp.int32, rep)]]
+    with use_mesh(mesh, (), "model"):
+        hlo = jax.jit(lambda *a: attention._paged_kernel(
+            *a, 3, None, None)).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in hlo
+    assert "all-reduce" not in hlo and "all-gather" not in hlo
 
 
 @pytest.mark.parametrize("bits,group", [(8, 0), (4, 64)])
