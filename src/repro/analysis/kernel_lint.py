@@ -293,7 +293,11 @@ def build_kernel_cases() -> List[KernelCase]:
                       64, 8, f32)),
             ("phi4_verify", (32, 4, 24, 8, hd_big, 32, 1200, 257, bf16)),
             ("phi4_prefill", (1, 256, 24, 8, hd_big, 32, 1200, 257, bf16)),
-            ("phi4_drafter", (32, 1, 8, 4, hd_big, 4, 1200, 257, bf16))]:
+            ("phi4_drafter", (32, 1, 8, 4, hd_big, 4, 1200, 257, bf16)),
+            # one chip's share of yi9b-chat's pools split by head over four
+            ("one_head_verify", (32, 4, 8, 1, hd_big, 48, 4096, 257, bf16)),
+            ("one_head_prefill", (1, 256, 8, 1, hd_big, 48, 4096, 257, bf16)),
+            ("one_head_drafter", (32, 1, 2, 1, hd_big, 4, 4096, 257, bf16))]:
         cases.append(KernelCase(
             name=f"paged_attention_{tag}", fn=paged_attention,
             args=(_sds((B, T, H, hd), dt), _sds((L, P, 16, Hkv, hd), dt),

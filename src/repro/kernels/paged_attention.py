@@ -5,9 +5,20 @@ KV pool (``serving.kv_pool``). The gather path in
 ``models.attention.paged_decode_attention`` builds a per-row view of every
 page the table can hold (``max_pages`` x ``page`` slots) and scores all of
 it. This kernel reads only the pages a row has written: K/V pools stay in
-HBM and each row's live pages are DMA'd into VMEM a block of
-``PAGES_PER_BLOCK`` pages at a time, double-buffered, with online softmax
-in float32 over the blocks.
+HBM and each row's live pages are DMA'd into VMEM a block of pages at a
+time, one block landing while the one before it is scored, with online
+softmax in float32 over the blocks.
+
+Tiling (``tiling``), from the call's shapes alone: a block holds as many
+pages as make ``BLOCK_BYTES`` of K and V, since each block pays a fixed
+wait on top of its pages' copies whatever it moves; 8 pages of a pool of
+8 heads, 64 of a pool of one head a chip (4-KB pages). A grid step takes
+all ``T * G`` query rows of a KV head when its tiles fit ``VMEM_BYTES``,
+and otherwise the largest even share of them that fits: each step reads
+the row's pages again, so a prefill chunk takes as few steps as fit. Each
+page is two DMAs, whose scalar bookkeeping is what a small page pays most
+for, so a block copies and waits on its live pages alone, in a loop as
+long as they are.
 
 Layout: the pools are the model's stacked per-layer pools
 ``(L, P, page, Hkv, hd)`` with the layer as a scalar operand, so the layer
@@ -44,8 +55,48 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
-PAGES_PER_BLOCK = 8     # pages per DMA block: 128 slots at page 16
-Q_ROWS = 256            # query rows per grid step when a row has more
+BLOCK_BYTES = 512 * 1024    # K + V one DMA block moves (8 pages of 32 KB)
+VMEM_BYTES = 12 * 1024 * 1024   # the tiles' share of 16 MiB of scoped VMEM
+SCORE_COPIES = 4            # f32 (rows, slots) values live at once a head
+
+
+def vmem_bytes(rows: int, npb: int, page: int, hkv: int, hd: int,
+               itemsize: int, max_pages: int) -> int:
+    """VMEM one grid step of ``rows`` query rows and ``npb``-page blocks
+    takes: the pipelined q, query-position, slot-position and output tiles
+    (two of each), the landing buffers, the softmax state, and the score
+    tiles of one head. Columns of width 1 fill a 128-lane row."""
+    slots = npb * page
+    nb = -(-max_pages // npb)
+    tiles = 2 * (hkv * rows * hd * (itemsize + 4)    # q, f32 output
+                 + rows * 128 * 4 + nb * slots * 4)  # query, slot positions
+    buffers = 2 * 2 * slots * hkv * hd * itemsize
+    softmax = 2 * hkv * rows * 128 * 4               # m, l
+    return tiles + buffers + softmax + SCORE_COPIES * rows * slots * 4
+
+
+def tiling(page: int, hkv: int, hd: int, itemsize: int, rows: int,
+           max_pages: int, pages_per_block=None):
+    """(pages a DMA block, query rows a grid step) for a call's shapes.
+
+    A block moves ``BLOCK_BYTES`` of K and V whatever a page holds, so a
+    pool of one head a chip (4-KB pages) takes 64 pages a block where one
+    of 8 heads takes 8. All
+    ``rows`` query rows of a KV head go in one grid step when their tiles
+    fit ``VMEM_BYTES``, so a row's K/V is read once; otherwise the largest
+    whole share of them that fits (a multiple of 16 rows, the bf16
+    sublane tile). ``pages_per_block`` overrides the first."""
+    page_bytes = 2 * page * hkv * hd * itemsize
+    npb = pages_per_block or max(1, BLOCK_BYTES // page_bytes)
+    npb = min(npb, max_pages)
+    for parts in range(1, rows + 1):
+        rb = rows // parts
+        if rows % parts or (parts > 1 and rb % 16):
+            continue
+        if vmem_bytes(rb, npb, page, hkv, hd, itemsize,
+                      max_pages) <= VMEM_BYTES:
+            return npb, rb
+    return npb, rows
 
 
 def supports(num_kv_heads: int, dtype) -> bool:
@@ -58,8 +109,7 @@ def supports(num_kv_heads: int, dtype) -> bool:
         num_kv_heads == 1 and size == 2)
 
 
-def _live_span(page_table, pos, page: int, window=None,
-               pages_per_block: int = PAGES_PER_BLOCK):
+def _live_span(page_table, pos, page: int, window, pages_per_block: int):
     """Per row (B, 2) int32: the first block to read and one past the last
     live page. ``page_table`` (B, max_pages) has inactive rows all null."""
     max_pages = page_table.shape[1]
@@ -121,20 +171,22 @@ def _kernel(layer_ref, table_ref, span_ref,                   # SMEM
         first, end = span_ref[2 * row], span_ref[2 * row + 1]
         return first, end, jnp.maximum(end - first * npb + npb - 1, 0) // npb
 
+    pools = ((k_hbm, kbuf, 0), (v_hbm, vbuf, 1))
+
+    def live(blk, end):         # pages of block ``blk`` below ``end``
+        return jnp.clip(end - blk * npb, 0, npb)
+
     def each_page(row, slot, blk, end, act):
         """``act`` on the K and V copies of each live page of a block."""
-        def page_copies(p, carry):
-            lp = blk * npb + p
-            phys = table_ref[row * max_pages + jnp.minimum(lp, max_pages - 1)]
+        at = row * max_pages + blk * npb
 
-            @pl.when(lp < end)
-            def _():
-                for hbm, buf, kv in ((k_hbm, kbuf, 0), (v_hbm, vbuf, 1)):
-                    act(pltpu.make_async_copy(hbm.at[layer, phys],
-                                              buf.at[slot, p],
-                                              sems.at[kv, slot]))
+        def page_copies(p, carry):
+            phys = table_ref[at + p]
+            for hbm, buf, kv in pools:
+                act(pltpu.make_async_copy(hbm.at[layer, phys],
+                                          buf.at[slot, p], sems.at[kv, slot]))
             return carry
-        jax.lax.fori_loop(0, npb, page_copies, 0)
+        jax.lax.fori_loop(0, live(blk, end), page_copies, 0)
 
     def start(row, slot, blk, end):
         each_page(row, slot, blk, end, lambda c: c.start())
@@ -218,7 +270,7 @@ def _kernel(layer_ref, table_ref, span_ref,                   # SMEM
 
 def paged_attention(q, k_pool, v_pool, page_pos, page_table, pos, layer=0,
                     softcap=None, window=None, interpret=True,
-                    pages_per_block: int = PAGES_PER_BLOCK):
+                    pages_per_block=None):
     """Attention of new queries over a paged KV pool, read in place.
 
     q: (B, T, H, hd) queries (RoPE applied); k_pool/v_pool:
@@ -227,6 +279,7 @@ def paged_attention(q, k_pool, v_pool, page_pos, page_table, pos, layer=0,
     (B, max_pages) int32, inactive rows all 0 (the null page); pos: (B, T)
     int32 query positions; layer: which of the L pools (traced is fine).
     The new tokens must already be scattered into the pools.
+    ``pages_per_block`` overrides the rule's block size (``tiling``).
 
     Returns (B, T, H, hd) float32. Oracle: ``ref.ref_paged_attention``.
     """
@@ -235,9 +288,9 @@ def paged_attention(q, k_pool, v_pool, page_pos, page_table, pos, layer=0,
     G = H // hkv
     R = T * G
     max_pages = page_table.shape[1]
-    npb = min(pages_per_block, max_pages)
+    npb, rb = tiling(page, hkv, hd, jnp.dtype(k_pool.dtype).itemsize, R,
+                     max_pages, pages_per_block)
     nb = pl.cdiv(max_pages, npb)
-    rb = Q_ROWS if R > Q_ROWS and R % Q_ROWS == 0 else R
     page_table = page_table.astype(jnp.int32)
     pos = pos.astype(jnp.int32)
     layer = jnp.asarray(layer, jnp.int32).reshape(1)

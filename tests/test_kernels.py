@@ -145,10 +145,14 @@ def _paged_rows(T, page=8, max_pages=6, P=20, L=2):
     row's slots up to its last query position are written, rows differ in
     length. Rows: last query at 7 (a page's last slot), at 8 (one slot into
     the next page), sharing row 0's first page then running to 20, inactive
-    (all-null table), and long (37) with stale writes past its queries."""
+    (all-null table), and long (37) with stale writes past its queries.
+    A chunk of more than 8 queries (prefill) starts rows 0 and 1 at
+    position 0 and 1, and the table and pool grow to hold it."""
     import numpy as np
     rng = np.random.default_rng(0)
-    lasts = [7, 8, 20 + T - 1, 5, 37 + T - 1]
+    lasts = [max(7, T - 1), max(8, T), 20 + T - 1, 5, 37 + T - 1]
+    max_pages = max(max_pages, (lasts[-1] + 4) // page + 1)
+    P = max(P, 3 * max_pages)
     pp = np.full((L, P, page), -1, np.int32)
     tab = np.zeros((len(lasts), max_pages), np.int32)
     nxt = 1
@@ -180,20 +184,35 @@ def _paged_rows(T, page=8, max_pages=6, P=20, L=2):
     (4, 2, None, 5.0, jnp.float32, 4, 2),
     (4, 8, None, None, jnp.bfloat16, 4, 1),
     (4, 8, 11, None, jnp.bfloat16, 2, 1),
+    (4, 8, None, None, jnp.bfloat16, None, 1),
+    (4, 8, 11, None, jnp.bfloat16, None, 1),
+    (64, 8, None, None, jnp.bfloat16, None, 1),
 ], ids=["t1_g2", "t4_g3", "t4_g2_bf16", "window", "softcap",
-        "t4_g8_bf16_one_head", "window_bf16_one_head"])
-def test_paged_attention_matches_gather(T, G, window, cap, dtype, npb, Hkv):
+        "t4_g8_bf16_one_head", "window_bf16_one_head",
+        "one_head_rule_tiling", "window_one_head_rule_tiling",
+        "prefill_one_head_rule_tiling"])
+def test_paged_attention_matches_gather(T, G, window, cap, dtype, npb, Hkv,
+                                        monkeypatch):
     """The in-place paged kernel equals the gather + masked-softmax oracle
     (``ref.ref_paged_attention``); an all-null row reads zeros, not NaN.
     Four pages a block leave the 6-page table's last block half past its
     end; two a block let the window skip the long row's first block. A
     single 16-bit KV head (a chip's share of a pool split by head) is read
-    without its head axis."""
+    without its head axis. Without ``npb`` the rule tiles the call, its
+    byte target cut to 4 of these 512-byte pages (as 512 KB is 64 of
+    yi9b-chat's 8-KB ones), so the long row spans two blocks and the
+    others one; a prefill chunk of 512 query rows, two grid steps before
+    the rule, then goes in one."""
     from repro.kernels import paged_attention as pk
     hd, L = 32, 2
     assert pk.supports(Hkv, dtype)
     page_pos, table, pos = _paged_rows(T)
     P, page = page_pos.shape[1:]
+    if npb is None:
+        size = jnp.dtype(dtype).itemsize
+        monkeypatch.setattr(pk, "BLOCK_BYTES", 4 * 2 * page * Hkv * hd * size)
+        rule = pk.tiling(page, Hkv, hd, size, T * G, table.shape[1])
+        assert rule == (4, T * G)
     kk = jax.random.split(KEY, 3)
     k = jax.random.normal(kk[0], (L, P, page, Hkv, hd)).astype(dtype)
     v = jax.random.normal(kk[1], (L, P, page, Hkv, hd)).astype(dtype)
@@ -206,3 +225,29 @@ def test_paged_attention_matches_gather(T, G, window, cap, dtype, npb, Hkv):
     assert float(jnp.max(jnp.abs(got[3]))) == 0.0          # inactive row
     tol = 2e-2 if dtype == jnp.bfloat16 else 1e-5
     assert jnp.allclose(got, want, atol=tol), float(jnp.max(jnp.abs(got - want)))
+
+
+@pytest.mark.parametrize("t,h,hkv,want", [
+    (4, 24, 8, (8, 12)), (1, 8, 4, (16, 2)), (256, 24, 8, None),
+    (4, 8, 1, (64, 32)), (1, 2, 1, (64, 2)), (256, 8, 1, None)],
+    ids=["phi4_verify", "phi4_drafter_feed", "phi4_prefill",
+         "one_head_target_verify", "one_head_drafter_feed",
+         "one_head_target_prefill"])
+def test_paged_tiling_rule(t, h, hkv, want):
+    """The tiling rule at the cells' shapes (page 16, hd 128, bf16, 257-page
+    tables): phi4-chat's verify keeps the 8 pages and 12 rows it was tuned
+    at; a block moves ``BLOCK_BYTES`` of K and V; rows a step divide the
+    rows evenly; and a step's tiles, landing buffers and scores stay under
+    the VMEM budget kernel_lint holds every kernel to."""
+    from repro.analysis.kernel_lint import VMEM_BUDGET_BYTES
+    from repro.kernels import paged_attention as pk
+    page, hd, size, max_pages = 16, 128, 2, 257
+    rows = t * (h // hkv)
+    npb, rb = pk.tiling(page, hkv, hd, size, rows, max_pages)
+    if want is not None:
+        assert (npb, rb) == want
+    assert npb * 2 * page * hkv * hd * size == pk.BLOCK_BYTES
+    assert rows % rb == 0 and (rb == rows or rb % 16 == 0)
+    assert pk.vmem_bytes(rb, npb, page, hkv, hd, size, max_pages) \
+        <= pk.VMEM_BYTES < VMEM_BUDGET_BYTES
+    assert pk.tiling(page, hkv, hd, size, rows, max_pages, 8)[0] == 8
