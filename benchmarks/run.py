@@ -2,7 +2,6 @@
 the roofline/dry-run and kernel suites. Prints ``name,value,derived`` CSV.
 
   PYTHONPATH=src python -m benchmarks.run [--quick] [--skip-repro] [--smoke]
-      [--json [DIR]] [--compare] [--compare-tol T]
 
 --quick shrinks the repro pipeline (CI-scale); without a cached
 experiments/repro_results.json the full pipeline (~10 min CPU) runs once and
@@ -13,14 +12,9 @@ shapes. In every mode a section error is reported as a CSV row, the
 remaining sections still run, and the process exits 1 at the end — so a
 benchmark whose imports or registrations rot cannot pass silently.
 
---json [DIR] persists each section's numeric rows as one run record in
-DIR/BENCH_<section>.json (bounded trajectory, default DIR "."); --compare
-then gates the fresh run against the previous same-config record and exits
-nonzero when a direction-aware metric regressed by more than --compare-tol
-(relative, default 0.25). See benchmarks.bench_persist.
-
 Every section also emits a ``<section>_section_wall_s`` row — harness wall
-time, informational only (never gates a compare).
+time on the CPU, informational only: speed is recorded from chip runs of
+``bench/``, not here.
 """
 from __future__ import annotations
 
@@ -34,19 +28,7 @@ def main() -> None:
     ap.add_argument("--quick", action="store_true")
     ap.add_argument("--skip-repro", action="store_true")
     ap.add_argument("--smoke", action="store_true")
-    ap.add_argument("--json", nargs="?", const=".", default=None,
-                    metavar="DIR", dest="json_dir",
-                    help="persist per-section BENCH_<section>.json run "
-                         "records into DIR (default '.')")
-    ap.add_argument("--compare", action="store_true",
-                    help="with --json: compare against the previous "
-                         "same-config run; exit nonzero on regression")
-    ap.add_argument("--compare-tol", type=float, default=0.25,
-                    help="relative regression tolerance for --compare")
     args = ap.parse_args()
-    if args.compare and args.json_dir is None:
-        ap.error("--compare requires --json (needs a trajectory to compare "
-                 "against)")
     from repro.launch.compile_cache import enable_compile_cache
     enable_compile_cache()
     smoke = args.smoke
@@ -80,8 +62,7 @@ def main() -> None:
         ("quality", lambda: quality_bench.rows(quick=quick)),
     ]
 
-    run_config = {"quick": quick, "smoke": smoke}
-    failed, regressions = [], []
+    failed = []
     print("name,value,derived")
     for name, fn in sections:
         t0 = time.perf_counter()
@@ -95,25 +76,9 @@ def main() -> None:
         rows.append((f"{name}_section_wall_s", round(wall_s, 3), ""))
         for row in rows:
             print(",".join(str(x) for x in row))
-        if args.json_dir is not None:
-            from .bench_persist import (append_run, compare_run,
-                                        load_history, record)
-            rec = record(name, rows, wall_s, config=run_config)
-            if args.compare:
-                history = load_history(args.json_dir, name)
-                for metric, prev, cur, bad in compare_run(
-                        history, rec, args.compare_tol):
-                    print(f"REGRESSION,{bad:.3f},{name}.{metric} "
-                          f"{prev:.6g} -> {cur:.6g}")
-                    regressions.append((name, metric))
-            append_run(args.json_dir, rec)
     if failed:
         print(f"SECTIONS_FAILED,{len(failed)},{';'.join(failed)}")
         sys.exit(1)
-    if regressions:
-        print(f"COMPARE_FAILED,{len(regressions)},"
-              + ";".join(f"{s}.{m}" for s, m in regressions))
-        sys.exit(2)
 
 
 if __name__ == "__main__":

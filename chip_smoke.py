@@ -83,8 +83,8 @@ def check_device():
 def serve_pass(args, served):
     """Serve the requests once through a fresh engine. -> (results by id,
     per-request stats, wall seconds, compile seconds)."""
-    engine, reqs, _ = serve.build_continuous(args, served,
-                                             np.random.default_rng(0))
+    engine, reqs = serve.build_continuous(args, served,
+                                          np.random.default_rng(0))
     with CompileWatcher() as clock:
         t0 = time.perf_counter()
         for r in reqs:

@@ -30,7 +30,7 @@ state); ``run_jaxpr_audit()`` applies every rule and returns a
 """
 from __future__ import annotations
 
-from dataclasses import dataclass, field
+from dataclasses import dataclass
 from functools import partial
 from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
@@ -67,8 +67,6 @@ class AuditSubject:
     fn: Callable
     args: Tuple
     state_argnum: int = 2
-    # which rules apply; engine-shaped subjects skip donation when phased
-    check_donation: bool = True
     # JX004 compares dtypes only within a group: the int8-KV variant is
     # *meant* to store different cache dtypes than the fp variants
     dtype_group: str = "fp"
@@ -181,8 +179,6 @@ def audit_donation(subj: AuditSubject) -> List[Finding]:
     leaves means some buffer is silently double-allocated — the KV pool
     (the big one) would exist twice.
     """
-    if not subj.check_donation:
-        return []
     lowered = jax.jit(subj.fn,
                       donate_argnums=(subj.state_argnum,)).lower(*subj.args)
     n_alias = lowered.as_text().count("tf.aliasing_output")
@@ -278,8 +274,7 @@ def build_audit_subjects(include_engine: bool = True) -> List[AuditSubject]:
     engine-shaped subject reuses a real (tiny) ``ContinuousEngine`` state to
     get the paged page-table layout exactly as production builds it.
     """
-    from ..core.speculative import (SDConfig, _prefill_state, sd_round,
-                                    tree_sd_round)
+    from ..core.speculative import SDConfig, _prefill_state, bind_round
     from ..spectree.tree import TreeSpec
 
     t, d = _tiny_models()
@@ -296,12 +291,12 @@ def build_audit_subjects(include_engine: bool = True) -> List[AuditSubject]:
 
     chain = SDConfig(gamma=2, temperature=0.0)
     subjects.append(AuditSubject(
-        name="chain_round", fn=partial(sd_round, d, t, chain),
+        name="chain_round", fn=bind_round(d, t, chain),
         args=(dp, tp, state_for(chain), key)))
 
     quant = SDConfig(gamma=2, temperature=0.0, kv_quant=True)
     subjects.append(AuditSubject(
-        name="chain_round_kv_quant", fn=partial(sd_round, d, t, quant),
+        name="chain_round_kv_quant", fn=bind_round(d, t, quant),
         args=(dp, tp, state_for(quant), key), dtype_group="kv_int8"))
 
     qual = SDConfig(gamma=2, temperature=0.0, quality=True)
@@ -309,12 +304,12 @@ def build_audit_subjects(include_engine: bool = True) -> List[AuditSubject]:
     st_q = dict(state_for(qual))
     st_q["qual"] = jax.eval_shape(partial(init_quality_buffer, 2, qual.gamma))
     subjects.append(AuditSubject(
-        name="chain_round_quality", fn=partial(sd_round, d, t, qual),
+        name="chain_round_quality", fn=bind_round(d, t, qual),
         args=(dp, tp, st_q, key)))
 
     tree = TreeSpec((2, 1))
     subjects.append(AuditSubject(
-        name="tree_round", fn=partial(tree_sd_round, d, t, chain, tree),
+        name="tree_round", fn=bind_round(d, t, chain, tree),
         args=(dp, tp, state_for(chain, max_total=40), key)))
 
     if include_engine:
@@ -327,7 +322,7 @@ def build_engine_subjects() -> List[AuditSubject]:
     continuous engine actually feeds it (active mask + page table + pooled
     caches), chain and tree. Built from a real tiny engine so the state
     layout can never drift from production."""
-    from ..core.speculative import SDConfig, sd_round, tree_sd_round
+    from ..core.speculative import SDConfig, bind_round
     from ..serving.continuous import ContinuousEngine
     from ..spectree.tree import TreeSpec
 
@@ -342,8 +337,7 @@ def build_engine_subjects() -> List[AuditSubject]:
         eng = ContinuousEngine(target=t, target_params=tp, draft=d,
                                draft_params=dp, sd=sdc, tree=tree,
                                max_batch=2, max_seq_len=48, page_size=8)
-        fn = (partial(sd_round, d, t, eng.sd) if tree is None
-              else partial(tree_sd_round, d, t, eng.sd, tree))
+        fn = bind_round(d, t, eng.sd, tree)
         args = (dp, tp,
                 jax.tree_util.tree_map(
                     lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype),

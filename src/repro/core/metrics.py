@@ -139,9 +139,8 @@ def latency_percentiles(values_s, qs=(50, 99)) -> Dict[str, float]:
     stalls, bursty arrivals) the serving stack exists to bound.
 
     Empty input returns NaN, not 0.0 — a run that completed zero requests
-    has no latency, and a fake 0 ms p99 both reads as an impossibly good
-    result and poisons benchmark trajectory comparison (bench_persist skips
-    NaN-valued metrics instead of flagging a regression against 0).
+    has no latency, and a fake 0 ms p99 reads as an impossibly good
+    result.
 
     Accepts either an iterable of latencies or a streaming quantile sketch
     (anything with a ``query(phi)`` method, e.g. ``repro.obs.sketch.GKSketch``)

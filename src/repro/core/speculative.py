@@ -29,7 +29,7 @@ from __future__ import annotations
 import time
 from dataclasses import dataclass
 from functools import lru_cache, partial
-from typing import Any, Optional, Tuple
+from typing import Tuple
 
 import jax
 import jax.numpy as jnp
@@ -213,8 +213,8 @@ def sd_draft_phase(draft, target: Model, sdc: SDConfig,
                             only, for the rewind-by-selection), else None
 
     Each phase re-derives the same ``jax.random.split(key, gamma + 2)`` from
-    the round key and consumes its fixed slice, so the phased decomposition
-    is bit-identical to the fused ``sd_round``.
+    the round key and consumes its fixed slice (``bench/lib/replay`` rebuilds
+    the round's draws from that fan-out).
     """
     from ..draftheads.drafter import head_draft_chain, is_head_drafter
     head = is_head_drafter(draft)
@@ -428,10 +428,9 @@ def sd_round(draft, target: Model, sdc: SDConfig,
                              requires attention-only draft AND target.
 
     The round is the composition of three phase functions (draft / verify /
-    commit), jitted as ONE computation here; the serving engine's opt-in
-    ``time_phases`` path jits the same three functions separately with
-    ``block_until_ready`` fences between them (repro.obs.phases) — identical
-    math, observable seams.
+    commit), jitted as ONE computation; each phase runs under its own
+    ``jax.named_scope``, so a device trace splits the round's time by phase
+    with no fence between them.
     """
     draft_out = sd_draft_phase(draft, target, sdc, d_params, t_params,
                                state, key)
@@ -454,22 +453,23 @@ def tree_sd_round(draft: Model, target: Model, sdc: SDConfig, tree,
 
 # ----------------------------------------------------------------- drivers
 
-@lru_cache(maxsize=64)
-def _cached_round(draft: Model, target: Model, sdc: SDConfig):
-    """One jitted round per (draft cfg, target cfg, sd cfg) — evaluation
-    sweeps (checkpoints x losses x tasks) reuse the compiled round."""
-    return jax.jit(partial(sd_round, draft, target, sdc))
+def bind_round(draft, target: Model, sdc: SDConfig, tree=None):
+    """The round as a ``functools.partial`` of its static arguments: the
+    chain ``sd_round`` when ``tree`` is None, else ``tree_sd_round`` over
+    that ``spectree.TreeSpec``. Jitted, a partial keeps the program name
+    every caller's round shares (the decode steps are partials too)."""
+    if tree is None:
+        return partial(sd_round, draft, target, sdc)
+    return partial(tree_sd_round, draft, target, sdc, tree)
 
 
 @lru_cache(maxsize=64)
-def _cached_tree_round(draft: Model, target: Model, sdc: SDConfig, tree):
-    """Jitted tree round per (draft, target, sd cfg, tree shape)."""
-    return jax.jit(partial(tree_sd_round, draft, target, sdc, tree))
-
-
-@lru_cache(maxsize=64)
-def _cached_round_donated(draft: Model, target: Model, sdc: SDConfig):
-    """``_cached_round`` with the ``state`` argument donated to XLA.
+def _cached_round_donated(draft: Model, target: Model, sdc: SDConfig,
+                          tree=None):
+    """One jitted round per (drafter, target, sd cfg, tree shape or None),
+    with the ``state`` argument donated to XLA. Call it with ``tree``
+    positional everywhere, so the same shapes find one cache entry and one
+    compiled program.
 
     Every state leaf (token buffer, KV caches / paged pools) is aliased
     input->output instead of double-buffered, so the round's cache commit
@@ -481,63 +481,23 @@ def _cached_round_donated(draft: Model, target: Model, sdc: SDConfig):
 
     Callers MUST NOT touch the input state after the call: the generate
     drivers rebind their loop variable, the continuous engine replaces
-    ``self._state``. Anything that re-reads a round's input state (the
-    phased-equivalence tests, fixture reuse) belongs on ``_cached_round``.
+    ``self._state``. Anything that re-reads a round's input state jits
+    ``bind_round`` itself.
     """
-    return jax.jit(partial(sd_round, draft, target, sdc), donate_argnums=(2,))
+    return jax.jit(bind_round(draft, target, sdc, tree), donate_argnums=(2,))
 
 
 @lru_cache(maxsize=64)
-def _cached_tree_round_donated(draft: Model, target: Model, sdc: SDConfig,
-                               tree):
-    """Tree-round analogue of ``_cached_round_donated`` (state donated)."""
-    return jax.jit(partial(tree_sd_round, draft, target, sdc, tree),
-                   donate_argnums=(2,))
-
-
-@lru_cache(maxsize=64)
-def _cached_phased_round(draft, target: Model, sdc: SDConfig):
-    """The chain round as three separately-jitted phase functions, for the
-    engine's opt-in phase-time attribution (``time_phases``): fencing between
-    them yields a draft/verify/commit wall-time split. Same math as the fused
-    round — each phase re-splits the round key identically."""
-    return {
-        "draft": jax.jit(partial(sd_draft_phase, draft, target, sdc)),
-        "verify": jax.jit(partial(sd_verify_phase, draft, target, sdc)),
-        "commit": jax.jit(partial(sd_commit_phase, draft, target, sdc)),
-    }
-
-
-@lru_cache(maxsize=64)
-def _cached_phased_tree_round(draft, target: Model, sdc: SDConfig, tree):
-    """Tree-round analogue of ``_cached_phased_round`` (spectree.round)."""
-    from ..spectree.round import (tree_commit_phase, tree_draft_phase,
-                                  tree_verify_phase)
-    return {
-        "draft": jax.jit(partial(tree_draft_phase, draft, target, sdc, tree)),
-        "verify": jax.jit(partial(tree_verify_phase, draft, target, sdc,
-                                  tree)),
-        "commit": jax.jit(partial(tree_commit_phase, draft, target, sdc,
-                                  tree)),
-    }
-
-
-@lru_cache(maxsize=64)
-def _cached_decode(model: Model, long_context: bool):
+def _cached_decode(model: Model, long_context: bool,
+                   return_hidden: bool = False):
     """Decode step with the cache (argument 3) donated: every caller rebinds
     its cache to the result. Undonated, each call allocates a second cache
     while the host runs ahead of the device — on a chip, chunked prefill of
-    one prompt then holds several full paged pools at once."""
-    return jax.jit(partial(model.decode_step, long_context=long_context),
-                   donate_argnums=(3,))
-
-
-@lru_cache(maxsize=64)
-def _cached_decode_hidden(model: Model, long_context: bool):
-    """Hidden-returning decode step (draft-head prefill needs the feature);
-    cache donated as in ``_cached_decode``."""
+    one prompt then holds several full paged pools at once. With
+    ``return_hidden`` it also returns the final hidden states (draft-head
+    prefill needs the feature)."""
     return jax.jit(partial(model.decode_step, long_context=long_context,
-                           return_hidden=True), donate_argnums=(3,))
+                           return_hidden=return_hidden), donate_argnums=(3,))
 
 
 def _prefill_state(draft, target, d_params, t_params, prompt, max_total,
@@ -595,7 +555,7 @@ def speculative_generate(draft, target: Model, d_params, t_params,
 
     # Donated round: the loop rebinds ``state`` every iteration and never
     # re-reads the previous one, so XLA can commit caches in place.
-    round_fn = _cached_round_donated(draft, target, sdc)
+    round_fn = _cached_round_donated(draft, target, sdc, None)
     stats = SDStats()
     target_len = S + max_new_tokens
     # Host mirror of per-row lengths: known exactly after prefill, then
@@ -625,7 +585,7 @@ def autoregressive_generate(model: Model, params, prompt, max_new_tokens: int,
     max_total = S + max_new_tokens + 1
     lg, cache = model.prefill(params, prompt, cache_len=max_total,
                               long_context=long_context)
-    step = _cached_decode(model, long_context)
+    step = _cached_decode(model, long_context, False)
     toks = [prompt]
     key, k = jax.random.split(key)
     cur = sample_from_probs(k, probs_from_logits(lg[:, 0], temperature, top_p))

@@ -52,17 +52,15 @@ Observability (repro.obs, all opt-in): ``--trace-out trace.json`` writes a
 Chrome/Perfetto trace of the run (per-request lifecycle tracks + engine
 spans — load at https://ui.perfetto.dev), ``--metrics-out m.jsonl`` appends
 periodic metrics-registry snapshots (final Prometheus exposition to
-``m.jsonl.prom``), ``--time-phases`` swaps the fused round for fenced
-per-phase jits and prints the draft/verify/commit/host wall-time split plus
-a roofline-vs-measured report (``--peak-gbps`` turns achieved GB/s into an
-MBU estimate), and ``--jax-profile DIR`` captures a jax.profiler device
-trace of the serve loop, whose ops carry the named scopes draft / verify /
-commit, attention / kv_write / kv_gather / sdpa, mlp, lm_head and sampling
-(with ``--trace-out`` too, the engine's spans land on its host plane):
+``m.jsonl.prom``), and ``--jax-profile DIR`` captures a jax.profiler
+device trace of the serve loop, whose ops carry the named scopes draft /
+verify / commit, attention / kv_write / kv_gather / sdpa, mlp, lm_head and
+sampling — the per-phase split of a round (with ``--trace-out`` too, the
+engine's spans land on its host plane):
 
   PYTHONPATH=src python -m repro.launch.serve --arch yi-9b --reduced \
-      --continuous --requests 8 --trace-out trace.json --time-phases \
-      --metrics-out metrics.jsonl
+      --continuous --requests 8 --trace-out trace.json \
+      --metrics-out metrics.jsonl --jax-profile prof
 
 Speculation-quality telemetry (repro.obs.quality): ``--quality-telemetry``
 makes the jitted round leave per-depth TVD/entropy/accept buffers in the
@@ -96,8 +94,7 @@ from ..core.speculative import SDConfig
 from ..draftheads import HeadConfig, HeadDrafter
 from ..models.model import Model
 from ..obs import (MetricsRegistry, SLOConfig, Tracer, acceptance_report,
-                   attribution_report, format_acceptance_report,
-                   format_attribution, jax_profile)
+                   format_acceptance_report, jax_profile)
 from ..quant import quantize_params
 from ..serving import ContinuousEngine, Request, ServeRequest, ServingEngine
 from ..spectree import TreeSpec, tree_speculative_generate
@@ -200,7 +197,7 @@ def prompt_lengths(args, rng) -> np.ndarray:
 
 def build_continuous(args, served: Served, rng, tracer=None, registry=None):
     """The continuous engine for ``args`` and its requests, not yet
-    submitted. Returns (engine, requests, max_seq)."""
+    submitted. Returns (engine, requests)."""
     cfg, draft = served.cfg, served.draft
     if args.traffic_mix is not None:
         from ..traffic import make_mix
@@ -233,13 +230,13 @@ def build_continuous(args, served: Served, rng, tracer=None, registry=None):
         policy=args.policy, aging_s=args.aging_s,
         kv_quant=args.quant_kv, prefix_cache=args.prefix_cache,
         tracer=tracer, registry=registry,
-        time_phases=args.time_phases, metrics_out=args.metrics_out,
+        metrics_out=args.metrics_out,
         quality=args.quality_telemetry,
         flight_record=args.flight_record is not None,
         flight_dir=args.flight_record or "flight",
         slo=(SLOConfig(ttft_ms=args.slo_ttft_ms, tpot_ms=args.slo_tpot_ms)
              if args.slo_ttft_ms is not None else None))
-    return engine, serve_reqs, max_seq
+    return engine, serve_reqs
 
 
 def tree_spec(args) -> Optional[TreeSpec]:
@@ -307,14 +304,6 @@ def parse_args(argv=None):
     ap.add_argument("--metrics-out", default=None, metavar="PATH",
                     help="append periodic metrics-registry JSONL snapshots; "
                          "final Prometheus exposition goes to PATH.prom")
-    ap.add_argument("--time-phases", action="store_true",
-                    help="fenced per-phase round jits: print the draft/"
-                         "verify/commit/host wall-time split and the "
-                         "roofline-vs-measured report (perturbs async "
-                         "dispatch; measurement mode, not serving mode)")
-    ap.add_argument("--peak-gbps", type=float, default=None,
-                    help="peak HBM bandwidth for the achieved-MBU estimate "
-                         "in the --time-phases report")
     ap.add_argument("--jax-profile", default=None, metavar="DIR",
                     help="capture a jax.profiler device trace of the serve "
                          "loop into DIR (TensorBoard/Perfetto viewable)")
@@ -340,7 +329,6 @@ def parse_args(argv=None):
         ap.error("--traffic-mix requires --continuous")
     for flag, val in (("--trace-out", args.trace_out),
                       ("--metrics-out", args.metrics_out),
-                      ("--time-phases", args.time_phases),
                       ("--jax-profile", args.jax_profile),
                       ("--quality-telemetry", args.quality_telemetry),
                       ("--flight-record", args.flight_record),
@@ -396,13 +384,11 @@ def main(argv=None):
     if args.continuous:
         if args.no_draft:
             raise SystemExit("--continuous is speculative-only")
-        head = isinstance(draft, HeadDrafter)
         # with a device trace too, the spans also land on its host plane
         tracer = (Tracer(annotate=bool(args.jax_profile))
                   if args.trace_out else None)
-        registry = (MetricsRegistry()
-                    if args.metrics_out or args.time_phases else None)
-        engine, serve_reqs, max_seq = build_continuous(
+        registry = MetricsRegistry() if args.metrics_out else None
+        engine, serve_reqs = build_continuous(
             args, served, rng, tracer=tracer, registry=registry)
         for r in serve_reqs:
             engine.submit(r)
@@ -465,17 +451,6 @@ def main(argv=None):
             print(f"  flight recorder: {rc.rounds_seen} rounds ringed "
                   f"(cap {rc.capacity}), {len(rc.triggers)} triggers, "
                   f"{len(rc.dumped_paths)} bundles in {rc.out_dir}/")
-        if args.time_phases:
-            print(f"  {engine.phases.summary()}")
-            drafter_cfg = draft.hc if head else draft.cfg
-            rep = attribution_report(
-                engine.phases, cfg, drafter_cfg,
-                batch=max(int(round(tel.mean_active_rows)), 1),
-                ctx=max_seq // 2, gamma=seq_draft_steps,
-                weights=args.quant_weights or cfg.param_dtype,
-                kv="int8" if args.quant_kv else cfg.dtype,
-                peak_gbps=args.peak_gbps)
-            print("  " + format_attribution(rep).replace("\n", "\n  "))
         if registry is not None:
             pooled.emit(registry, prefix="sd_pooled")
         if args.metrics_out:

@@ -4,9 +4,9 @@ A serve run that fails (drafter drift alarm, SLO breach, crash) is only
 debuggable if the moments *leading up to* the failure were retained — but a
 production loop cannot afford to log every round forever. The recorder
 keeps a bounded ring of the most recent per-round records (accept masks,
-TVD summaries, scheduler/pool occupancy, phase times when enabled) at O(1)
-memory, and ``dump()`` writes the whole ring plus caller-supplied context
-snapshots as one self-contained JSON bundle when something trips.
+TVD summaries, scheduler/pool occupancy) at O(1) memory, and ``dump()``
+writes the whole ring plus caller-supplied context snapshots as one
+self-contained JSON bundle when something trips.
 
 Dump triggers are the caller's (the continuous engine dumps on drift alarm,
 SLO breach, and crash); ``max_dumps`` bounds disk usage when an alarm

@@ -26,12 +26,16 @@ On the profiler clock: ``Tracer(annotate=True)`` also enters a
 ``jax.profiler.TraceAnnotation`` of the span's name for every span, so that
 a ``jax.profiler`` capture holds the spans on its host plane beside the
 device ops (``launch/serve.py --trace-out --jax-profile``).
+``jax_profile(dir)`` takes that capture around a region; its device ops
+carry the round's named scopes (draft / verify / commit, ...), which is
+where the per-phase split of a round is read.
 """
 from __future__ import annotations
 
 import json
 import threading
 import time
+from contextlib import contextmanager
 from typing import Optional
 
 import jax
@@ -166,3 +170,24 @@ class Tracer:
 
 
 NULL_TRACER = Tracer(enabled=False)
+
+
+@contextmanager
+def jax_profile(trace_dir: Optional[str]):
+    """Capture a ``jax.profiler`` device trace for the wrapped region.
+
+    No-op when ``trace_dir`` is falsy, and degrades to a warning if the
+    profiler backend is unavailable (e.g. sandboxed CPU CI)."""
+    if not trace_dir:
+        yield
+        return
+    try:
+        jax.profiler.start_trace(trace_dir)
+    except Exception as e:                       # pragma: no cover
+        print(f"warning: jax.profiler unavailable ({e}); continuing untraced")
+        yield
+        return
+    try:
+        yield
+    finally:
+        jax.profiler.stop_trace()

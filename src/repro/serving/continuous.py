@@ -53,17 +53,13 @@ import numpy as np
 from ..core.metrics import RequestStats, ServingTelemetry
 from ..core.sampling import probs_from_logits, sample_from_probs
 from ..core.speculative import (SDConfig, _cached_decode,
-                                _cached_decode_hidden, _cached_phased_round,
-                                _cached_phased_tree_round,
-                                _cached_round_donated,
-                                _cached_tree_round_donated, attention_only,
-                                init_quality_buffer, sd_round,
-                                trim_paged_cache, tree_sd_round)
+                                _cached_round_donated, attention_only,
+                                bind_round, init_quality_buffer,
+                                trim_paged_cache)
 from ..draftheads import HeadDrafter
 from ..kernels.tree_attention import kv_window
 from ..models.model import Model
-from ..obs import (NULL_TRACER, FlightRecorder, PhaseTimer, QualityStats,
-                   SLOTracker)
+from ..obs import NULL_TRACER, FlightRecorder, QualityStats, SLOTracker
 from ..sharding import context as shctx
 from ..spectree.tree import TreeSpec
 from .engine import Request, Result
@@ -151,7 +147,7 @@ def _jit_keeping(step, at, sharding, donate):
 
 
 @lru_cache(maxsize=8)
-def _cached_mesh_programs(mesh, drafter, sd, tree, *shape):
+def _cached_mesh_programs(mesh, drafter, sd, tree, return_hidden, *shape):
     """Across chips, the engine's layout over ``mesh`` and the programs
     that keep it: (jitted ``_init_state(*shape)`` that makes every leaf
     straight into its sharding, those shardings, the donated round, the
@@ -161,16 +157,15 @@ def _cached_mesh_programs(mesh, drafter, sd, tree, *shape):
     target, draft = shape[:2]
     make = partial(_init_state, *shape)
     layout = state_shardings(jax.eval_shape(make), mesh)
-    round_fn = (partial(sd_round, drafter, target, sd) if tree is None
-                else partial(tree_sd_round, drafter, target, sd, tree))
     lc = sd.long_context
     d_step = (None if draft is None else _jit_keeping(
         partial(draft.decode_step, long_context=lc), 1, layout["d_cache"], 3))
     t_step = _jit_keeping(partial(target.decode_step, long_context=lc,
-                                  return_hidden=draft is None),
+                                  return_hidden=return_hidden),
                           1, layout["t_cache"], 3)
     return (jax.jit(make, out_shardings=layout), layout,
-            _jit_keeping(round_fn, 0, layout, 2), d_step, t_step)
+            _jit_keeping(bind_round(drafter, target, sd, tree), 0, layout, 2),
+            d_step, t_step)
 
 
 @dataclass
@@ -214,16 +209,10 @@ class ContinuousEngine:
     # tracer — span tracer; per-request lifecycle tracks + engine-thread
     #   spans, exported as Chrome/Perfetto trace-event JSON (tracer.write).
     # registry — metrics registry; telemetry dataclasses emit into it live.
-    # time_phases — swap the fused jitted round for three separately-jitted
-    #   phases with block_until_ready fences between them, filling
-    #   ``self.phases`` with a draft/verify/commit/prefill wall-time split.
-    #   The fences serialize dispatch (the perturbation DESIGN.md documents),
-    #   which is why this is not free and not the default.
     # metrics_out — JSONL path; a registry snapshot is appended every
     #   ``metrics_every`` steps and once at drain.
     tracer: Optional[object] = None
     registry: Optional[object] = None
-    time_phases: bool = False
     metrics_out: Optional[str] = None
     metrics_every: int = 50
     # quality — speculation-quality telemetry (repro.obs.quality): the jitted
@@ -233,8 +222,8 @@ class ContinuousEngine:
     #   tenant, and engine-wide; the engine pool runs the Page–Hinkley
     #   drafter-drift detector.
     # flight_record — bounded ring of per-round records (accept masks, TVD,
-    #   pool/queue snapshot, phase times when time_phases is on), dumped as a
-    #   post-mortem JSON bundle on drift alarm, SLO breach, or engine crash.
+    #   pool/queue snapshot), dumped as a post-mortem JSON bundle on drift
+    #   alarm, SLO breach, or engine crash.
     # slo — obs.sketch.SLOConfig; TTFT/TPOT observed per retired request into
     #   multi-window burn-rate trackers + O(1)-memory quantile sketches.
     quality: bool = False
@@ -305,7 +294,6 @@ class ContinuousEngine:
         self._tr = self.tracer if self.tracer is not None else NULL_TRACER
         if self._tr.enabled:
             _trace_gc(self)
-        self.phases = PhaseTimer()
         if self.registry is not None:
             # accepted-draft-tokens-per-round histogram: the live acceptance
             # signal the adaptive-speculation controller will consume
@@ -325,6 +313,8 @@ class ContinuousEngine:
                  self.page_size, self.kv_quant,
                  self._qdepth if self.quality else None)
         drafter = self.draft_heads if self.draft_heads is not None else self.draft
+        # draft heads prefill only the target, whose hidden states seed them
+        hidden = self.draft_heads is not None
         # across chips (the mesh the launch code installed, tensor parallel
         # over its model axis) every leaf is made straight into its layout:
         # the pools split by KV head, all else replicated. The donated round
@@ -335,7 +325,7 @@ class ContinuousEngine:
         self._layout = None
         if mesh is not None and mesh.size > 1:
             make, self._layout, *steps = _cached_mesh_programs(
-                mesh, drafter, self.sd, self.tree, *shape)
+                mesh, drafter, self.sd, self.tree, hidden, *shape)
             self._state = make()
         else:
             self._state = _init_state(*shape)
@@ -351,39 +341,27 @@ class ContinuousEngine:
         self._slots = [_Slot() for _ in range(B)]
         self._lengths_h = np.zeros((B,), np.int64)
         self._table_h = np.zeros((B, max_pages), np.int32)
-        # fused round with the state donated: the engine rebinds self._state
-        # every round and reads only the round's *output* leaves afterwards,
-        # so XLA aliases every state buffer input->output (cache commits are
-        # in-place; one copy of the pool, not two). The phased path below
-        # cannot donate — draft and verify both consume the same state.
-        self._round = (
-            _cached_tree_round_donated(drafter, self.target, self.sd,
-                                       self.tree)
-            if self.tree is not None
-            else _cached_round_donated(drafter, self.target, self.sd))
         # device-side committed-window gather: indexing tokens with host np
         # index arrays would be an implicit h2d transfer per round (and a
         # transfer_guard violation); this keeps the gather on device so the
         # round's ONLY host sync is the single fetch device_get.
         self._win_fn = _cached_window_gather(self._span)
-        # phase-time attribution path: the SAME round math split into three
-        # separately-jitted phase fns so host-side fences can see the seams
-        self._phased = None
-        if self.time_phases:
-            self._phased = (
-                _cached_phased_tree_round(drafter, self.target, self.sd,
-                                          self.tree)
-                if self.tree is not None
-                else _cached_phased_round(drafter, self.target, self.sd))
-        self._d_step = (None if self.draft_heads is not None
-                        else _cached_decode(self.draft, self.sd.long_context))
-        self._t_step = (_cached_decode_hidden(self.target, self.sd.long_context)
-                        if self.draft_heads is not None
-                        else _cached_decode(self.target, self.sd.long_context))
         self._key = jax.random.PRNGKey(0)
         if self._layout is not None:
             self._round, self._d_step, self._t_step = steps
             self._key = jax.device_put(self._key, self._layout["lengths"])
+        else:
+            # fused round with the state donated: the engine rebinds
+            # self._state every round and reads only the round's *output*
+            # leaves afterwards, so XLA aliases every state buffer
+            # input->output (cache commits are in-place; one copy of the
+            # pool, not two)
+            self._round = _cached_round_donated(drafter, self.target,
+                                                self.sd, self.tree)
+            lc = self.sd.long_context
+            self._d_step = (None if hidden
+                            else _cached_decode(self.draft, lc, False))
+            self._t_step = _cached_decode(self.target, lc, hidden)
         self._admit_seq = 0
         self._t0: Optional[float] = None
         self._last_sanitize = 0
@@ -607,7 +585,6 @@ class ContinuousEngine:
             return self._step()
 
     def _step(self) -> List[tuple]:
-        t_step = time.perf_counter()
         now = self._now()
         events: List[tuple] = []
         did_work = False
@@ -624,12 +601,7 @@ class ContinuousEngine:
         if prefilling:
             i = min(prefilling, key=lambda j: self._slots[j].admit_seq)
             with self._tr.span("prefill_chunk", slot=i):
-                if self.time_phases:
-                    with self.phases.phase("prefill"):
-                        first_tok = self._prefill_one_chunk(i)
-                        jax.block_until_ready(self._state["t_cache"])
-                else:
-                    first_tok = self._prefill_one_chunk(i)
+                first_tok = self._prefill_one_chunk(i)
             if first_tok is not None:
                 events.extend(self._emit(i, np.asarray([first_tok], np.int64)))
             did_work = True
@@ -656,8 +628,6 @@ class ContinuousEngine:
                 self._tr.counter("queue_depth", qd)
                 self._tr.counter("active_rows", act)
                 self._tr.counter("free_pages", self.pool.num_free)
-            if self.time_phases:
-                self.phases.add_step(time.perf_counter() - t_step)
             if self.registry is not None:
                 if self.prefix is not None:
                     self.prefix.tel.emit(self.registry)
@@ -669,24 +639,6 @@ class ContinuousEngine:
                         self.telemetry.steps % self.metrics_every == 0:
                     self.registry.write_snapshot(self.metrics_out)
         return events
-
-    def _run_round_phased(self, st, kr):
-        """The same round as ``self._round``, as three separately-jitted
-        phases with ``block_until_ready`` fences between them. Each fence
-        forces the device work of its phase to finish before the clock is
-        read — draft/verify/commit wall time becomes attributable, at the
-        cost of serializing dispatch (why ``time_phases`` is opt-in)."""
-        ph, tr, timer = self._phased, self._tr, self.phases
-        with tr.span("draft"), timer.phase("draft"):
-            draft_out = ph["draft"](self._d_params, self.target_params, st, kr)
-            jax.block_until_ready(draft_out)
-        with tr.span("verify"), timer.phase("verify"):
-            verify_out = ph["verify"](self.target_params, st, draft_out)
-            jax.block_until_ready(verify_out)
-        with tr.span("commit"), timer.phase("commit"):
-            st, n_acc = ph["commit"](st, draft_out, verify_out, kr)
-            jax.block_until_ready(n_acc)
-        return st, n_acc
 
     def _decode_round(self) -> List[tuple]:
         tr = self._tr
@@ -701,11 +653,8 @@ class ContinuousEngine:
             # cross to the device
             base_dev = st["lengths"].copy()
             t_round = time.perf_counter()
-            if self._phased is not None:
-                st, n_acc = self._run_round_phased(st, kr)
-            else:
-                st, n_acc = self._round(self._d_params, self.target_params,
-                                        st, kr)
+            st, n_acc = self._round(self._d_params, self.target_params, st,
+                                    kr)
             self._state = st
             # one transfer: lengths + committed windows + the fresh pending
             # token (+ the quality buffers when enabled — they ride the
@@ -814,9 +763,6 @@ class ContinuousEngine:
                  "queue_depth": self.scheduler.ready_depth(self._now()),
                  "free_pages": self.pool.num_free,
                  "active_rows": len(slots)}
-        if self.time_phases:
-            entry["phase_s"] = {k: round(v, 6)
-                                for k, v in self.phases.seconds.items()}
         self.recorder.record_round(**entry)
 
     # ---------------------------------------------------------------- sanitize

@@ -172,8 +172,8 @@ def tree_draft_phase(draft, target: Model, sdc: SDConfig, spec: TreeSpec,
     p_node (N, B, V), d_cache (None for head drafters)}.
 
     Each phase re-derives the identical ``jax.random.split(key, n_keys)``
-    and consumes its fixed slice (draft: the first ``depth`` keys), so the
-    phased decomposition is bit-identical to the fused ``tree_round``."""
+    and consumes its fixed slice (draft: the first ``depth`` keys, commit:
+    the rest), so each phase's draws are fixed by the round key alone."""
     from ..draftheads.drafter import head_draft_tree, is_head_drafter
     head = is_head_drafter(draft)
     if not attention_only(target.cfg) or \
@@ -386,9 +386,8 @@ def tree_round(draft, target: Model, sdc: SDConfig, spec: TreeSpec,
     the per-node slot writes and the root-path commit.
 
     Composed from three phase functions (draft expansion / verify /
-    accept+commit) jitted as ONE computation here; the serving engine's
-    opt-in ``time_phases`` path jits them separately with fences between
-    (repro.obs.phases) — identical math, observable seams."""
+    accept+commit) jitted as ONE computation; each runs under its own
+    ``jax.named_scope``, which is how a device trace splits the round."""
     draft_out = tree_draft_phase(draft, target, sdc, spec, d_params,
                                  t_params, state, key)
     verify_out = tree_verify_phase(draft, target, sdc, spec, t_params,
@@ -404,7 +403,7 @@ def tree_speculative_generate(draft, target: Model, d_params, t_params,
                               spec: TreeSpec, key=None
                               ) -> Tuple[jnp.ndarray, SDStats]:
     """Generate with tree speculation; mirrors ``speculative_generate``."""
-    from ..core.speculative import _cached_tree_round_donated
+    from ..core.speculative import _cached_round_donated
     key = key if key is not None else jax.random.PRNGKey(0)
     B, S = prompt.shape
     # the tree-attention kernel needs a KV width it can tile
@@ -414,7 +413,7 @@ def tree_speculative_generate(draft, target: Model, d_params, t_params,
                            max_total, sdc, k0)
     if sdc.quality:
         state["qual"] = init_quality_buffer(B, spec.depth)
-    round_fn = _cached_tree_round_donated(draft, target, sdc, spec)
+    round_fn = _cached_round_donated(draft, target, sdc, spec)
     stats = SDStats()
     target_len = S + max_new_tokens
     lengths_host = np.full((B,), S, np.int64)

@@ -1,22 +1,19 @@
-"""Observability layer (repro.obs): span tracer, metrics registry, phase
-timer, roofline attribution, bench persistence, and the engine integration —
-trace-reconstructed latencies must match RequestStats, and the phased decode
-path must be token-identical to the fused round it decomposes."""
+"""Observability layer (repro.obs): span tracer, metrics registry, and the
+engine integration — trace-reconstructed latencies must match RequestStats,
+and the engine's emitters must land in the registry."""
 import gc
 import json
 import time
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from benchmarks.bench_persist import (append_run, compare_run, load_history,
-                                      metric_direction, record)
 from repro.configs.base import ModelConfig
 from repro.core.speculative import SDConfig
 from repro.models import Model
-from repro.obs import (Histogram, MetricsRegistry, PhaseTimer, Tracer,
-                       attribution_report, format_attribution)
+from repro.obs import Histogram, MetricsRegistry, Tracer
 from repro.serving import ContinuousEngine, ServeRequest
 from repro.spectree import TreeSpec
 
@@ -183,93 +180,6 @@ def test_snapshot_jsonl(tmp_path):
     assert recs[1]["ts"] > recs[0]["ts"]
 
 
-# --------------------------------------------------------------- phase timer
-
-def test_phase_timer_residual_closure():
-    pt = PhaseTimer()
-    for _ in range(2):
-        pt.add("draft", 0.03)
-        pt.add("verify", 0.05)
-        pt.add_step(0.1)
-    bd = pt.breakdown()
-    # host is the residual, so the breakdown sums to total by construction
-    assert sum(bd.values()) == pytest.approx(pt.total_s)
-    assert bd["host"] == pytest.approx(0.04)
-    assert list(bd)[:2] == ["verify", "draft"]      # sorted descending
-    assert sum(pt.fractions().values()) == pytest.approx(1.0)
-    assert "verify=" in pt.summary() and "host=" in pt.summary()
-    assert PhaseTimer().summary() == "phase timing: no steps recorded"
-
-
-def test_attribution_report_rows():
-    tcfg = ModelConfig(name="t", arch_type="dense", num_layers=2, **BASE)
-    dcfg = ModelConfig(name="d", arch_type="dense", num_layers=1, **BASE)
-    pt = PhaseTimer()
-    for _ in range(4):
-        pt.add("draft", 0.01)
-        pt.add("verify", 0.02)
-        pt.add_step(0.04)
-    rep = attribution_report(pt, tcfg, dcfg, batch=2, ctx=64, gamma=3,
-                             peak_gbps=100.0)
-    assert rep["rounds"] == 4
-    assert set(rep["phases"]) == {"draft", "verify"}
-    for row in rep["phases"].values():
-        assert row["modeled_bytes_per_round"] > 0
-        assert row["achieved_gbps"] > 0
-        assert row["achieved_mbu"] == pytest.approx(
-            row["achieved_gbps"] / 100.0)
-    assert rep["phases"]["verify"]["measured_s_per_round"] == (
-        pytest.approx(0.02))
-    assert "GB/s" in format_attribution(rep)
-    assert "no timed device phases" in format_attribution(
-        attribution_report(PhaseTimer(), tcfg, dcfg, batch=1, ctx=8, gamma=1))
-
-
-# ----------------------------------------------------------- bench persist
-
-def test_metric_direction_heuristics():
-    assert metric_direction("serving_tok_per_s") == 1
-    assert metric_direction("spectree_speedup") == 1
-    assert metric_direction("prefix_hit_rate") == 1
-    assert metric_direction("serving_ttft_p50_ms") == -1
-    assert metric_direction("roofline_step_bytes") == -1
-    assert metric_direction("serving_section_wall_s") == 0   # harness time
-    assert metric_direction("table1_num_layers") == 0        # unknown: no gate
-
-
-def test_bench_trajectory_and_compare(tmp_path):
-    rows = [("serving_tok_per_s", 100.0, ""), ("serving_ttft_p50_ms", 5.0, ""),
-            ("serving_note", "text", "skipped"),
-            ("serving_section_wall_s", 9.0, "")]
-    rec1 = record("serving", rows, wall_s=9.0, config={"quick": True})
-    assert "serving_note" not in rec1["metrics"]
-    path = append_run(str(tmp_path), rec1)
-    assert path.endswith("BENCH_serving.json")
-    hist = load_history(str(tmp_path), "serving")
-    assert len(hist) == 1 and hist[0]["metrics"]["serving_tok_per_s"] == 100.0
-
-    # regression in both directions: throughput down 40%, latency up 60%
-    worse = record("serving", [("serving_tok_per_s", 60.0, ""),
-                               ("serving_ttft_p50_ms", 8.0, ""),
-                               ("serving_section_wall_s", 99.0, "")],
-                   wall_s=99.0, config={"quick": True})
-    regs = compare_run(hist, worse, tol=0.25)
-    assert {r[0] for r in regs} == {"serving_tok_per_s",
-                                    "serving_ttft_p50_ms"}
-    # within tolerance / improvements never flag; wall time never gates
-    ok = record("serving", [("serving_tok_per_s", 90.0, ""),
-                            ("serving_ttft_p50_ms", 4.0, "")],
-                wall_s=1.0, config={"quick": True})
-    assert compare_run(hist, ok, tol=0.25) == []
-    # a different config (quick vs full) is never comparable
-    full = record("serving", [("serving_tok_per_s", 1.0, "")],
-                  wall_s=1.0, config={"quick": False})
-    assert compare_run(hist, full, tol=0.25) == []
-    # trajectory appends and survives a round-trip
-    append_run(str(tmp_path), worse)
-    assert len(load_history(str(tmp_path), "serving")) == 2
-
-
 # --------------------------------------------------- engine integration
 
 @pytest.fixture(scope="module")
@@ -288,31 +198,18 @@ def _requests(rng, lens, max_new):
             for i, (L, m) in enumerate(zip(lens, max_new))]
 
 
-def test_engine_trace_phases_and_fused_equivalence(models):
-    """One instrumented continuous run checks the acceptance criteria:
-    trace-reconstructed TTFT matches RequestStats within 1ms, the phase
-    breakdown covers the full step wall time (host = residual), per-request
-    SD wall time is populated, the registry sees the engine's emitters —
-    and the fenced phased round commits the same tokens as the fused jit."""
-    t, d, tp, dp = models
-    lens, max_new = [6, 10, 8], [8, 6, 7]
-    sdc = SDConfig(gamma=2, temperature=0.0)
-    kw = dict(target=t, target_params=tp, draft=d, draft_params=dp, sd=sdc,
-              max_batch=2, max_seq_len=32, page_size=4, prefill_chunk=8)
-    fused = ContinuousEngine(**kw).serve(
-        _requests(np.random.default_rng(3), lens, max_new))
-
+@pytest.mark.parametrize("tree", [None, TreeSpec((2, 1))],
+                         ids=["chain", "tree"])
+def test_engine_trace_and_registry(models, tree):
+    """One instrumented continuous run, chain and tree: trace-reconstructed
+    TTFT matches RequestStats within 1ms, per-request SD wall time is
+    populated, and the registry sees the engine's emitters."""
     tracer, registry = Tracer(), MetricsRegistry()
-    eng = ContinuousEngine(**kw, tracer=tracer, registry=registry,
-                           time_phases=True)
-    for r in _requests(np.random.default_rng(3), lens, max_new):
+    eng = ContinuousEngine(**_engine_kw(models, tree=tree, tracer=tracer,
+                                        registry=registry))
+    for r in _requests(np.random.default_rng(3), [6, 10, 8], [8, 6, 7]):
         eng.submit(r)
-    phased = eng.run()
-
-    # phased round == fused round, token for token (greedy)
-    for a, b in zip(fused, phased):
-        assert a.request_id == b.request_id
-        assert np.array_equal(a.tokens, b.tokens), a.request_id
+    eng.run()
 
     # trace reconstructs TTFT to within 1ms of the engine's own stats
     evs = tracer.events()
@@ -324,15 +221,7 @@ def test_engine_trace_phases_and_fused_equivalence(models):
         assert abs((first[rid] - begin[rid]) / 1e6 - st.ttft_s) < 1e-3
     names = {e["name"] for e in evs}
     assert {"request", "admit", "first_token", "decode_round",
-            "draft", "verify", "commit", "queue_depth"} <= names
-
-    # phase attribution covers the whole step time (host is the residual)
-    bd = eng.phases.breakdown()
-    assert eng.phases.total_s > 0
-    assert sum(bd.values()) == pytest.approx(eng.phases.total_s, rel=1e-6)
-    assert {"draft", "verify", "commit", "prefill"} <= set(bd)
-    device_frac = 1.0 - eng.phases.fractions()["host"]
-    assert device_frac > 0.5            # fenced phases dominate the step
+            "queue_depth"} <= names
 
     # satellite fixes: per-request SD wall time is stamped every round
     for st in eng.stats.values():
@@ -427,15 +316,53 @@ def test_engine_spans_nest(models):
         == eng.telemetry.decode_rounds
 
 
-@pytest.mark.parametrize("tree", [None, TreeSpec((2, 1))],
-                         ids=["chain", "tree"])
-def test_round_hlo_carries_named_scopes(models, tree):
+def _variant_engine(models, variant):
+    """A tiny engine of the named kind: chain, tree (``TreeSpec((2, 1))``),
+    eagle (an EAGLE head drafter in place of the draft model) or int8
+    (int8 K/V pools)."""
+    kw = _engine_kw(models)
+    if variant == "tree":
+        kw["tree"] = TreeSpec((2, 1))
+    elif variant == "int8":
+        kw["sd"] = SDConfig(gamma=2, temperature=0.0, kv_quant=True)
+        kw["kv_quant"] = True
+    elif variant == "eagle":
+        from repro.draftheads import HeadConfig, HeadDrafter
+        h = HeadDrafter(HeadConfig.for_target("eagle", models[0].cfg))
+        kw.update(draft=None, draft_params=None, draft_heads=h,
+                  draft_head_params=h.init(jax.random.PRNGKey(7)))
+    return ContinuousEngine(**kw)
+
+
+@pytest.mark.parametrize("variant", ["chain", "tree", "eagle", "int8"])
+def test_round_hlo_carries_named_scopes(models, variant):
     """The engine's round program names its phases and the model step's
     parts in its HLO metadata, and keeps its program name."""
-    eng = ContinuousEngine(**_engine_kw(models, tree=tree))
+    eng = _variant_engine(models, variant)
     txt = eng._round.lower(eng._d_params, eng.target_params, eng._state,
                            jax.random.PRNGKey(0)).as_text(debug_info=True)
     for scope in ("draft/", "verify/", "commit", "attention", "kv_write",
                   "kv_gather", "sdpa", "mlp", "lm_head", "sampling"):
         assert scope in txt, scope
     assert "module @jit__unknown" in txt
+
+
+@pytest.mark.parametrize("variant", ["chain", "eagle"])
+def test_prefill_step_keeps_the_round_program_name(models, variant):
+    """The engine's prefill steps lower to the round's program name under
+    another fingerprint, which is how the device trace tells prefill from
+    the round: the drafter's and the target's step, and the target's
+    hidden-returning step that seeds draft heads."""
+    eng = _variant_engine(models, variant)
+    C = eng.prefill_chunk
+    toks = jnp.zeros((1, C), jnp.int32)
+    pos = jnp.arange(C, dtype=jnp.int32)[None]
+    table = jnp.asarray(eng._table_h[:1])
+    steps = [(eng._t_step, eng.target_params, "t_cache")]
+    if eng._d_step is not None:
+        steps.append((eng._d_step, eng.draft_params, "d_cache"))
+    assert (eng._d_step is None) == (variant == "eagle")
+    for step, params, cache in steps:
+        txt = step.lower(params, toks, pos, eng._state[cache],
+                         page_table=table).as_text()
+        assert "module @jit__unknown" in txt, cache

@@ -5,28 +5,25 @@ hypothesis property variant when installed), Page–Hinkley false-positive /
 detection behavior, QualityStats accounting semantics (attempted vs drafted
 vs accepted), temp-0 token identity of the engine with quality telemetry on
 (chain AND tree), SLO burn-rate alerting, the flight recorder, and the
-satellite fixes (NaN latency percentiles, NaN-skipping bench compare,
-histogram bucket validation, acceptance-attribution report).
+satellite fixes (NaN latency percentiles, histogram bucket validation,
+acceptance-attribution report).
 """
 import json
 import os
-import sys
 
 import jax
 import numpy as np
 import pytest
 
-sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "benchmarks"))
-
-from repro.configs.base import ModelConfig                      # noqa: E402
-from repro.core.metrics import latency_percentiles              # noqa: E402
-from repro.core.speculative import SDConfig                     # noqa: E402
-from repro.models import Model                                  # noqa: E402
-from repro.obs import (FlightRecorder, GKSketch, Histogram,     # noqa: E402
-                       PageHinkley, QualityStats, SLOConfig, SLOTracker,
-                       acceptance_report, log_buckets)
-from repro.serving import ContinuousEngine, ServeRequest        # noqa: E402
-from repro.spectree import TreeSpec                             # noqa: E402
+from repro.configs.base import ModelConfig
+from repro.core.metrics import latency_percentiles
+from repro.core.speculative import SDConfig
+from repro.models import Model
+from repro.obs import (FlightRecorder, GKSketch, Histogram, PageHinkley,
+                       QualityStats, SLOConfig, SLOTracker, acceptance_report,
+                       log_buckets)
+from repro.serving import ContinuousEngine, ServeRequest
+from repro.spectree import TreeSpec
 
 BASE = dict(d_model=64, num_heads=4, num_kv_heads=2, d_ff=128, vocab_size=64,
             attn_chunk=16, remat=False)
@@ -351,18 +348,6 @@ def test_latency_percentiles_accepts_sketch():
     for k in out:
         assert out[k] == pytest.approx(ref[k], rel=0.1)
     assert all(np.isnan(v) for v in latency_percentiles(GKSketch()).values())
-
-
-def test_compare_run_skips_nan_metrics():
-    from bench_persist import compare_run, record
-    prev = record("s", [("x_ms", 10.0), ("y_ms", float("nan"))], 1.0, {})
-    cur = record("s", [("x_ms", float("nan")), ("y_ms", 5.0)], 1.0, {})
-    prev["ts"], cur["ts"] = 1.0, 2.0
-    assert compare_run([prev], cur, tol=0.01) == []
-    # sanity: a real regression still gates
-    cur2 = record("s", [("x_ms", 100.0)], 1.0, {})
-    cur2["ts"] = 3.0
-    assert len(compare_run([prev], cur2, tol=0.01)) == 1
 
 
 def test_histogram_bucket_validation():
